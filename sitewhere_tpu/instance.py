@@ -94,6 +94,11 @@ class Instance(LifecycleComponent):
                  template: Optional[InstanceTemplate] = None,
                  recovery_decoder=None):
         super().__init__("instance")
+        # before the first compile: the device programs take about a
+        # minute each at the shipped capacity, seconds from the cache
+        from sitewhere_tpu.runtime.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         self.config = config or Config()
         self.template = template or InstanceTemplate()
         self.instance_id = self.config["instance.id"]

@@ -92,6 +92,23 @@ def load_swwire():
         _load_lock.release()
 
 
+def build_swwire():
+    """Compile ``swwire.c`` NOW on the calling thread and load it — for
+    a caller that must know the native tier is in place before its first
+    decode (``chip_smoke.py``).  Always compiles: a ``.so`` left in the
+    tree by an earlier run is never trusted.  Raises where
+    :func:`load_swwire` would quietly leave the Python path in charge."""
+    with _load_lock:
+        if not _compile(_build_path()):
+            raise RuntimeError("native wire decoder did not build "
+                               "(no C compiler, or swwire.c failed)")
+        if _swwire is None:
+            _load_locked()
+        if _swwire is None:
+            raise RuntimeError("native wire decoder built but did not load")
+        return _swwire
+
+
 def _load_locked():
     global _swwire, _tried
     _tried = True

@@ -1,31 +1,19 @@
-"""``shard_map`` version compatibility — ONE import site for the repo.
+"""``shard_map`` with the replication check off — ONE import site.
 
-jax >= 0.6 exports :func:`shard_map` at the top level with a ``check_vma``
-kwarg; older releases only ship ``jax.experimental.shard_map.shard_map``
-whose equivalent kwarg is ``check_rep``.  Every SPMD builder in this repo
-(pipeline/sharded.py, analytics/runner.py) imports from HERE so the code
-runs unchanged on both — the TPU fleet's current jax and the pinned CI
-container.  Semantics are identical: we always disable the replication
-check (the local bodies use psum/ppermute with explicitly replicated
-outputs the checker cannot always prove).
+Every SPMD builder in this repo (pipeline/sharded.py,
+analytics/runner.py) imports from HERE: the local bodies use
+psum/ppermute with explicitly replicated outputs the checker cannot
+always prove, so ``check_vma`` defaults to False in one place.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export, check_vma kwarg
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable ``shard_map`` (keyword-only, matching new-jax)."""
     return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
+                      out_specs=out_specs, check_vma=check_vma)
 
 
 __all__ = ["shard_map"]

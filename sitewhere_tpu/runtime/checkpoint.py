@@ -127,7 +127,8 @@ def _copy_val(v):
 def merge_store(obj, values: Dict[str, object]) -> None:
     """Restore snapshotted attributes into a live store IN PLACE where
     possible (dict containers are cleared+updated so components holding
-    references keep seeing the store)."""
+    references keep seeing the store).  A store that derives anything
+    from those attributes drops it in ``_on_restored``."""
     for k, v in values.items():
         current = getattr(obj, k)
         if isinstance(current, dict) and isinstance(v, dict):
@@ -135,6 +136,9 @@ def merge_store(obj, values: Dict[str, object]) -> None:
             current.update(v)
         else:
             setattr(obj, k, v)
+    on_restored = getattr(obj, "_on_restored", None)
+    if on_restored is not None:
+        on_restored()
 
 
 def _atomic_write(path: str, write_fn) -> None:

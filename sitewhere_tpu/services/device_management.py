@@ -451,6 +451,15 @@ class DeviceManagement:
         self.zones: Dict[str, Zone] = {}
         self.device_groups: Dict[str, DeviceGroup] = {}
         self.alarms: Dict[str, DeviceAlarm] = {}
+        # device token -> its assignments in creation order, so
+        # _active_assignment scans one device's handful instead of the
+        # whole fleet's (the full scan made registering n devices cost
+        # n^2: minutes at 100k).  None = rebuild on next use.
+        self._assignments_of: Optional[Dict[str, List[DeviceAssignment]]] = None
+
+    def _on_restored(self) -> None:
+        """checkpoint.merge_store refilled the store dicts in place."""
+        self._assignments_of = None
 
     # -- listeners (DeviceManagementTriggers analog) ------------------------
 
@@ -678,8 +687,14 @@ class DeviceManagement:
     # -- assignments --------------------------------------------------------
 
     def _active_assignment(self, device_token: str) -> Optional[DeviceAssignment]:
-        for a in self.assignments.values():
-            if a.device == device_token and a.status in ("Active", "Missing"):
+        index = self._assignments_of
+        if index is None:
+            index = {}
+            for a in self.assignments.values():
+                index.setdefault(a.device, []).append(a)
+            self._assignments_of = index
+        for a in index.get(device_token, ()):
+            if a.status in ("Active", "Missing"):
                 return a
         return None
 
@@ -701,6 +716,8 @@ class DeviceManagement:
                 require(a.area in self.areas, InvalidReference(f"area {a.area}"))
             require(a.status in _ASSIGN_STATUS, ValidationError(f"bad status {a.status}"))
             self.assignments[token] = a
+            if self._assignments_of is not None:
+                self._assignments_of.setdefault(a.device, []).append(a)
             self.identity.assignment.mint(self._scoped(token))
             self._sync_device_row(a.device)
             # Reference: DeviceManagementTriggers fires a StateChange event
@@ -788,6 +805,9 @@ class DeviceManagement:
         with self._lock:
             a = self.get_device_assignment(token)
             del self.assignments[token]
+            if self._assignments_of is not None:
+                self._assignments_of[a.device] = [
+                    x for x in self._assignments_of[a.device] if x is not a]
             self._sync_device_row(a.device)
             self._notify("assignment.deleted", a)
             return a

@@ -1232,7 +1232,8 @@ class TestCrashRecBench:
         import subprocess
         import sys
 
-        env = dict(os.environ)
+        # one process per chip: a child that needs JAX runs on the CPU
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop("SW_CRASHPOINT", None)
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         return subprocess.run(

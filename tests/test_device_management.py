@@ -317,3 +317,61 @@ def test_tenant_isolation_between_services():
     assert mirror.tenant_id[d1] == t1.tenant_id
     assert mirror.tenant_id[d2] == t2.tenant_id
     assert t1.tenant_id != t2.tenant_id
+
+
+def test_active_assignment_lookup_survives_delete_and_restore(dm):
+    """The per-device assignment index answers exactly what a scan of
+    ``dm.assignments`` would, across every way the dict changes:
+    create, delete, and a checkpoint restore refilling it in place."""
+    from sitewhere_tpu.runtime.checkpoint import merge_store
+
+    def scan(device):
+        for a in dm.assignments.values():
+            if a.device == device and a.status in ("Active", "Missing"):
+                return a
+        return None
+
+    for i in range(3):
+        dm.create_device(token=f"d-{i}", device_type="thermo")
+    dm.create_device_assignment(token="a-0", device="d-0")
+    dm.release_device_assignment("a-0")
+    dm.create_device_assignment(token="a-0b", device="d-0")
+    dm.create_device_assignment(token="a-1", device="d-1")
+    assert dm.get_active_assignment("d-0").token == "a-0b"
+    assert dm.get_active_assignment("d-2") is None
+
+    dm.delete_device_assignment("a-0b")
+    assert dm.get_active_assignment("d-0") is scan("d-0") is None
+    dm.create_device_assignment(token="a-0c", device="d-0")
+    assert dm.get_active_assignment("d-0").token == "a-0c"
+
+    # restore: the same dict object, other contents
+    import copy
+
+    snap = copy.deepcopy(dm.assignments)
+    del snap["a-1"]
+    merge_store(dm, {"assignments": snap})
+    for device in ("d-0", "d-1", "d-2"):
+        assert dm.get_active_assignment(device) is scan(device)
+    assert dm.get_active_assignment("d-1") is None
+    dm.create_device_assignment(token="a-1b", device="d-1")   # allowed again
+
+
+def test_registering_a_fleet_is_not_quadratic(dm):
+    """create_device_assignment used to scan every assignment (twice):
+    registering n devices cost n^2 and 100k took minutes.  Compare the
+    work per device early and late in a fleet instead of a wall clock."""
+    calls = []
+    real = dict.values
+
+    class Counting(dict):
+        def values(self):
+            calls.append(len(self))
+            return real(self)
+
+    dm.assignments = Counting()
+    for i in range(300):
+        dm.create_device(token=f"f-{i}", device_type="thermo")
+        dm.create_device_assignment(device=f"f-{i}")
+    # the index is built from the dict once; nothing walks it per device
+    assert len(calls) <= 1

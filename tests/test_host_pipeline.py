@@ -576,13 +576,6 @@ class _FakeDeviceArray:
 
 
 class TestStartHostCopy:
-    @pytest.fixture(autouse=True)
-    def _force_capability(self, monkeypatch):
-        from sitewhere_tpu.pipeline import packed
-
-        monkeypatch.setattr(packed, "_ASYNC_HOST_COPY", True)
-        yield
-
     def test_deleted_buffer_race_stays_silent(self):
         from sitewhere_tpu.pipeline import packed
 

@@ -77,6 +77,7 @@ def test_two_process_sharded_step(tmp_path):
             "SW_COORDINATOR": f"127.0.0.1:{port}",
             "SW_NUM_PROCESSES": "2",
             "SW_PROCESS_ID": str(pid),
+            "JAX_PLATFORMS": "cpu",   # one process per chip
             "PYTHONPATH": os.path.dirname(os.path.dirname(worker))
                           + os.pathsep + env.get("PYTHONPATH", ""),
         })

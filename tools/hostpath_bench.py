@@ -31,7 +31,7 @@ an end-to-end number:
   background workers (the ``store.seal_s`` stage timer).
 
 Also reports ``host_rtt_s`` (trivial-program round-trip: the per-sync
-floor on a network-attached chip) and ``host_syncs_per_batch`` for the
+floor) and ``host_syncs_per_batch`` for the
 single-step (1.0) vs ring (1/ring_k) dispatch paths — every remaining
 millisecond of config-2 latency attributes to exactly one of these
 rows.

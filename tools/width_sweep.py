@@ -6,7 +6,7 @@ judged by: for every width it also times the H2D slot staging
 (``device_put`` of one packed batch), the blocking D2H output fetch, and
 derives the per-batch host-sync budget — step_ms is the device dwell, and
 ``rtt/K + h2d + d2h`` is what a ring slot actually adds on the host side.
-Run on any backend; widths via argv.  Reproduces TPU_EVIDENCE_r05.md §7.
+Run on any backend; widths via argv.
 
     python tools/width_sweep.py [width ...]
 """
