@@ -1,0 +1,109 @@
+"""BENCHMARK.json against the letter of the contract, and every file a
+cell or a metric names."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks import cells
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+BENCH = cells.load_benchmark()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["paths"] == ["benchmarks"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(cells.REPO, "BENCHMARK.json")) \
+        < 64 << 10
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 2)
+
+
+def test_names_units_and_entry_keys():
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and len(c["source"]) <= 200
+        assert all(NAME.match(k) for k in c["reduced"])
+        assert c["file"].startswith("benchmarks/")
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
+        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+    for m in BENCH["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better",
+                                          "bound", "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in BENCH["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better",
+                                          "source", "layer", "moves"}
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+    for m in metrics:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
+    for group in (metrics, BENCH["workloads"], BENCH["configs"]):
+        names = [x["name"] for x in group]
+        assert len(names) == len(set(names))
+    assert {c["name"] for c in BENCH["configs"]} \
+        == {w["config"] for w in BENCH["workloads"]}
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_every_file_of_a_cell_resolves_by_name(name):
+    cell = cells.resolve_cell(name)
+    assert os.path.exists(cell["traffic"]["kind_file"])
+    assert cell["config"]["config"]["pipeline"]["n_shards"] == cell["chips"]
+    e2e = [entry["name"] for entry, _ in cell["end_to_end"]]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert cell["per_layer"]
+    for entry, path in cell["end_to_end"] + cell["per_layer"]:
+        assert callable(cells.load_module(path).read), path
+    # a per-layer metric is reported only where the metric it moves is
+    assert {entry["moves"] for entry, _ in cell["per_layer"]} <= set(e2e)
+    if cell["traffic"]["kind"] == "wire-open-loop":
+        assert cell["traffic"]["rate_events_per_s"] > 0
+
+
+def test_metric_and_kind_files_all_belong_to_an_entry():
+    here = os.path.join(cells.REPO, "benchmarks")
+    for folder, section in (("end_to_end", "end_to_end"),
+                            ("layer_metrics", "per_layer")):
+        files = {f[:-3] for f in os.listdir(os.path.join(here, folder))
+                 if f.endswith(".py")}
+        assert files == {m["name"] for m in BENCH[section]}
+
+
+def test_run_names_no_cell_configuration_mix_or_metric():
+    with open(os.path.join(cells.REPO, "benchmarks", "run.py")) as f:
+        text = f.read()
+    words = ([w["name"] for w in BENCH["workloads"]]
+             + [c["name"] for c in BENCH["configs"]]
+             + [w["traffic"] for w in BENCH["workloads"]]
+             + [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
+    assert [w for w in words if w in text] == []
+
+
+def test_a_run_without_a_tpu_exits_non_zero_and_prints_no_result():
+    done = subprocess.run(
+        [sys.executable, *BENCH["command"][1:], "--workload", CELLS[0],
+         "--seed", "0", "--seconds", "1", "--trace", "0"],
+        cwd=cells.REPO, capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode != 0
+    last = done.stdout.strip().splitlines()[-1]
+    with pytest.raises(ValueError):
+        json.loads(last)
