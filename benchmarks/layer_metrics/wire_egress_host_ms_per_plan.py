@@ -1,0 +1,6 @@
+"""``egress_host_ms_per_plan`` in the open-loop wire cells, where it bears
+on latency and not on events/s (the rate is fixed)."""
+
+from benchmarks import cells
+
+read = cells.reader("layer_metrics", "egress_host_ms_per_plan")

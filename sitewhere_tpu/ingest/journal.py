@@ -20,6 +20,7 @@ record indices; a sparse index maps offsets to (segment, file position).
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import os
 import struct
@@ -43,6 +44,11 @@ class Journal:
     reference's Mongo event buffer trades flush interval
     (``DeviceEventBuffer.java:40-46``): 0 = fsync on every append (safest),
     N = fsync every N appends and on close/rotate.
+
+    ``append_timer`` (a ``runtime.metrics.Timer``, optional) times every
+    :meth:`append` whole — lock wait and fsync included: durability's
+    cost on the caller's path (the instance passes
+    ``ingest.journal_append_s`` to the ingest journal).
     """
 
     def __init__(
@@ -52,6 +58,7 @@ class Journal:
         segment_bytes: int = 64 << 20,
         fsync_every: int = 256,
         index_every: int = _INDEX_EVERY,
+        append_timer=None,
     ):
         self.dir = os.path.join(root, name)
         os.makedirs(self.dir, exist_ok=True)
@@ -61,6 +68,8 @@ class Journal:
         # higher = sparser index, less memory, scans seek then roll forward.
         self.index_every = max(1, index_every)
         self._lock = threading.Lock()
+        self._append_span = (append_timer.time if append_timer is not None
+                             else contextlib.nullcontext)
         self._unsynced = 0
         # duration of the most recent fsync — an overload pressure
         # signal (a saturated disk shows up here before queues fill)
@@ -178,7 +187,7 @@ class Journal:
 
     def append(self, payload: bytes) -> int:
         """Append one record; returns its offset."""
-        with self._lock:
+        with self._append_span(), self._lock:
             offset = self._next_offset
             if offset % self.index_every == 0:
                 self._index.append((offset, self._file.name, self._file.tell()))

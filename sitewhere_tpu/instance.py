@@ -196,6 +196,7 @@ class Instance(LifecycleComponent):
             self.data_dir, name="ingest",
             fsync_every=int(self.config["journal.fsync_every"]),
             segment_bytes=int(self.config["journal.segment_bytes"]),
+            append_timer=self.metrics.timer("ingest.journal_append_s"),
         )
         self.dead_letters = Journal(self.data_dir, name="dead-letters")
         # terminal seal failures dead-letter instead of pinning memory /

@@ -27,6 +27,7 @@ envelope, keep the payload identical.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Callable, Dict, Optional, Tuple
 
@@ -513,6 +514,20 @@ def pack_batch_host(cols: Dict[str, np.ndarray],
     return bi, bf
 
 
+def _blocking_fetch(dev_pair, wait_timer=None, seq: int = -1):
+    """THE blocking device→host fetch of a step's (or a ring's) output
+    pair: one ``device_get`` — it starts the copies for every leaf
+    before blocking on any, so the pair costs one host sync even when
+    the dispatch-time ``copy_to_host_async`` has not landed yet.  Under
+    ``wait_timer`` (the dispatcher's ``pipeline.device_wait_s``) the
+    wait is a timer observation and a profiler span tagged ``seq``: the
+    host blocked on the device finishing the step and on D2H."""
+    with (wait_timer.time(seq=seq) if wait_timer is not None
+          else contextlib.nullcontext()):
+        a, b = jax.device_get(dev_pair)
+        return np.asarray(a), np.asarray(b)
+
+
 class PackedView:
     """Host-side adapter over the packed step outputs.
 
@@ -522,7 +537,8 @@ class PackedView:
     array — it feeds the next commit, never the host.
     """
 
-    def __init__(self, oi, metrics, present_now, on_fetch=None):
+    def __init__(self, oi, metrics, present_now, on_fetch=None,
+                 wait_timer=None, seq: int = -1):
         self._oi_dev = oi
         self._metrics_dev = metrics
         self.present_now = present_now
@@ -533,17 +549,17 @@ class PackedView:
         # host-sync instrumentation: called ONCE, at the blocking fetch
         # (the dispatcher wires its ``pipeline.host_syncs`` counter)
         self._on_fetch = on_fetch
+        # ... and timed there (``pipeline.device_wait_s``), tagged with
+        # the plan's ``seq``
+        self._wait_timer = wait_timer
+        self._seq = seq
 
     def _fetch(self) -> None:
-        """Materialize BOTH host copies in one device_get: it starts the
-        copies for every leaf before blocking on any, so the pair costs
-        one host sync even when the dispatch-time copy_to_host_async
-        has not landed yet."""
+        """Materialize BOTH host copies in one :func:`_blocking_fetch`."""
         if self._on_fetch is not None:
             self._on_fetch()
-        oi, metrics = jax.device_get((self._oi_dev, self._metrics_dev))
-        self._oi = np.asarray(oi)
-        self._metrics_host = np.asarray(metrics)
+        self._oi, self._metrics_host = _blocking_fetch(
+            (self._oi_dev, self._metrics_dev), self._wait_timer, self._seq)
 
     @property
     def oi(self) -> np.ndarray:
@@ -659,18 +675,22 @@ class RingFetch:
     finds the bytes already host-side.
     """
 
-    def __init__(self, ois, metrics, on_fetch=None):
+    def __init__(self, ois, metrics, on_fetch=None, wait_timer=None,
+                 seq: int = -1):
         self._ois_dev = ois
         self._metrics_dev = metrics
         self._host: Optional[tuple] = None
         self._on_fetch = on_fetch
+        self._wait_timer = wait_timer
+        self._seq = seq   # the ring's first plan
 
     def fetch(self) -> tuple:
         if self._host is None:
             if self._on_fetch is not None:
                 self._on_fetch()
-            ois, mets = jax.device_get((self._ois_dev, self._metrics_dev))
-            self._host = (np.asarray(ois), np.asarray(mets))
+            self._host = _blocking_fetch(
+                (self._ois_dev, self._metrics_dev), self._wait_timer,
+                self._seq)
         return self._host
 
 

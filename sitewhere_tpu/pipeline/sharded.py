@@ -117,9 +117,10 @@ def build_sharded_step(mesh: Mesh, donate: bool = True):
             device_id=jnp.where(derived.device_id >= 0, derived.device_id + offset,
                                 derived.device_id)
         )
-        metrics = jax.tree_util.tree_map(
-            lambda c: jax.lax.psum(c, SHARD_AXIS), out.metrics
-        )
+        with jax.named_scope("mesh_reduce"):
+            metrics = jax.tree_util.tree_map(
+                lambda c: jax.lax.psum(c, SHARD_AXIS), out.metrics
+            )
         out = out.replace(derived_alerts=derived, metrics=metrics)
         return new_state, out
 
@@ -171,7 +172,8 @@ def build_sharded_packed_step(mesh: Mesh):
         # telemetry rides the psum-ed metrics vector: occupancy counters
         # aggregate over shards exactly like the step scalars
         oi, metrics, present = pack_outputs(out, local_batch)
-        metrics = jax.lax.psum(metrics, SHARD_AXIS)
+        with jax.named_scope("mesh_reduce"):
+            metrics = jax.lax.psum(metrics, SHARD_AXIS)
         # derived-alert/enrich ids in `oi` are table indices (replicated
         # tables → already global); device ids never leave the host cols
         return pack_state(new_state), oi, metrics, present
@@ -241,7 +243,8 @@ def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
                                                  ps, slots)
         # one collective per chain: psum of the stacked [K, n] block is
         # the per-step psum the single sharded step would have done K×
-        mets = jax.lax.psum(mets, SHARD_AXIS)
+        with jax.named_scope("mesh_reduce"):
+            mets = jax.lax.psum(mets, SHARD_AXIS)
         return c, ois, mets, present
 
     mapped = shard_map(

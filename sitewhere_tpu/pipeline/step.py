@@ -517,6 +517,11 @@ def _build_derived_alerts(
     )
 
 
+#: ``jax.named_scope`` names of the stages :func:`pipeline_step` composes
+STEP_STAGES = ("validate_enrich", "threshold_rules", "zone_rules",
+               "state_update", "derived_alerts")
+
+
 def pipeline_step(
     registry: Registry,
     state: DeviceState,
@@ -527,8 +532,13 @@ def pipeline_step(
     """The fused inbound step: validate → enrich → rules → state → outputs.
 
     Pure function of its inputs — jit/pjit it once and feed batches forever.
+    The five stages carry ``jax.named_scope`` names (:data:`STEP_STAGES`)
+    — metadata only: a profiler capture shows them as each op's
+    ``op_name`` prefix, the compiled program is the same.
     """
-    accepted, unregistered, unassigned, enrich = validate_and_enrich(registry, batch)
+    with jax.named_scope("validate_enrich"):
+        accepted, unregistered, unassigned, enrich = validate_and_enrich(
+            registry, batch)
     # Numeric integrity: a NaN/Inf in any float column would flow through
     # the EWMA fold, the rule compares (NE is True for NaN!) and the
     # time-ordered scatters straight into CARRIED state — poisoning the
@@ -538,21 +548,27 @@ def pipeline_step(
               & jnp.isfinite(batch.lon) & jnp.isfinite(batch.elevation))
     nonfinite = batch.valid & ~finite
     clean = accepted & finite
-    rule_fired, rule_id, ewma_candidates = eval_threshold_rules(
-        rules, state, batch, clean)
-    zone_fired, zone_id = eval_zone_rules(zones, batch, clean, enrich["area_id"])
-    new_state, present_now = update_device_state(
-        state, batch, clean, ewma_candidates)
-    # Per-device attribution rides device state (one scatter-add, no host
-    # sync): the quarantine threshold is evaluated host-side from the
-    # packed telemetry scalar + this counter.
-    cap = state.capacity
-    nf_idx = jnp.where(nonfinite & (batch.device_id >= 0)
-                       & (batch.device_id < cap), batch.device_id, cap)
-    new_state = new_state.replace(
-        nonfinite_count=new_state.nonfinite_count.at[nf_idx].add(
-            1, mode="drop"))
-    derived = _build_derived_alerts(batch, rules, zones, rule_id, zone_id)
+    with jax.named_scope("threshold_rules"):
+        rule_fired, rule_id, ewma_candidates = eval_threshold_rules(
+            rules, state, batch, clean)
+    with jax.named_scope("zone_rules"):
+        zone_fired, zone_id = eval_zone_rules(
+            zones, batch, clean, enrich["area_id"])
+    with jax.named_scope("state_update"):
+        new_state, present_now = update_device_state(
+            state, batch, clean, ewma_candidates)
+        # Per-device attribution rides device state (one scatter-add, no
+        # host sync): the quarantine threshold is evaluated host-side
+        # from the packed telemetry scalar + this counter.
+        cap = state.capacity
+        nf_idx = jnp.where(nonfinite & (batch.device_id >= 0)
+                           & (batch.device_id < cap), batch.device_id, cap)
+        new_state = new_state.replace(
+            nonfinite_count=new_state.nonfinite_count.at[nf_idx].add(
+                1, mode="drop"))
+    with jax.named_scope("derived_alerts"):
+        derived = _build_derived_alerts(
+            batch, rules, zones, rule_id, zone_id)
 
     metrics = StepMetrics(
         processed=batch.valid.sum().astype(jnp.int32),

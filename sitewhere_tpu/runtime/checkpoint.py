@@ -73,6 +73,7 @@ import numpy as np
 
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
+from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
 logger = logging.getLogger("sitewhere_tpu.checkpoint")
 
@@ -107,6 +108,9 @@ _SUPPORTED_STORES_VERSIONS = {1}
 # section names owned by the checkpointer itself — providers may not
 # register under them
 _RESERVED_SECTIONS = frozenset({"stores", "mirror", "state", "identity"})
+#: the numbered blocks of ``Checkpointer.save``, in order
+SAVE_PHASES = ("stores", "mirror", "state", "identity", "providers",
+               "manifest")
 
 
 class SnapshotCorrupt(Exception):
@@ -253,6 +257,14 @@ class Checkpointer(LifecycleComponent):
         #: the committed offset, the pre-offset-contract behavior)
         self.replay_floor: Optional[int] = None
         self.restore_s: float = 0.0
+        # one periodic checkpoint, start to manifest swap (inside
+        # _save_lock), and its six phases — each a timer and, in a
+        # profiler capture, a span of the same name
+        metrics = getattr(instance, "metrics", None) or MetricsRegistry()
+        self._m_save = metrics.timer("checkpoint.save_s")
+        self._m_phase = {
+            p: metrics.timer(f"checkpoint.phase_{p}_s") for p in SAVE_PHASES
+        }
         candidates = self._manifest_candidates()
         self.generation = candidates[0][0] if candidates else -1
 
@@ -315,10 +327,47 @@ class Checkpointer(LifecycleComponent):
             journal = getattr(inst, "ingest_journal", None)
             journal_end = int(journal.end_offset) if journal is not None \
                 else 0
-            gen = self.generation + 1
-            names: Dict[str, str] = {}
-            offsets: Dict[str, int] = {}
+            with self._m_save.time():
+                self._write_generation(inst, committed, journal_end)
+            # 7. journal retention (opt-in): everything below the
+            # pipeline's durably committed offset is re-derivable from
+            # this snapshot + the event store, so whole segments under
+            # it reclaim.  payload_ref resolution for rows older than
+            # the snapshot becomes unresolvable — every downstream
+            # handler already tolerates a missing ref.
+            if self.prune_journal:
+                if reader is not None:
+                    pruned = inst.ingest_journal.prune(reader.committed)
+                    if pruned:
+                        logger.info(
+                            "pruned %d ingest-journal segment(s) below "
+                            "committed offset %d", pruned, reader.committed)
+            # 8. dead-letter retention: keep the newest N records (the
+            # Kafka-retention analog for the dead-letter topics); pruned
+            # records stop being listable/requeueable, which is what
+            # retention means.  0 disables.
+            keep = int(inst.config.get("dead_letters.retain_records",
+                                       10_000) or 0)
+            if keep > 0:
+                cut = inst.dead_letters.end_offset - keep
+                if cut > 0 and inst.dead_letters.prune(cut):
+                    logger.info("pruned dead-letter segments below %d", cut)
+            logger.info("checkpoint generation %d saved (committed=%d)",
+                        self.generation, committed)
+            return self._manifest_path
 
+    def _write_generation(self, inst, committed: int,
+                          journal_end: int) -> None:
+        """Write generation ``self.generation + 1`` and commit it with
+        the MANIFEST swap — one ``checkpoint.save_s`` span, each numbered
+        phase a ``checkpoint.phase_<name>_s`` span inside it (in a
+        profiler capture: which phase covers a delivery gap)."""
+        phase = self._m_phase
+        gen = self.generation + 1
+        names: Dict[str, str] = {}
+        offsets: Dict[str, int] = {}
+
+        with phase["stores"].time():
             # 1. management stores — containers are COPIED under each
             # store's lock so the pickle below (lock released) can't race
             # a concurrent mutation
@@ -361,6 +410,7 @@ class Checkpointer(LifecycleComponent):
             # disk with no manifest — the previous generation must restore
             faults.crosspoint("crash.mid_checkpoint")
 
+        with phase["mirror"].time():
             # 2. registry mirror columns (+ zone tables + epoch)
             mirror = inst.mirror
             with mirror._lock:
@@ -378,6 +428,7 @@ class Checkpointer(LifecycleComponent):
             )
             offsets["mirror"] = committed
 
+        with phase["state"].time():
             # 3. device-state tensors (one device→host copy per field);
             # a remoted device_state belongs to the owning host's
             # checkpoints, like any other facade-backed domain
@@ -394,11 +445,13 @@ class Checkpointer(LifecycleComponent):
                 )
                 offsets["state"] = committed
 
+        with phase["identity"].time():
             # 4. identity map LAST (see module docstring: a token minted
             # mid-save must never be dangling in the restored identity)
             names["identity"] = f"identity-{gen:08d}.json"
             inst.identity.save(os.path.join(self.dir, names["identity"]))
 
+        with phase["providers"].time():
             # 5. registered component providers (analytics/CEP operator
             # state with its exact applied offset, dedup tables, spool
             # cursors…) — a provider crash skips ITS section, never the
@@ -421,6 +474,7 @@ class Checkpointer(LifecycleComponent):
                              header, payload)
                 offsets[provider.name] = int(header["as_of"])
 
+        with phase["manifest"].time():
             # 6. manifest: the per-generation anchor first (it is what
             # torn-snapshot fallback finds when a LATER save dies before
             # its swap), then the MANIFEST swap commits the generation
@@ -443,32 +497,6 @@ class Checkpointer(LifecycleComponent):
             # keep gen-1 too: torn-generation fallback needs ONE previous
             # complete file set on disk (gc'd once gen+1 commits)
             self._gc(keep=gen - 1)
-            # 7. journal retention (opt-in): everything below the
-            # pipeline's durably committed offset is re-derivable from
-            # this snapshot + the event store, so whole segments under
-            # it reclaim.  payload_ref resolution for rows older than
-            # the snapshot becomes unresolvable — every downstream
-            # handler already tolerates a missing ref.
-            if self.prune_journal:
-                if reader is not None:
-                    pruned = inst.ingest_journal.prune(reader.committed)
-                    if pruned:
-                        logger.info(
-                            "pruned %d ingest-journal segment(s) below "
-                            "committed offset %d", pruned, reader.committed)
-            # 8. dead-letter retention: keep the newest N records (the
-            # Kafka-retention analog for the dead-letter topics); pruned
-            # records stop being listable/requeueable, which is what
-            # retention means.  0 disables.
-            keep = int(inst.config.get("dead_letters.retain_records",
-                                       10_000) or 0)
-            if keep > 0:
-                cut = inst.dead_letters.end_offset - keep
-                if cut > 0 and inst.dead_letters.prune(cut):
-                    logger.info("pruned dead-letter segments below %d", cut)
-            logger.info("checkpoint generation %d saved (committed=%d)",
-                        gen, committed)
-            return self._manifest_path
 
     def _gc(self, keep: int) -> None:
         for path in glob.glob(os.path.join(self.dir, "*-*.np[zy]")) + \

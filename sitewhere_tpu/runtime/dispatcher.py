@@ -57,9 +57,20 @@ Output fetches stay selective: batch columns never round-trip (the
 batcher keeps its numpy originals in ``BatchPlan``), device→host copies
 start asynchronously at dispatch, and the unregistered mask /
 derived-alert rows are fetched only when the step's metric counters say
-they exist.  Per-stage host time lands in the
-``pipeline.stage_{decode,batch,dispatch,egress}_s`` timers — when their
-totals exceed wall elapsed, the stages are provably overlapping.
+they exist.
+
+A plan's life on the host is a chain of timers, each also a profiler
+span of the same name (``runtime/metrics.py Timer.time``; spans of one
+plan share ``seq``): ``pipeline.stage_decode_s`` and
+``ingest.journal_append_s`` per payload; per plan ``stage_batch_s`` →
+``stage_h2d_s`` (unpacked plans) → ``stage_dispatch_wait_s`` (full
+egress window + step-lock wait) → ``stage_dispatch_s`` →
+``stage_inflight_wait_s`` (queued for egress) → ``stage_egress_s``, of
+which ``pipeline.device_wait_s`` is the part blocked on the device and
+D2H; ring plans have ``stage_ring_wait_s`` / ``stage_ring_dispatch_s``
+in place of the dispatch pair.  With the batcher wait
+(``plan.max_wait_s``) they add up to the plan's latency; when the
+stage totals exceed wall elapsed, the stages are provably overlapping.
 """
 
 from __future__ import annotations
@@ -99,6 +110,12 @@ _EGRESS_HOST = tuple(
     n for n in _segment_schema.COLUMN_NAMES
     if n not in _EGRESS_ENRICHMENT and n != "received_s"
 )
+
+
+class _StepFailed(Exception):
+    """The launch/commit of a packed single step raised — the one
+    containable region of ``_dispatch_plan`` (the cause rides
+    ``__cause__``; ``_contain_step_failure`` bisects the batch)."""
 
 
 class EgressColumns(collections.abc.Mapping):
@@ -459,6 +476,10 @@ class PipelineDispatcher(LifecycleComponent):
         # surface): decode / batch-assembly / step-dispatch / egress each
         # accumulate the HOST time they consume, so `sum(stage totals) >
         # wall elapsed` is the measurable proof the stages overlap.
+        # Lexical stages go through ``Timer.time(seq=plan.seq)``: the
+        # same region is a span of the timer's name in a profiler
+        # capture.  Intervals between threads (ring_wait, inflight_wait)
+        # are plain ``observe()``s.
         self._m_stage = {
             s: metrics.timer(f"pipeline.stage_{s}_s")
             for s in ("decode", "batch", "dispatch", "egress",
@@ -468,8 +489,21 @@ class PipelineDispatcher(LifecycleComponent):
                       # unpacked plans' lazy EventBatch H2D (moved off
                       # the intake lock out of _emit — its own stage so
                       # the batch timer's per-plan sample count stays 1)
-                      "h2d")
+                      "h2d",
+                      # the dispatch path blocked: a full egress window
+                      # (_stall_for_egress_room) plus the wait for
+                      # _step_lock, ending when the lock is held
+                      "dispatch_wait",
+                      # a dispatched plan queued in _inflight until the
+                      # egress worker (or an inline drain) pops it
+                      "inflight_wait")
         }
+        # The host BLOCKED on the device finishing a step and on its D2H
+        # (the views' blocking fetch in pipeline/packed.py, the unpacked
+        # fallback's metrics fetch in _egress): a child of the egress
+        # stage, so egress self time = stage_egress_s - device_wait_s.
+        # One observation per host sync (pipeline.host_syncs).
+        self._m_device_wait = metrics.timer("pipeline.device_wait_s")
         # "How often does the host touch the device" as a first-class
         # metric: one inc per BLOCKING device→host sync on the dispatch/
         # egress path (the packed views' lazy fetch, the ring's shared
@@ -656,18 +690,18 @@ class PipelineDispatcher(LifecycleComponent):
         """Run a batcher intake under the lock, counting every emitted plan
         as outstanding until its egress completes — the commit gate's
         accounting (see ``_maybe_commit_offset``)."""
-        t0 = time.perf_counter()
-        with self._lock:
-            out = intake()
-            if out is None:
-                plans: List[BatchPlan] = []
-            elif isinstance(out, list):
-                plans = [p for p in out if p is not None]
-            else:
-                plans = [out]
-            self._plans_outstanding += len(plans)
-        if plans:
-            self._m_stage["batch"].observe(time.perf_counter() - t0)
+        with self._m_stage["batch"].time() as span:
+            with self._lock:
+                out = intake()
+                if out is None:
+                    plans: List[BatchPlan] = []
+                elif isinstance(out, list):
+                    plans = [p for p in out if p is not None]
+                else:
+                    plans = [out]
+                self._plans_outstanding += len(plans)
+            if not plans:
+                span.discard()   # the timer is per EMITTED plan
         return plans
 
     def _run_plans(self, plans: List[BatchPlan],
@@ -712,9 +746,8 @@ class PipelineDispatcher(LifecycleComponent):
             # into the batch timer would double that timer's per-plan
             # sample count and halve the per-batch attribution the
             # bench derives from totals/counts.
-            t0 = time.perf_counter()
-            plan.materialize_batch()
-            self._m_stage["h2d"].observe(time.perf_counter() - t0)
+            with self._m_stage["h2d"].time(seq=plan.seq):
+                plan.materialize_batch()
 
     def _shed_intake(self, payload: bytes, shed: Dict[object, int],
                      source_id: str, tenant: str,
@@ -1277,8 +1310,16 @@ class PipelineDispatcher(LifecycleComponent):
                     # the deferred steps so egress latency stays bounded
                     # when traffic pauses.
                     self._flush_ring_if_due()
-                    self._drain_inflight()
-                    self._maybe_commit_offset()
+                    # the idle housekeeping below takes the step lock:
+                    # try, never wait (the rule above) — a dispatch that
+                    # took the lock since this tick's probe may be the
+                    # wedged one; the next tick retries
+                    if self._step_lock.acquire(blocking=False):
+                        try:
+                            self._drain_inflight()
+                            self._maybe_commit_offset()
+                        finally:
+                            self._step_lock.release()
             except Exception:
                 logger.exception("dispatch cycle failed")
 
@@ -1525,8 +1566,9 @@ class PipelineDispatcher(LifecycleComponent):
                 self._ring.append(plan)
                 due = len(self._ring) >= self.ring_depth
             if due:
-                self._stall_for_egress_room()
+                wait = self._await_dispatch(plan.seq, stall=True)
                 with self._step_lock:
+                    wait.__exit__(None, None, None)
                     if len(self._ring) >= self.ring_depth:
                         self._run_ring()
             return
@@ -1560,6 +1602,20 @@ class PipelineDispatcher(LifecycleComponent):
                 # breaker demoted past CHAINED: bisectable single-step
                 # dispatch only, until a cooldown probe succeeds
                 and self.breaker.allow_chain())
+
+    def _await_dispatch(self, seq: int, stall: bool):
+        """Open the ``pipeline.stage_dispatch_wait_s`` span — the time
+        the dispatch path is BLOCKED: a full egress window (``stall``)
+        plus the wait for ``_step_lock`` — and stall.  The caller closes
+        the returned span as its first statement under ``with
+        self._step_lock`` (the span ends when the lock is held; the lock
+        stays a ``with`` region for the lock-order lint).  One
+        observation per wait entered, so the timer reads as a total."""
+        wait = self._m_stage["dispatch_wait"].time(seq=seq)
+        wait.__enter__()
+        if stall:
+            self._stall_for_egress_room()
+        return wait
 
     def _stall_for_egress_room(self) -> None:
         """Bounded offload queue: stall — never while holding the step
@@ -1605,9 +1661,10 @@ class PipelineDispatcher(LifecycleComponent):
         taken but not yet stepped (the stall, which must never run under
         the lock, sits between holds)."""
         while True:
-            if stall:
-                self._stall_for_egress_room()
+            wait = self._await_dispatch(
+                upto_seq if upto_seq is not None else -1, stall)
             with self._step_lock:
+                wait.__exit__(None, None, None)
                 if not self._ring:
                     return
                 if upto_seq is not None and self._ring[0].seq >= upto_seq:
@@ -1698,50 +1755,58 @@ class PipelineDispatcher(LifecycleComponent):
             staged = plan.staged or (plan.packed_i, plan.packed_f)
             slots_i[i] = staged[0]
             slots_f[i] = staged[1]
-        t0 = time.perf_counter()
-        tables = self._tables_packed()
-        # one watchdog entry for the whole chain; each slot's egress
-        # decrements a part, so the entry drains when the LAST slot does
-        # (`plans` rides as the opaque payload — the trip callback
-        # renders records lazily, off the per-batch hot path)
-        wd = self.watchdog.begin(plans, parts=k)
-        for plan in plans:
-            self._wd_tokens[id(plan)] = wd
-        ctrace = self.tracer.trace("pipeline.chain")
-        try:
-            if faults.device_active():
-                # device-fault injection point: fires against the HOST
-                # copies of the packed batch (plan.packed_i/f, always
-                # retained), so when_nonfinite matches exactly what the
-                # device would compute over
-                for plan in plans:
-                    faults.device_fire("device.dispatch",
-                                       values=plan.packed_f,
-                                       valid=plan.packed_i[0] != 0)
-            with ctrace.span("ring.dispatch").tag("steps", k):
-                _, ois, mets, _present = self._dispatch_chain(
-                    chain, tables, slots_i, slots_f)
-            start_host_copy(ois, mets, on_error=self._on_host_copy_error)
-        except Exception as e:
+        failure = None
+        with self._m_stage["ring_dispatch"].time(
+                seq=plans[0].seq, steps=k) as span:
+            tables = self._tables_packed()
+            # one watchdog entry for the whole chain; each slot's egress
+            # decrements a part, so the entry drains when the LAST slot
+            # does (`plans` rides as the opaque payload — the trip
+            # callback renders records lazily, off the per-batch hot path)
+            wd = self.watchdog.begin(plans, parts=k)
+            for plan in plans:
+                self._wd_tokens[id(plan)] = wd
+            ctrace = self.tracer.trace("pipeline.chain")
+            try:
+                if faults.device_active():
+                    # device-fault injection point: fires against the
+                    # HOST copies of the packed batch (plan.packed_i/f,
+                    # always retained), so when_nonfinite matches exactly
+                    # what the device would compute over
+                    for plan in plans:
+                        faults.device_fire("device.dispatch",
+                                           values=plan.packed_f,
+                                           valid=plan.packed_i[0] != 0)
+                with ctrace.span("ring.dispatch").tag("steps", k):
+                    _, ois, mets, _present = self._dispatch_chain(
+                        chain, tables, slots_i, slots_f)
+                start_host_copy(ois, mets,
+                                on_error=self._on_host_copy_error)
+            except Exception as e:
+                failure = e
+                span.discard()   # the timer counts dispatched chains
+            finally:
+                # drop the slot references: staged H2D buffers must not
+                # outlive their ring pinned in the dispatch scratch
+                for i in range(k):
+                    slots_i[i] = None
+                    slots_f[i] = None
             ctrace.end()
-            self._recover_ring(plans, e)
+            if failure is None:
+                # chaos kill point: the K-step chain dispatched and
+                # committed on device, but NO slot has egressed — every
+                # ring plan must replay
+                faults.crosspoint("crash.mid_ring")
+        if failure is not None:
+            self._recover_ring(plans, failure)
             return
-        finally:
-            # drop the slot references: staged H2D buffers must not
-            # outlive their ring pinned in the dispatch scratch
-            for i in range(k):
-                slots_i[i] = None
-                slots_f[i] = None
-        ctrace.end()
-        # chaos kill point: the K-step chain dispatched and committed on
-        # device, but NO slot has egressed — every ring plan must replay
-        faults.crosspoint("crash.mid_ring")
-        chain_dt = time.perf_counter() - t0
-        self._m_stage["ring_dispatch"].observe(chain_dt)
+        chain_dt = span.elapsed
         self._m_ring_chains.inc()
         for plan in plans:
             plan.dispatch_s = chain_dt / k   # per-slot share of the chain
-        fetch = RingFetch(ois, mets, on_fetch=self._m_host_syncs.inc)
+        fetch = RingFetch(ois, mets, on_fetch=self._m_host_syncs.inc,
+                          wait_timer=self._m_device_wait,
+                          seq=plans[0].seq)
         for slot, plan in enumerate(plans):
             trace = self.tracer.trace("pipeline.plan")
             trace.record("batch.assemble", plan.max_wait_s,
@@ -2113,121 +2178,135 @@ class PipelineDispatcher(LifecycleComponent):
         # chaos hook: a step-dispatch failure (device OOM, donation bug)
         # — the plan stays outstanding, so the commit gate fails closed
         faults.fire("dispatcher.step")
-        if stall and replay_depth == 0:
-            # Re-injected plans (depth > 0, which includes everything the
-            # egress worker itself submits) skip the wait so the worker
-            # can never block on its own backlog.
-            self._stall_for_egress_room()
         self._stage_plan(plan)
         trace = self.tracer.trace("pipeline.plan")
         # the batcher wait of the oldest row = the "batch assemble" stage
         trace.record("batch.assemble", plan.max_wait_s,
                      rows=plan.n_events, fill=round(plan.fill, 3))
         self._m_assemble.observe(plan.max_wait_s)
-        t_dispatch = time.perf_counter()
+        # Re-injected plans (depth > 0, which includes everything the
+        # egress worker itself submits) skip the stall so the worker
+        # can never block on its own backlog.
+        wait = self._await_dispatch(plan.seq, stall and replay_depth == 0)
         with self._step_lock:
-            if plan.packed_i is not None:
-                from sitewhere_tpu.pipeline.packed import (
-                    PackedView,
-                    start_host_copy,
-                )
-
-                tables = self._tables_packed()
-                epoch = self.state_manager.current_packed
-                ps = epoch
-                # staged pair (H2D already in flight) off the CPU
-                # backend; the raw numpy buffers otherwise (the jitted
-                # call then transfers synchronously)
-                bi, bf = plan.staged or (plan.packed_i, plan.packed_f)
-                if self.mesh is not None:
-                    from sitewhere_tpu.pipeline.sharded import (
-                        place_packed_batch,
-                        place_packed_state,
-                    )
-
-                    if plan.staged is None:
-                        bi, bf = place_packed_batch(self.mesh, bi, bf)
-                    ps = place_packed_state(self.mesh, ps)
-                # breaker at FALLBACK: the chip is presumed dead — route
-                # the same jitted program to a CPU device (single-chip
-                # path only; a mesh program keeps its own placement)
-                step_fn = self._packed_step
-                if self.mesh is None:
-                    from sitewhere_tpu.runtime.devguard import FALLBACK
-
-                    if self.breaker.level >= FALLBACK:
-                        fallback = self._cpu_packed_step()
-                        if fallback is not None:
-                            step_fn = fallback
-                            self._m_fault["cpu_fallback_steps"].inc()
-                wd = self.watchdog.begin(plan)
-                self._wd_tokens[id(plan)] = wd
-                try:
-                    if faults.device_active():
-                        # fires against the retained HOST copies, so the
-                        # injection point is mesh-agnostic — per-shard
-                        # containment drills rely on it firing here too
-                        faults.device_fire("device.dispatch",
-                                           values=plan.packed_f,
-                                           valid=plan.packed_i[0] != 0)
-                    with trace.span("step.dispatch").tag(
-                            "rows", plan.n_events):
-                        new_ps, oi, metrics, present = step_fn(
-                            tables, ps, bi, bf)
-                        self.state_manager.commit_packed(
-                            new_ps, present_now=present, read_epoch=epoch)
-                    # Start the egress fetches NOW, asynchronously: the
-                    # copies complete in the background while later plans
-                    # step, so the blocking np.asarray at the window's
-                    # egress end finds the bytes already on the host
-                    # (≈0 RTT in steady state).
-                    start_host_copy(oi, metrics,
-                                    on_error=self._on_host_copy_error)
-                except Exception as e:
-                    self._wd_end(plan)
-                    self._contain_step_failure(plan, e, replay_depth,
-                                               trace)
-                    return
-                dt = time.perf_counter() - t_dispatch
-                self._m_stage["dispatch"].observe(dt)
-                plan.dispatch_s = dt   # flight-record stage attribution
-                self._window_step(
-                    plan,
-                    PackedView(oi, metrics, present,
-                               on_fetch=self._m_host_syncs.inc),
-                    replay_depth, trace)
+            wait.__exit__(None, None, None)
+            failure = None
+            with self._m_stage["dispatch"].time(seq=plan.seq) as span:
+                if plan.packed_i is not None:
+                    try:
+                        out = self._step_packed(plan, trace)
+                    except _StepFailed as e:
+                        failure = e.__cause__
+                        span.discard()   # the timer counts dispatched steps
+                else:
+                    out = self._step_unpacked(plan, trace)
+            if failure is not None:
+                self._wd_end(plan)
+                self._contain_step_failure(plan, failure, replay_depth,
+                                           trace)
                 return
-            batch = plan.batch
-            state = self.state_manager.current
-            if self.mesh is not None:
-                from sitewhere_tpu.pipeline.sharded import place_batch
-
-                registry = self._placed("registry", self.registry_provider())
-                rules = self._placed("rules", self.rules_provider(),
-                                     replicated=True)
-                zones = self._placed("zones", self.zones_provider(),
-                                     replicated=True)
-                # State changes identity every commit, so caching would
-                # never hit; device_put is a no-op once the epoch already
-                # carries the mesh sharding (i.e. after the first step).
-                from sitewhere_tpu.pipeline.sharded import _specs_sharded
-
-                state = jax.tree_util.tree_map(
-                    self._mesh_put, state, _specs_sharded(state))
-                batch = place_batch(self.mesh, batch)
-            else:
-                registry = self.registry_provider()
-                rules = self.rules_provider()
-                zones = self.zones_provider()
-            with trace.span("step.dispatch").tag("rows", plan.n_events):
-                new_state, out = self._step(registry, state, rules, zones,
-                                            batch)
-                self.state_manager.commit(new_state,
-                                          present_now=out.present_now)
-            dt = time.perf_counter() - t_dispatch
-            self._m_stage["dispatch"].observe(dt)
-            plan.dispatch_s = dt
+            plan.dispatch_s = span.elapsed   # flight-record attribution
             self._window_step(plan, out, replay_depth, trace)
+
+    @hot_path
+    def _step_packed(self, plan: BatchPlan, trace):
+        """Launch the packed single step for ``plan`` and commit its
+        state (under ``_step_lock``, inside the dispatch stage); returns
+        the :class:`PackedView` over its outputs."""
+        from sitewhere_tpu.pipeline.packed import start_host_copy
+
+        tables = self._tables_packed()
+        epoch = self.state_manager.current_packed
+        ps = epoch
+        # staged pair (H2D already in flight) off the CPU backend; the
+        # raw numpy buffers otherwise (the jitted call then transfers
+        # synchronously)
+        bi, bf = plan.staged or (plan.packed_i, plan.packed_f)
+        if self.mesh is not None:
+            from sitewhere_tpu.pipeline.sharded import (
+                place_packed_batch,
+                place_packed_state,
+            )
+
+            if plan.staged is None:
+                bi, bf = place_packed_batch(self.mesh, bi, bf)
+            ps = place_packed_state(self.mesh, ps)
+        # breaker at FALLBACK: the chip is presumed dead — route the
+        # same jitted program to a CPU device (single-chip path only; a
+        # mesh program keeps its own placement)
+        step_fn = self._packed_step
+        if self.mesh is None:
+            from sitewhere_tpu.runtime.devguard import FALLBACK
+
+            if self.breaker.level >= FALLBACK:
+                fallback = self._cpu_packed_step()
+                if fallback is not None:
+                    step_fn = fallback
+                    self._m_fault["cpu_fallback_steps"].inc()
+        wd = self.watchdog.begin(plan)
+        self._wd_tokens[id(plan)] = wd
+        try:
+            if faults.device_active():
+                # fires against the retained HOST copies, so the
+                # injection point is mesh-agnostic — per-shard
+                # containment drills rely on it firing here too
+                faults.device_fire("device.dispatch",
+                                   values=plan.packed_f,
+                                   valid=plan.packed_i[0] != 0)
+            with trace.span("step.dispatch").tag("rows", plan.n_events):
+                new_ps, oi, metrics, present = step_fn(tables, ps, bi, bf)
+                self.state_manager.commit_packed(
+                    new_ps, present_now=present, read_epoch=epoch)
+            # Start the egress fetches NOW, asynchronously: the copies
+            # complete in the background while later plans step, so the
+            # blocking np.asarray at the window's egress end finds the
+            # bytes already on the host (≈0 RTT in steady state).
+            start_host_copy(oi, metrics, on_error=self._on_host_copy_error)
+        except Exception as e:
+            raise _StepFailed() from e
+        return self._packed_view(oi, metrics, present, plan.seq)
+
+    def _packed_view(self, oi, metrics, present, seq: int):
+        """A step's :class:`PackedView`, its one blocking fetch counted
+        (``pipeline.host_syncs``) and timed (``pipeline.device_wait_s``)."""
+        from sitewhere_tpu.pipeline.packed import PackedView
+
+        return PackedView(oi, metrics, present,
+                          on_fetch=self._m_host_syncs.inc,
+                          wait_timer=self._m_device_wait, seq=seq)
+
+    @hot_path
+    def _step_unpacked(self, plan: BatchPlan, trace):
+        """The unpacked fallback's single step (under ``_step_lock``,
+        inside the dispatch stage); returns its ``PipelineOutputs``."""
+        batch = plan.batch
+        state = self.state_manager.current
+        if self.mesh is not None:
+            from sitewhere_tpu.pipeline.sharded import place_batch
+
+            registry = self._placed("registry", self.registry_provider())
+            rules = self._placed("rules", self.rules_provider(),
+                                 replicated=True)
+            zones = self._placed("zones", self.zones_provider(),
+                                 replicated=True)
+            # State changes identity every commit, so caching would
+            # never hit; device_put is a no-op once the epoch already
+            # carries the mesh sharding (i.e. after the first step).
+            from sitewhere_tpu.pipeline.sharded import _specs_sharded
+
+            state = jax.tree_util.tree_map(
+                self._mesh_put, state, _specs_sharded(state))
+            batch = place_batch(self.mesh, batch)
+        else:
+            registry = self.registry_provider()
+            rules = self.rules_provider()
+            zones = self.zones_provider()
+        with trace.span("step.dispatch").tag("rows", plan.n_events):
+            new_state, out = self._step(registry, state, rules, zones,
+                                        batch)
+            self.state_manager.commit(new_state,
+                                      present_now=out.present_now)
+        return out
 
     def _contain_step_failure(self, plan: BatchPlan, exc,
                               replay_depth: int, trace) -> None:
@@ -2329,16 +2408,11 @@ class PipelineDispatcher(LifecycleComponent):
                 raise
         except Exception:
             return False
-        from sitewhere_tpu.pipeline.packed import (
-            PackedView,
-            start_host_copy,
-        )
+        from sitewhere_tpu.pipeline.packed import start_host_copy
 
         start_host_copy(oi, metrics, on_error=self._on_host_copy_error)
         self._window_step(
-            plan,
-            PackedView(oi, metrics, present,
-                       on_fetch=self._m_host_syncs.inc),
+            plan, self._packed_view(oi, metrics, present, plan.seq),
             replay_depth, trace)
         return True
 
@@ -2412,7 +2486,10 @@ class PipelineDispatcher(LifecycleComponent):
         THIS thread while the device computes.  Called under _step_lock."""
         self.steps += 1
         self._m_steps.inc()
-        self._inflight.append((plan, out, replay_depth, trace))
+        # the queue-entry stamp rides the item: inflight_wait is the
+        # interval to its pop in _egress_guarded, across threads
+        self._inflight.append((plan, out, replay_depth, trace,
+                               time.perf_counter()))
         if self._offloaded():
             self._m_inflight.set(len(self._inflight))
             self._egress_evt.set()
@@ -2469,9 +2546,11 @@ class PipelineDispatcher(LifecycleComponent):
         counted and flight-recorded (the crashed plan's record with its
         trace id, THEN the anomaly dump: the snapshot must contain the
         batch that died) no matter which thread ran it."""
+        self._m_stage["inflight_wait"].observe(
+            time.perf_counter() - item[4])
         try:
             try:
-                self._egress(*item)
+                self._egress(*item[:4])
             except Exception as e:
                 self.egress_failures += 1
                 self._m_egress_fail.inc()
@@ -2523,17 +2602,34 @@ class PipelineDispatcher(LifecycleComponent):
         # raise kills the egress WORKER mid-window; its supervisor
         # restarts the loop and the window's remaining plans still drain.
         faults.fire("dispatcher.egress")
-        t_egress = time.perf_counter()
         if trace is None:
             trace = _NOOP_TRACE
+        with self._m_stage["egress"].time(seq=plan.seq) as span:
+            lat = self._fan_out(plan, out, replay_depth, trace)
+        if self.flightrec is not None:
+            self._flight_record(plan, out, replay_depth, commit="ok",
+                                e2e_s=lat, egress_s=span.elapsed,
+                                trace=trace)
+
+    @hot_path
+    def _fan_out(self, plan: BatchPlan, out, replay_depth: int,
+                 trace) -> float:
+        """The egress stage's body (one ``pipeline.stage_egress_s``
+        span): fetch the step's outputs — the one place the host blocks
+        on the device, timed as ``pipeline.device_wait_s`` — then store,
+        fan out, re-inject.  Returns the plan's end-to-end latency."""
         host_cols = plan.host_cols
-        if not hasattr(out, "_fetch"):
-            # unpacked fallback: the as_numpy/np.asarray below IS a
-            # blocking device→host sync (packed/ring views count their
-            # own lazy fetch via on_fetch instead)
-            self._m_host_syncs.inc()
         with trace.span("egress.fetch-outputs"):
-            m = as_numpy(out.metrics)
+            if hasattr(out, "_fetch"):
+                # packed/ring views count and time their own lazy fetch
+                # (on_fetch / wait_timer), which this access triggers
+                m = as_numpy(out.metrics)
+            else:
+                # unpacked fallback: this as_numpy IS the blocking
+                # device→host sync
+                self._m_host_syncs.inc()
+                with self._m_device_wait.time(seq=plan.seq):
+                    m = as_numpy(out.metrics)
             # packed/ring views hand back the host mask memoized on the
             # shared fetch; only the unpacked fallback still pays a
             # device→host conversion here
@@ -2685,12 +2781,7 @@ class PipelineDispatcher(LifecycleComponent):
             lat, trace_id=(trace.trace_id if trace.sampled else None))
         self._m_queue.set(self.batcher.pending)
         self._m_inflight.set(len(self._inflight))
-        egress_dt = time.perf_counter() - t_egress
-        self._m_stage["egress"].observe(egress_dt)
-        if self.flightrec is not None:
-            self._flight_record(plan, out, replay_depth, commit="ok",
-                                e2e_s=lat, egress_s=egress_dt,
-                                trace=trace)
+        return lat
 
     def _columns(self, host_cols: Dict[str, np.ndarray], out):
         """Egress columns as a zero-copy view (see :class:`EgressColumns`)

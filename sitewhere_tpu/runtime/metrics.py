@@ -89,9 +89,15 @@ class Timer:
     ``bisect.insort`` kept the reservoir sorted on every observation:
     O(n) memmove per sample *while holding the lock*, i.e. ~4096 element
     moves on the hot path per event at steady state.
+
+    A timer is also a span: :meth:`time` times a lexical region into the
+    timer AND, while a ``jax.profiler`` session is open, leaves a
+    host-plane event under the timer's ``name`` on the profiler's clock
+    (the same clock as the device trace).
     """
 
-    def __init__(self, reservoir: int = 4096):
+    def __init__(self, reservoir: int = 4096, name: str = "timer"):
+        self.name = name
         self.reservoir = reservoir
         self._samples: collections.deque = collections.deque(maxlen=reservoir)
         self._sorted: Optional[List[float]] = None
@@ -106,19 +112,11 @@ class Timer:
             self._samples.append(seconds)
             self._sorted = None
 
-    def time(self):
-        timer = self
-
-        class _Ctx:
-            def __enter__(self):
-                self.t0 = time.perf_counter()
-                return self
-
-            def __exit__(self, *exc):
-                timer.observe(time.perf_counter() - self.t0)
-                return False
-
-        return _Ctx()
+    def time(self, **tags) -> "TimedSpan":
+        """Context manager over one region: ``with timer.time(seq=7):``.
+        ``tags`` ride the profiler event (spans of one plan share
+        ``seq``); the registry side takes the duration only."""
+        return TimedSpan(self, tags)
 
     def percentile(self, q: float) -> float:
         with self._lock:
@@ -132,6 +130,53 @@ class Timer:
     @property
     def mean(self) -> float:
         return self.total / self.count if self.count else 0.0
+
+
+_TRACE_ANNOTATION = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so this
+    module stays importable before (and without) JAX."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+class TimedSpan:
+    """One timed region of a :class:`Timer` (``Timer.time()``): on enter
+    it opens a profiler annotation named after the timer and reads the
+    clock; on exit it closes both and observes — also when the region
+    raised, the time was spent.  ``elapsed`` holds the duration after
+    exit; :meth:`discard` keeps the profiler event but drops the
+    observation (a region that turned out to hold none of the timed
+    work, e.g. an intake that emitted no plan)."""
+
+    __slots__ = ("_timer", "_ann", "_t0", "_keep", "elapsed")
+
+    def __init__(self, timer: Timer, tags: dict):
+        self._timer = timer
+        self._ann = _trace_annotation()(timer.name, **tags)
+        self._keep = True
+        self.elapsed = 0.0
+
+    def __enter__(self) -> "TimedSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.elapsed = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if self._keep:
+            self._timer.observe(self.elapsed)
+        return False
+
+    def discard(self) -> None:
+        self._keep = False
 
 
 # Fixed latency buckets (seconds): 25µs…10s around the <10ms p99 target.
@@ -218,8 +263,11 @@ class MetricsRegistry:
 
     def timer(self, name: str) -> Timer:
         with self._lock:
-            return self._timers.setdefault(sanitize_metric_name(name),
-                                           Timer())
+            name = sanitize_metric_name(name)
+            t = self._timers.get(name)
+            if t is None:
+                t = self._timers[name] = Timer(name=name)
+            return t
 
     def histogram(self, name: str,
                   buckets: Optional[Tuple[float, ...]] = None) -> Histogram:

@@ -373,6 +373,50 @@ class TestDispatcherContainment:
             inst.stop()
             inst.terminate()
 
+    def test_loop_idle_tick_never_waits_for_the_step_lock(self, tmp_path):
+        """The loop thread is the watchdog's only observer, so its idle
+        housekeeping (inline drain, offset commit) may try the step lock
+        but never wait for it: a dispatch that takes the lock AFTER the
+        tick's probe — here, while the loop thread sits in its deadline
+        poll — may be the wedged one.  The race is forced, not hoped
+        for: the loop is parked inside ``_take``, the lock is taken,
+        the loop released; it must keep checking while the lock is held."""
+        import threading
+
+        from sitewhere_tpu.instance import Instance
+
+        inst = Instance(_instance_config(tmp_path))
+        inst.start()
+        try:
+            d = inst.dispatcher
+            assert not d._offloaded()   # CPU default: the drain is inline
+            parked, go = threading.Event(), threading.Event()
+            checks = []
+            take, check = d._take, d.watchdog.check
+
+            def gated_take(intake):
+                if (threading.current_thread() is d._thread
+                        and not parked.is_set()):
+                    parked.set()
+                    assert go.wait(5)
+                return take(intake)
+
+            def counted_check(*a, **kw):
+                checks.append(time.monotonic())
+                return check(*a, **kw)
+
+            d._take, d.watchdog.check = gated_take, counted_check
+            assert parked.wait(5)
+            with d._step_lock:          # the "wedged dispatch"
+                go.set()
+                t0 = time.monotonic()
+                time.sleep(0.25)
+                seen = sum(t >= t0 for t in checks)
+            assert seen >= 5, f"loop thread made {seen} checks in 0.25 s"
+        finally:
+            inst.stop()
+            inst.terminate()
+
     def test_breaker_trip_rides_and_releases_the_overload_ladder(
             self, tmp_path):
         """The breaker trip forces DEGRADED with its own driver tag; the
