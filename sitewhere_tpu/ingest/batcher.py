@@ -582,6 +582,13 @@ class Batcher:
             self._m_copied = metrics.counter("pipeline.bytes_copied.batch")
         else:
             self._m_copied = None
+        # rows in shard s's segment of every emitted plan (their sum is
+        # ingest.rows_emitted): the skew a mesh pays for — a plan goes
+        # out when its FULLEST segment fills.  Mesh only.
+        self._m_shard_rows = (
+            [metrics.counter(f"ingest.shard_rows_emitted.{s}")
+             for s in range(n_shards)]
+            if metrics is not None and n_shards > 1 else None)
 
     @property
     def deadline_s(self) -> float:
@@ -958,6 +965,8 @@ class Batcher:
             res = ch.reserved
             n += ch.length
             self._counts[s] -= ch.length
+            if self._m_shard_rows is not None:
+                self._m_shard_rows[s].inc(ch.length)
         host_cols = res.finalize_adopted(n)
         now, wait = self._emit_tail(n, reason)
         return BatchPlan(
@@ -1036,6 +1045,8 @@ class Batcher:
                     q.popleft()
             self._counts[s] -= filled
             n += filled
+            if self._m_shard_rows is not None:
+                self._m_shard_rows[s].inc(filled)
         self._count_copied(n * _ROW_BYTES)
 
         now, wait = self._emit_tail(n, reason)

@@ -1042,19 +1042,31 @@ class Instance(LifecycleComponent):
     def run_device_profile(self, iters: int = 16,
                            repeats: int = 3) -> dict:
         """On-demand device-stage calibration (the ``profile_step.py``
-        fori-chain methodology at this instance's width/capacity):
+        fori-chain methodology at the size of the step ONE chip runs):
         records ``device.stage_ms.*`` histogram samples and returns the
         stage medians.  Compiles one probe chain per stage — seconds of
-        work; REST exposes it admin-only for exactly that reason."""
+        work; REST exposes it admin-only for exactly that reason.
+
+        On a mesh (``pipeline.n_shards`` > 1) every chip steps its own
+        shard — ``width // n_shards`` batch rows against
+        ``registry_capacity // n_shards`` registry rows — so that share
+        is what the probe runs, on one chip (the default device), and
+        what the watchdog's budgets are calibrated from.  The whole
+        registry on one chip is a step no chip of the mesh runs, and at
+        the capacities a mesh exists for it does not fit one.  The
+        collective (a psum of a few hundred scalars) is not in the
+        probe.  With one shard the share is the whole."""
         from sitewhere_tpu.pipeline.telemetry import profile_device_stages
 
         # the LIVE table shapes: rule/zone eval cost is shape-driven, so
         # the probes must run at this deployment's actual capacities
         rules = self.rules.publish()
         zones = self.mirror.publish_zones()
+        n_shards = int(self.config["pipeline.n_shards"])
         result = profile_device_stages(
-            width=int(self.config["pipeline.width"]),
-            capacity=int(self.config["pipeline.registry_capacity"]),
+            width=int(self.config["pipeline.width"]) // n_shards,
+            capacity=(int(self.config["pipeline.registry_capacity"])
+                      // n_shards),
             rules_capacity=int(rules.threshold.shape[0]),
             zones_capacity=int(zones.nvert.shape[0]),
             iters=iters, repeats=repeats, metrics=self.metrics)

@@ -63,7 +63,8 @@ A plan's life on the host is a chain of timers, each also a profiler
 span of the same name (``runtime/metrics.py Timer.time``; spans of one
 plan share ``seq``): ``pipeline.stage_decode_s`` and
 ``ingest.journal_append_s`` per payload; per plan ``stage_batch_s`` →
-``stage_h2d_s`` (unpacked plans) → ``stage_dispatch_wait_s`` (full
+``stage_h2d_s`` (unpacked plans) or ``stage_place_s`` (packed plans on
+a mesh: the per-shard placement) → ``stage_dispatch_wait_s`` (full
 egress window + step-lock wait) → ``stage_dispatch_s`` →
 ``stage_inflight_wait_s`` (queued for egress) → ``stage_egress_s``, of
 which ``pipeline.device_wait_s`` is the part blocked on the device and
@@ -498,6 +499,10 @@ class PipelineDispatcher(LifecycleComponent):
                       # egress worker (or an inline drain) pops it
                       "inflight_wait")
         }
+        if mesh is not None:
+            # a packed plan's per-shard placement (_stage_packed: two
+            # arrays x n_shards device_puts); never observed on one chip
+            self._m_stage["place"] = metrics.timer("pipeline.stage_place_s")
         # The host BLOCKED on the device finishing a step and on its D2H
         # (the views' blocking fetch in pipeline/packed.py, the unpacked
         # fallback's metrics fetch in _egress): a child of the egress
@@ -714,17 +719,25 @@ class PipelineDispatcher(LifecycleComponent):
         for plan in plans:
             self._run_plan(plan, replay_depth)
 
-    def _stage_packed(self, bi, bf):
+    def _stage_packed(self, bi, bf, seq: Optional[int] = None):
         """One packed batch placed where the jitted programs take it:
-        sharded over the mesh, or ``device_put`` ahead of its step (None
-        on the CPU backend — the jitted call then transfers
-        synchronously).  The per-shard device_put is asynchronous, so a
-        burst's later placements overlap earlier steps exactly like the
-        single-chip staging path."""
+        ``device_put`` ahead of its step on one chip (None on the CPU
+        backend — the jitted call then transfers synchronously), or on a
+        mesh split along its width into ``n_shards`` segments, each put
+        on the chip that owns those devices' registry rows
+        (``place_packed_batch``: two arrays x ``n_shards`` transfers).
+        The transfers are asynchronous, so a burst's later placements
+        overlap earlier steps on either path.  The mesh placement of a
+        plan (``seq`` given; the boot warm-up passes none) is timed as
+        ``pipeline.stage_place_s`` — the host's time to issue the
+        transfers, one observation a packed plan."""
         if self.mesh is not None:
             from sitewhere_tpu.pipeline.sharded import place_packed_batch
 
-            return place_packed_batch(self.mesh, bi, bf)
+            if seq is None:
+                return place_packed_batch(self.mesh, bi, bf)
+            with self._m_stage["place"].time(seq=seq):
+                return place_packed_batch(self.mesh, bi, bf)
         from sitewhere_tpu.pipeline.packed import stage_packed_batch
 
         return stage_packed_batch(bi, bf)
@@ -733,7 +746,8 @@ class PipelineDispatcher(LifecycleComponent):
         """Start the async H2D copy of a packed plan (double-buffer front
         half, :meth:`_stage_packed`)."""
         if plan.staged is None and plan.packed_i is not None:
-            plan.staged = self._stage_packed(plan.packed_i, plan.packed_f)
+            plan.staged = self._stage_packed(plan.packed_i, plan.packed_f,
+                                             seq=plan.seq)
             if plan.staged is not None:
                 self._m_bytes["h2d"].inc(
                     plan.packed_i.nbytes + plan.packed_f.nbytes)

@@ -815,3 +815,106 @@ def test_recovery_metrics_exported_on_restore(tmp_path):
     finally:
         b.stop()
         b.terminate()
+
+
+@pytest.mark.parametrize("version", [2, 1])
+def test_stores_section_restores_in_both_layouts(tmp_path, version):
+    """Version 2 holds each store's containers pickled on their own
+    under that store's lock (``{store: bytes}``); a version-1 section
+    (``{store: {attr: container}}``, written before) still restores."""
+    import os
+    import pickle
+
+    from sitewhere_tpu.runtime.checkpoint import read_framed, write_framed
+
+    a = Instance(_cfg(tmp_path))
+    a.start()
+    a.device_management.create_device_type(token="sensor", name="Sensor")
+    a.device_management.create_device(token="dev-a", device_type="sensor",
+                                      metadata={"site": "north"})
+    a.device_management.create_device_assignment(device="dev-a")
+    a.checkpointer.save()
+    gen = a.checkpointer.generation
+    path = os.path.join(a.checkpointer.dir, f"stores-{gen:08d}.swsnap")
+    header, payload = read_framed(path, component="stores")
+    stores = pickle.loads(payload)
+    assert header["version"] == 2
+    assert all(isinstance(v, bytes) for k, v in stores.items()
+               if k != "__engines__")
+    assert "dev-a" in pickle.loads(stores["device_management"])["devices"]
+    if version == 1:
+        old = {k: (v if k == "__engines__" else pickle.loads(v))
+               for k, v in stores.items()}
+        header["version"] = 1
+        write_framed(path, header, pickle.dumps(old, protocol=4))
+    a.ingest_journal.close()
+    a.dead_letters.close()
+    del a  # simulated kill
+
+    b = Instance(_cfg(tmp_path))
+    assert b.restored
+    try:
+        dev = b.device_management.get_device("dev-a")
+        assert dev is not None and dev.metadata == {"site": "north"}
+        assert b.device_management.get_active_assignment("dev-a") is not None
+    finally:
+        b.terminate()
+
+
+def test_a_torn_store_inside_the_stores_section_fails_the_generation(
+        tmp_path):
+    """The inner pickles are read while the generation is validated, not
+    while it is applied: an unreadable one falls back to the previous
+    generation like any torn section."""
+    import os
+    import pickle
+
+    from sitewhere_tpu.runtime.checkpoint import read_framed, write_framed
+
+    a = Instance(_cfg(tmp_path))
+    a.start()
+    a.device_management.create_device_type(token="sensor", name="Sensor")
+    a.device_management.create_device(token="dev-a", device_type="sensor")
+    a.checkpointer.save()
+    good = a.checkpointer.generation
+    a.device_management.create_device(token="dev-b", device_type="sensor")
+    a.checkpointer.save()
+    gen = a.checkpointer.generation
+    path = os.path.join(a.checkpointer.dir, f"stores-{gen:08d}.swsnap")
+    header, payload = read_framed(path, component="stores")
+    stores = pickle.loads(payload)
+    stores["device_management"] = stores["device_management"][:-7]
+    write_framed(path, header, pickle.dumps(stores, protocol=4))
+    a.ingest_journal.close()
+    a.dead_letters.close()
+    del a
+
+    b = Instance(_cfg(tmp_path))
+    try:
+        assert b.restored and b.checkpointer.restored_generation == good
+        assert b.device_management.get_device("dev-a") is not None
+    finally:
+        b.terminate()
+
+
+def test_identity_file_is_what_json_dump_wrote(tmp_path):
+    """``IdentityMap.save`` encodes in one pass of the C encoder; the
+    bytes are ``json.dump``'s, and load into a fresh map round-trips."""
+    import io
+
+    from sitewhere_tpu.ids import IdentityMap
+
+    im = IdentityMap()
+    for i in range(300):
+        im.device.mint(f"d-{i}")
+    im.device.free("d-7")
+    im.mtype.mint("temp")
+    path = str(tmp_path / "identity.json")
+    im.save(path)
+    want = io.StringIO()
+    json.dump({n: s.to_dict() for n, s in im.spaces.items()}, want)
+    with open(path) as f:
+        assert f.read() == want.getvalue()
+    back = IdentityMap.load(path)
+    assert back.device.lookup("d-299") == im.device.lookup("d-299")
+    assert back.device.lookup("d-7") == -1 and back.device.mint("new") == 7

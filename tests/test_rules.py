@@ -440,3 +440,32 @@ class TestRulebenchSmoke:
         assert result["recompiles_during_swaps"] == 0
         table = mod._render(result)
         assert "compiled shapes" in table
+
+
+def test_attribute_snapshot_holds_only_the_minted_columns():
+    """Columns are minted densely and nothing else is written, so a
+    store with no columns saves none of its capacity-sized table, and a
+    restore over a store that held values leaves no stale one behind."""
+    from sitewhere_tpu.ids import NULL_ID
+    from sitewhere_tpu.rules.enrich import MAX_ATTR_COLUMNS, AttributeStore
+
+    src = AttributeStore(device_capacity=64)
+    cols, arrays = src.snapshot_payload()
+    assert arrays["device"].shape == (64, 0)
+    assert arrays["asset"].shape[1] == 0
+    src.set("device", 5, "tier", 3)
+    src.set("device", 6, "zone", 9)
+    cols, arrays = src.snapshot_payload()
+    assert arrays["device"].shape == (64, 2)
+    assert arrays["asset"].shape[1] == 0
+
+    dst = AttributeStore(device_capacity=64)
+    dst.set("device", 7, "old", 1)
+    dst.set("asset", 2, "grade", 4)
+    dst.restore_payload(cols, arrays)
+    assert dst.columns("device") == {"tier": 0, "zone": 1}
+    assert dst.columns("asset") == {}
+    host = dst._host["device"]
+    assert host.shape == (64, MAX_ATTR_COLUMNS)
+    assert host[5, 0] == 3 and host[6, 1] == 9
+    assert host[7, 0] == NULL_ID and (dst._host["asset"] == NULL_ID).all()
