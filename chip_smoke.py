@@ -565,9 +565,14 @@ def run_leg(checks: Checks, meter: CompileMeter, *, capacity: int,
                      fault["breaker"]["levelName"] == "chained"
                      and fault["breaker"]["trips"] == 0,
                      json.dumps(fault["breaker"]))
-        checks.check(f"{tag}: watchdog never tripped",
-                     fault["watchdog"]["softTrips"] == 0
-                     and fault["watchdog"]["hardTrips"] == 0
+        # a SOFT trip is a flight record, not a fault: since the step
+        # costs the batch the calibrated soft budget (50 x the probe's
+        # device stage, ~0.9 s) is about the age of the oldest of sixteen
+        # plans queued for egress, so a burst of column batches may trip
+        # it (PERF.md §6, ROADMAP D12); a hard trip marks the tier
+        # unhealthy and fails here
+        checks.check(f"{tag}: watchdog never tripped hard",
+                     fault["watchdog"]["hardTrips"] == 0
                      and not fault["watchdog"]["unhealthy"],
                      json.dumps(fault["watchdog"]))
         checks.equal(f"{tag}: quarantined devices",
@@ -605,7 +610,7 @@ def run_leg(checks: Checks, meter: CompileMeter, *, capacity: int,
             checks.equal(f"{tag}: state sharded over devices", placed,
                          n_shards)
             ps = inst.device_state.current_packed
-            share = (ps.si.nbytes + ps.sf.nbytes) // n_shards
+            share = ps.rows.nbytes // n_shards
             mem = memory_doc()[:n_shards]
             if mem:
                 checks.check(

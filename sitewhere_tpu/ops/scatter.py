@@ -7,13 +7,27 @@ step many events for one device land in the same batch, so each slot needs
 the row with the newest ``(ts_s, ts_ns)`` key, tie-broken by batch row index
 (highest row wins) so exactly ONE event row writes all payload columns.
 
-Implementation is SORT-based, not scatter-based: XLA lowers scatters with
-duplicate indices to a serialized update loop on TPU, which measured 13x
-slower than this design at pipeline widths (131072 rows -> 16384 slots,
-13.7 ms vs 1.1 ms on v5e).  The stable multi-key sort groups rows by slot
-with newest-last, segment boundaries mark each slot's winning row, the
-winner map is written with UNIQUE indices (the fast scatter path), and
-payload columns are applied with gathers — every op on the parallel path.
+Everything here is sized by the BATCH, never by the table it updates:
+
+1. :func:`winning_rows` — a stable multi-key sort groups the B rows by
+   slot with the newest last; the run boundaries of the sorted rows ARE
+   the winners, and they go back to batch order as a ``bool[B]`` through
+   the sort's own permutation.  No ``[capacity]`` map is built.
+2. the slots' current time keys are GATHERED at the batch's ids (B rows),
+   :func:`newer_or_equal` makes the newest-wins comparison on B rows,
+3. the rows that win are SCATTERED back with ``unique_indices=True,
+   mode="drop"``: at most one row per slot survives step 1, and losers
+   and masked rows are aimed at distinct out-of-range targets
+   (:func:`drop_targets`).  Unique indices keep the scatter off XLA's
+   serialized duplicate-index update loop on the TPU — the reason the
+   winners are found by sorting in the first place.
+
+Where several batch rows change different columns of one slot's row,
+:func:`merge_rows_by_id` merges them first (a sort and a running sum, on
+batch-sized arrays), so that one whole row per slot is scattered.
+
+One algorithm on every backend and for every table shape: its cost
+follows the batch it is given.
 """
 
 from __future__ import annotations
@@ -25,18 +39,22 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _winner_rows_sort(
+def winning_rows(
     ids: jax.Array,
     keys: Sequence[jax.Array],
     mask: jax.Array,
     capacity: int,
 ) -> jax.Array:
-    """Sort-based winner map (the TPU fast path).
+    """``bool[B]``: True at the ONE batch row that wins its slot — the
+    largest lexicographic ``keys`` tuple among the masked rows targeting
+    the slot, the highest row on exact ties.  Rows with ``mask=False``
+    or ids outside ``[0, capacity)`` never win.
 
-    The stable ascending sort on ``(id, *keys)`` leaves each slot's winning
-    row LAST in its run (stability preserves batch order among equal keys,
-    giving the highest-row tie-break); run boundaries then identify
-    winners, which scatter into the slot map with unique indices.
+    The stable ascending sort on ``(id, *keys)`` leaves each slot's
+    winning row LAST in its run (stability preserves batch order among
+    equal keys, giving the highest-row tie-break); the sorted row
+    indices are a permutation of ``arange(B)``, so the boundary flags
+    scatter back to batch order with unique indices.
     """
     b = ids.shape[0]
     mask = mask & (ids >= 0) & (ids < capacity)
@@ -48,119 +66,68 @@ def _winner_rows_sort(
     eff_s, rows_s = sorted_ops[0], sorted_ops[-1]
     nxt = jnp.concatenate([eff_s[1:], jnp.full((1,), capacity + 1, jnp.int32)])
     boundary = (eff_s != nxt) & (eff_s < capacity)
-    win_ids = jnp.where(boundary, eff_s, capacity)
-    return jnp.full((capacity,), -1, jnp.int32).at[win_ids].set(
-        rows_s, mode="drop", unique_indices=True
-    )
+    return jnp.zeros((b,), bool).at[rows_s].set(boundary, unique_indices=True)
 
 
-def _winner_rows_scatter(
-    ids: jax.Array,
-    keys: Sequence[jax.Array],
-    mask: jax.Array,
-    capacity: int,
+def newer_or_equal(
+    ts_s: jax.Array, ts_ns: jax.Array, cur_s: jax.Array, cur_ns: jax.Array
 ) -> jax.Array:
-    """Scatter-based winner map (the CPU fast path).
+    """The newest-wins comparison: an event at least as new as the slot's
+    current ``(ts_s, ts_ns)`` key replaces it (events win exact ties, the
+    same contract per-partition ordering gives the reference)."""
+    return (ts_s > cur_s) | ((ts_s == cur_s) & (ts_ns >= cur_ns))
 
-    Lexicographic multi-pass scatter-max: pass k keeps the rows whose key
-    equals the per-slot max among rows that survived passes 0..k-1; a
-    final scatter-max of the row index breaks remaining ties (highest row
-    wins).  XLA CPU runs duplicate-index scatters well but variadic sorts
-    poorly — the mirror image of TPU (7.1 ms vs 0.5 ms at width 16k for
-    the sort form on CPU; 13.7 ms vs 1.1 ms for the scatter form on v5e).
-    """
-    won = mask & (ids >= 0) & (ids < capacity)
-    clip_ids = jnp.clip(ids, 0, capacity - 1)
-    key_min = jnp.iinfo(jnp.int32).min
-    for k in keys:
-        eff = jnp.where(won, ids, capacity)
-        mx = jnp.full((capacity,), key_min, jnp.int32).at[eff].max(
-            k, mode="drop")
-        won = won & (k == mx[clip_ids])
+
+def drop_targets(ids: jax.Array, write: jax.Array, capacity: int) -> jax.Array:
+    """Scatter targets for a ``unique_indices=True, mode="drop"`` scatter:
+    ``ids`` where ``write``, else an out-of-range index of the row's own
+    (``capacity + row``), so the index vector is unique as promised even
+    among the rows that are dropped."""
     rows = jnp.arange(ids.shape[0], dtype=jnp.int32)
-    eff = jnp.where(won, ids, capacity)
-    return jnp.full((capacity,), -1, jnp.int32).at[eff].max(rows, mode="drop")
+    return jnp.where(write, ids, capacity + rows).astype(jnp.int32)
 
 
-def winner_rows_by_keys(
-    ids: jax.Array,
-    keys: Sequence[jax.Array],
-    mask: jax.Array,
-    capacity: int,
-) -> jax.Array:
-    """Per-slot winning batch row (max lexicographic key, highest row on ties).
+def merge_rows_by_id(
+    ids: jax.Array, base: jax.Array, change: jax.Array, capacity: int
+) -> Tuple[jax.Array, jax.Array]:
+    """Sum the batch rows' ``change [B, W]`` (wrapping int32) per id and
+    add each id's total to its ``base [B, W]`` row, all on batch-sized
+    arrays: a sort by id brings an id's rows together, the running sum
+    down the sorted rows less its value at the previous id's last row is
+    the id's total, and the LAST row of each run carries ``base +
+    total``.  ``base`` must hold the same row wherever ``ids`` agree
+    (rows gathered at ``ids`` do).
 
-    Returns ``int32[capacity]`` — the batch row index whose ``keys`` tuple
-    is largest among masked rows targeting each slot, or ``-1`` for slots
-    no masked row targets.  Rows with out-of-range ids are dropped.
-
-    Backend-adaptive (chosen at trace time): sort-based on TPU, where
-    sorts are native and duplicate-index scatters serialize; scatter-based
-    everywhere else, where the opposite holds.
+    Returns ``(targets int32[B], merged int32[B, W])`` in sorted order:
+    ``targets`` is the id at each run's last row and a distinct
+    out-of-range index elsewhere (rows with ids outside ``[0, capacity)``
+    included), ready for ONE ``unique_indices=True, mode="drop"``
+    scatter of whole rows.
     """
-    if jax.default_backend() == "tpu":
-        return _winner_rows_sort(ids, keys, mask, capacity)
-    return _winner_rows_scatter(ids, keys, mask, capacity)
+    b = ids.shape[0]
+    pos = jnp.arange(b, dtype=jnp.int32)
+    in_range = (ids >= 0) & (ids < capacity)
+    ids_s, order = lax.sort(
+        (jnp.where(in_range, ids, capacity).astype(jnp.int32), pos),
+        num_keys=1, is_stable=True)
+    nxt = jnp.concatenate([ids_s[1:], jnp.full((1,), capacity + 1, jnp.int32)])
+    last = (ids_s != nxt) & (ids_s < capacity)
+    running = jnp.cumsum(change[order], axis=0, dtype=jnp.int32)
+    prev_last = jnp.concatenate([
+        jnp.full((1,), -1, jnp.int32),
+        lax.cummax(jnp.where(last, pos, -1))[:-1]])
+    before = jnp.where((prev_last >= 0)[:, None],
+                       running[jnp.maximum(prev_last, 0)], 0)
+    return (jnp.where(last, ids_s, capacity + pos),
+            base[order] + (running - before))
 
 
-def winner_rows(
-    ids: jax.Array,
-    ts_s: jax.Array,
-    ts_ns: jax.Array,
-    mask: jax.Array,
-    capacity: int,
-) -> jax.Array:
-    """Per-slot winning batch row (newest ``(ts_s, ts_ns)``, highest row on
-    ties) — the two-part-time-key form of :func:`winner_rows_by_keys`."""
-    return winner_rows_by_keys(ids, (ts_s, ts_ns), mask, capacity)
-
-
-def apply_winners(
-    slot_row: jax.Array,
-    cur_ts_s: jax.Array,
-    cur_ts_ns: jax.Array,
-    cur_payload: Sequence[jax.Array],
-    ts_s: jax.Array,
-    ts_ns: jax.Array,
-    payload: Sequence[jax.Array],
-) -> Tuple[jax.Array, jax.Array, Tuple[jax.Array, ...]]:
-    """Apply a :func:`winner_rows` map: update slots whose winning event is
-    at least as new as the slot's current key (events win exact ties, the
-    same contract per-partition ordering gives the reference).
-
-    The time keys and payload columns are gathered in dtype-grouped packs
-    (one multi-column gather per dtype) — separate [B]-sized gathers cost
-    ~1 ms each at pipeline widths on v5e, packed ones barely more than one.
-    """
-    capacity = cur_ts_s.shape[0]
-    has = slot_row >= 0
-    wr = jnp.clip(slot_row, 0)
-
-    b = ts_s.shape[0]
-    items = [("__ts", ts_s.reshape(b, 1)), ("__ns", ts_ns.reshape(b, 1))]
-    items += [(i, val.reshape(b, -1)) for i, val in enumerate(payload)]
-    groups: dict = {}
-    for key, arr in items:
-        groups.setdefault(jnp.dtype(arr.dtype), []).append((key, arr))
-    gathered = {}
-    for _, lst in groups.items():
-        packed = jnp.concatenate([a for _, a in lst], axis=1)[wr]  # [D, k]
-        off = 0
-        for key, a in lst:
-            gathered[key] = packed[:, off:off + a.shape[1]]
-            off += a.shape[1]
-
-    w_s = gathered["__ts"][:, 0]
-    w_ns = gathered["__ns"][:, 0]
-    newer = has & ((w_s > cur_ts_s) | ((w_s == cur_ts_s) & (w_ns >= cur_ts_ns)))
-    new_s = jnp.where(newer, w_s, cur_ts_s)
-    new_ns = jnp.where(newer, w_ns, cur_ts_ns)
-    out = []
-    for i, (cur, val) in enumerate(zip(cur_payload, payload)):
-        nd = jnp.reshape(newer, (capacity,) + (1,) * (val.ndim - 1))
-        win = gathered[i].reshape((capacity,) + val.shape[1:]).astype(val.dtype)
-        out.append(jnp.where(nd, win, cur))
-    return new_s, new_ns, tuple(out)
+def set_rows(col: jax.Array, targets, vals) -> jax.Array:
+    """``col`` with ``vals`` written at :func:`drop_targets` (an index
+    vector, or a tuple of them for a ``[D, M]`` column): the one
+    unique-index, out-of-range-dropping scatter everything here ends in."""
+    return col.at[targets].set(
+        jnp.asarray(vals, col.dtype), mode="drop", unique_indices=True)
 
 
 def scatter_last_by_time(
@@ -192,9 +159,16 @@ def scatter_last_by_time(
             f"payload arity mismatch: {len(cur_payload)} state arrays vs "
             f"{len(payload)} event arrays (pass tuples, not bare arrays)"
         )
-    slot_row = winner_rows(ids, ts_s, ts_ns, mask, cur_ts_s.shape[0])
-    return apply_winners(
-        slot_row, cur_ts_s, cur_ts_ns, cur_payload, ts_s, ts_ns, payload
+    capacity = cur_ts_s.shape[0]
+    safe = jnp.clip(ids, 0, capacity - 1)
+    write = winning_rows(ids, (ts_s, ts_ns), mask, capacity) & newer_or_equal(
+        ts_s, ts_ns, cur_ts_s[safe], cur_ts_ns[safe])
+    tgt = drop_targets(ids, write, capacity)
+    return (
+        set_rows(cur_ts_s, tgt, ts_s),
+        set_rows(cur_ts_ns, tgt, ts_ns),
+        tuple(set_rows(cur, tgt, val)
+              for cur, val in zip(cur_payload, payload)),
     )
 
 
@@ -213,17 +187,11 @@ def scatter_max_by_key(
             f"{len(payload)} event arrays (pass tuples, not bare arrays)"
         )
     capacity = cur_key.shape[0]
-    slot_row = winner_rows_by_keys(ids, (key,), mask, capacity)
-    has = slot_row >= 0
-    wr = jnp.clip(slot_row, 0)
-    w_key = key[wr]
-    newer = has & (w_key >= cur_key)
-    new_key = jnp.where(newer, w_key, cur_key)
-    out = []
-    for cur, val in zip(cur_payload, payload):
-        nd = jnp.reshape(newer, (capacity,) + (1,) * (val.ndim - 1))
-        out.append(jnp.where(nd, val[wr], cur))
-    return new_key, tuple(out)
+    safe = jnp.clip(ids, 0, capacity - 1)
+    write = winning_rows(ids, (key,), mask, capacity) & (key >= cur_key[safe])
+    tgt = drop_targets(ids, write, capacity)
+    return set_rows(cur_key, tgt, key), tuple(
+        set_rows(cur, tgt, val) for cur, val in zip(cur_payload, payload))
 
 
 def bincount_fixed(ids: jax.Array, mask: jax.Array, length: int) -> jax.Array:

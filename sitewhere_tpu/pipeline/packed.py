@@ -6,18 +6,21 @@ leaves (Registry 9 + DeviceState 16 + RuleTable 10 + ZoneTable 8 +
 EventBatch 16) and ~50 output leaves per call.  This module packs the
 step's interface into ELEVEN buffers total:
 
-  inputs:  PackedTables (6: epoch-cached) + PackedState (2, donated)
+  inputs:  PackedTables (6: epoch-cached) + PackedState (1, donated)
            + batch ints [12, B] + batch floats [4, B]
-  outputs: PackedState' (2) + out ints [10, B] + metrics [n] + present[D]
+  outputs: PackedState' (1) + out ints [10, B] + metrics [n] + present[D]
            (metrics = step scalars + per-type counts + the on-device
            occupancy telemetry block, ``TELEMETRY_SCALARS`` + the
            per-tenant attribution block, ``TENANT_METER_*``)
 
-Column-major ``[C, B]`` layout so every unpacked column is a contiguous
-row slice (free under XLA fusion) and the host packs each column with one
-memcpy.  The packed step calls the SAME :func:`pipeline_step` internally —
-semantics, tests and the sharded path are unchanged; this is purely an
-interface transform, verified bit-exact by ``tests/test_packed.py``.
+Column-major ``[C, B]`` layout for the batch and the tables, so every
+unpacked column is a contiguous row slice (free under XLA fusion) and the
+host packs each column with one memcpy.  The state carry is the other way
+round, one row per device (:class:`PackedState`), because the step reads
+and writes it by device.  The packed step runs the SAME
+:func:`~sitewhere_tpu.pipeline.step.fused_step` as :func:`pipeline_step`,
+gathering from and scattering into the packed carry directly — verified
+bit-exact against the unpacked step by ``tests/test_packed.py``.
 
 Reference framing: this is the TPU analog of the reference batching its
 Kafka payloads into ONE record batch per poll instead of per-event RPCs
@@ -29,6 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -39,11 +43,16 @@ from flax import struct
 logger = logging.getLogger("sitewhere_tpu.packed")
 
 from sitewhere_tpu.ids import NULL_ID
+from sitewhere_tpu.ops.scatter import merge_rows_by_id, set_rows
 from sitewhere_tpu.pipeline.step import (
     NUM_EVENT_TYPES,
     PipelineOutputs,
+    RowWrites,
+    StateRows,
     StepMetrics,
-    pipeline_step,
+    REGISTRY_FIELDS,
+    slot_address,
+    fused_step,
 )
 from sitewhere_tpu.schema import (
     DeviceState,
@@ -55,8 +64,7 @@ from sitewhere_tpu.schema import (
 
 # -- column orders (load-bearing: pack and unpack must agree) ---------------
 
-REG_I = ("active", "tenant_id", "assignment_status", "device_type_id",
-         "assignment_id", "area_id", "customer_id", "asset_id")
+REG_I = REGISTRY_FIELDS
 RULE_I = ("active", "tenant_id", "mtype_id", "op", "alert_code",
           "alert_level", "kind", "window_idx")
 ZONE_I = ("active", "tenant_id", "area_id", "nvert", "condition",
@@ -65,11 +73,19 @@ BATCH_I = ("valid", "device_id", "tenant_id", "event_type", "ts_s", "ts_ns",
            "mtype_id", "alert_code", "alert_level", "command_id",
            "payload_ref", "update_state")
 BATCH_F = ("value", "lat", "lon", "elevation")
-STATE_I = ("last_event_ts_s", "last_event_ts_ns", "last_event_type",
-           "last_location_ts_s", "last_location_ts_ns", "last_alert_code",
-           "last_alert_ts_s", "last_alert_ts_ns", "presence_missing",
-           "nonfinite_count")
-STATE_F = ("last_lat", "last_lon", "last_elevation")
+# Lanes of one device's row in the packed carry (PackedState.rows), each
+# family's columns together; float columns are stored as their bits.  The
+# measurement matrix follows from lane MEAS_LANE on, field-major:
+# field j of slot m at ``MEAS_LANE + j * M + m``; the fields are ts_s,
+# ts_ns, value, then the K EWMAs.
+STATE_LANES = ("last_event_ts_s", "last_event_ts_ns", "last_event_type",
+               "presence_missing",
+               "last_location_ts_s", "last_location_ts_ns",
+               "last_lat", "last_lon", "last_elevation",
+               "last_alert_ts_s", "last_alert_ts_ns", "last_alert_code",
+               "nonfinite_count")
+STATE_FLOAT = frozenset(("last_lat", "last_lon", "last_elevation"))
+MEAS_LANE = 16
 OUT_I = ("flags", "device_type_id", "assignment_id", "area_id",
          "customer_id", "asset_id", "rule_id", "zone_id",
          "derived_code", "derived_level")
@@ -112,7 +128,8 @@ TENANT_METER_COUNTERS = ("rows", "state_writes", "rows_nonfinite")
 TENANT_METER_SLOTS = 16
 TENANT_METER_BLOCK = len(TENANT_METER_COUNTERS) * TENANT_METER_SLOTS
 
-PRESENCE_ROW = STATE_I.index("presence_missing")
+_LANE = {f: i for i, f in enumerate(STATE_LANES)}
+PRESENCE_LANE = _LANE["presence_missing"]
 
 # flag bits in OUT_I row 0
 F_ACCEPTED = 1
@@ -121,37 +138,200 @@ F_UNASSIGNED = 4
 F_DERIVED = 8
 
 
+LANE_TILE = 128   # packed rows are padded to the chip's lane count
+
+
+def registry_group(capacity: int) -> int:
+    """Devices sharing one row of ``PackedTables.reg_i``: 16 (8 columns
+    x 16 = one 128-lane row) wherever that leaves the table's row count
+    a multiple of 64, so any mesh of up to 64 shards splits it evenly;
+    fewer for the small registries of tests, 1 for odd capacities."""
+    full = LANE_TILE // len(REG_I)
+    return math.gcd(full, capacity // 64) if capacity % 64 == 0 else 1
+
+
+def _pick_lane(got: jax.Array, onehot: jax.Array, field: int) -> jax.Array:
+    """``int32[B]``: from gathered rows ``got [B, W]`` whose ``field``-th
+    block of ``G = onehot.shape[1]`` lanes holds one value per group
+    member, the value at each row's member (``onehot [B, G]``) — a masked
+    sum over G lanes, exact on bit patterns (one term is non-zero)."""
+    g = onehot.shape[1]
+    lanes = got[:, field * g:(field + 1) * g]
+    return jnp.where(onehot, lanes, 0).sum(axis=1, dtype=jnp.int32)
+
+
 @struct.dataclass
 class PackedTables:
-    """Registry/rules/zones packed to six buffers (cached per epoch)."""
+    """Registry/rules/zones packed to six buffers (cached per epoch).
 
-    reg_i: jax.Array    # int32[8, D]
+    ``reg_i`` holds ``G`` = :func:`registry_group` (16) devices a row,
+    field-major within the row (field ``f`` of device ``d`` at ``[d // G,
+    f * G + d % G]``): the step looks a batch up with ONE gather of B
+    whole 128-lane rows (:meth:`rows_at`) and the table takes no more
+    memory than its columns."""
+
+    reg_i: jax.Array    # int32[D / G, 8 * G]
     rules_i: jax.Array  # int32[8, R]
     rules_f: jax.Array  # float32[R] — threshold
     taus: jax.Array     # float32[K] — shared EWMA time-scales
     zones_i: jax.Array  # int32[7, Z]
     zones_v: jax.Array  # float32[Z, V, 2]
 
+    @property
+    def _group(self) -> int:
+        return self.reg_i.shape[1] // len(REG_I)
+
+    @property
+    def capacity(self) -> int:
+        return self.reg_i.shape[0] * self._group
+
+    def rows_at(self, ids_safe: jax.Array) -> jax.Array:
+        """``int32[B, 8]`` registry columns (:data:`REG_I`) of B devices."""
+        g = self._group
+        got = self.reg_i[ids_safe // g]                       # [B, 8 * G]
+        onehot = (ids_safe % g)[:, None] == jnp.arange(
+            g, dtype=jnp.int32)[None, :]
+        return jnp.stack(
+            [_pick_lane(got, onehot, f) for f in range(len(REG_I))], axis=1)
+
+
+def _bits(x: jax.Array) -> jax.Array:
+    """A column as the carry stores it: int32, floats by their bits."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def _floats(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+
+def packed_row_width(num_mtype_slots: int, num_ewma_scales: int) -> int:
+    used = MEAS_LANE + num_mtype_slots * (3 + num_ewma_scales)
+    return -(-used // LANE_TILE) * LANE_TILE
+
 
 @struct.dataclass
 class PackedState:
-    """DeviceState packed to two buffers (the donated step carry)."""
+    """DeviceState packed to ONE buffer, the donated step carry:
+    ``rows int32[D, W]``, one row per device (:data:`STATE_LANES`, then
+    the measurement matrix from :data:`MEAS_LANE`; floats as their bits;
+    ``W`` a multiple of the chip's 128 lanes, so a row is one contiguous
+    tile line).  The step gathers the B rows a batch names, in one
+    gather, and scatters the merged rows back in one unique-index
+    scatter, in place when the carry is donated: it never moves the
+    registry.  The layout is private to this module — readers go through
+    :func:`unpack_state`, :func:`packed_presence_sweep` and
+    ``DeviceStateManager``.
+    """
 
-    si: jax.Array  # int32[10 + 2M, D]
-    sf: jax.Array  # float32[3 + M + M*K, D]
+    rows: jax.Array
     num_mtype_slots: int = struct.field(pytree_node=False, default=8)
     num_ewma_scales: int = struct.field(pytree_node=False, default=3)
 
     @property
     def capacity(self) -> int:
-        return self.si.shape[-1]
+        return self.rows.shape[0]
+
+    def gather(self, batch: EventBatch) -> StateRows:
+        """The batch's pre-batch state: ONE gather of B whole rows; the
+        measurement fields are then picked at each row's slot by a
+        one-hot sum over the M lanes of a field (batch-sized work)."""
+        M, K = self.num_mtype_slots, self.num_ewma_scales
+        ids_safe, slot = slot_address(batch, self.capacity, M)
+        got = self.rows[ids_safe]                            # [B, W]
+        onehot = slot[:, None] == jnp.arange(M, dtype=jnp.int32)[None, :]
+        meas_lanes = got[:, MEAS_LANE:]
+
+        def meas(j):
+            return _pick_lane(meas_lanes, onehot, j)
+
+        return StateRows(
+            ev_s=got[:, _LANE["last_event_ts_s"]],
+            ev_ns=got[:, _LANE["last_event_ts_ns"]],
+            loc_s=got[:, _LANE["last_location_ts_s"]],
+            loc_ns=got[:, _LANE["last_location_ts_ns"]],
+            alert_s=got[:, _LANE["last_alert_ts_s"]],
+            alert_ns=got[:, _LANE["last_alert_ts_ns"]],
+            val_s=meas(0), val_ns=meas(1), value=_floats(meas(2)),
+            ewma=_floats(jnp.stack([meas(3 + k) for k in range(K)], axis=1)),
+            raw=got,
+        )
+
+    def scatter(self, batch: EventBatch, cur: StateRows, writes: RowWrites,
+                ewma: jax.Array, nonfinite: Optional[jax.Array] = None,
+                ) -> "PackedState":
+        """Write the batch's changes back as WHOLE rows, one per device
+        the batch names, in ONE scatter with unique indices.
+
+        Several rows of a batch may change different lanes of one
+        device's row (its newest location, its newest alert, a
+        measurement per slot, one count per nonfinite row), so the rows
+        are merged before they are written, on batch-sized arrays: each
+        batch row states its change as a DIFFERENCE to the gathered row
+        (``new bits - old bits`` on the lanes it writes, +1 on the
+        nonfinite count, 0 elsewhere; wrapping int32, so exact), and
+        :func:`~sitewhere_tpu.ops.scatter.merge_rows_by_id` sums the
+        differences per device and adds them to the gathered row.  At
+        most one batch row writes a given lane (:class:`RowWrites`), so
+        a lane's sum is that row's difference, bit for bit.
+        """
+        cap, width = self.rows.shape
+        M = self.num_mtype_slots
+        ids = batch.device_id
+        b = ids.shape[0]
+        got = cur.raw
+        _, slot = slot_address(batch, cap, M)
+        in_range = (ids >= 0) & (ids < cap)
+
+        def diff(write, new, lane):
+            return jnp.where(write, _bits(new) - got[:, lane], 0)
+
+        named = {
+            "presence_missing": diff(writes.present, jnp.zeros_like(ids),
+                                     PRESENCE_LANE),
+            "nonfinite_count": (jnp.zeros_like(ids) if nonfinite is None
+                                else (nonfinite & in_range).astype(jnp.int32)),
+        }
+        for write, fields, cols in (
+                (writes.event,
+                 ("last_event_ts_s", "last_event_ts_ns", "last_event_type"),
+                 (batch.ts_s, batch.ts_ns, batch.event_type)),
+                (writes.location,
+                 ("last_location_ts_s", "last_location_ts_ns",
+                  "last_lat", "last_lon", "last_elevation"),
+                 (batch.ts_s, batch.ts_ns, batch.lat, batch.lon,
+                  batch.elevation)),
+                (writes.alert,
+                 ("last_alert_ts_s", "last_alert_ts_ns", "last_alert_code"),
+                 (batch.ts_s, batch.ts_ns, batch.alert_code))):
+            for f, c in zip(fields, cols):
+                named[f] = diff(write, c, _LANE[f])
+        at_slot = writes.measurement[:, None] & (
+            slot[:, None] == jnp.arange(M, dtype=jnp.int32)[None, :])
+        meas = [batch.ts_s, batch.ts_ns, batch.value] + [
+            ewma[:, k] for k in range(self.num_ewma_scales)]
+        blocks = [jnp.stack([named[f] for f in STATE_LANES], axis=1),
+                  jnp.zeros((b, MEAS_LANE - len(STATE_LANES)), jnp.int32)]
+        for j, c in enumerate(meas):
+            lanes = got[:, MEAS_LANE + j * M:MEAS_LANE + (j + 1) * M]
+            blocks.append(jnp.where(at_slot, _bits(c)[:, None] - lanes, 0))
+        used = MEAS_LANE + M * len(meas)
+        blocks.append(jnp.zeros((b, width - used), jnp.int32))
+        change = jnp.concatenate(blocks, axis=1)              # [B, W]
+
+        targets, merged = merge_rows_by_id(ids, got, change, cap)
+        return self.replace(rows=set_rows(self.rows, targets, merged))
 
 
 def pack_tables(registry: Registry, rules: RuleTable,
                 zones: ZoneTable) -> PackedTables:
+    cols = jnp.stack([getattr(registry, f).astype(jnp.int32)
+                      for f in REG_I])                       # [8, D]
+    g = registry_group(registry.capacity)
     return PackedTables(
-        reg_i=jnp.stack([getattr(registry, f).astype(jnp.int32)
-                         for f in REG_I]),
+        reg_i=cols.reshape(len(REG_I), -1, g).transpose(
+            1, 0, 2).reshape(-1, len(REG_I) * g),
         rules_i=jnp.stack([getattr(rules, f).astype(jnp.int32)
                            for f in RULE_I]),
         rules_f=rules.threshold,
@@ -163,46 +343,79 @@ def pack_tables(registry: Registry, rules: RuleTable,
 
 
 def unpack_tables(t: PackedTables) -> Tuple[Registry, RuleTable, ZoneTable]:
-    ri = {f: t.reg_i[i] for i, f in enumerate(REG_I)}
+    """The tables back as columns (tests and tools; the step never
+    unpacks the registry — it looks rows up with ``t.rows_at``)."""
+    cols = t.reg_i.reshape(-1, len(REG_I), t._group).transpose(
+        1, 0, 2).reshape(len(REG_I), -1)
+    ri = {f: cols[i] for i, f in enumerate(REG_I)}
     ri["active"] = ri["active"] != 0
     registry = Registry(epoch=jnp.int32(0), **ri)
+    return (registry, *_unpack_rule_tables(t))
+
+
+def _unpack_rule_tables(t: PackedTables) -> Tuple[RuleTable, ZoneTable]:
     li = {f: t.rules_i[i] for i, f in enumerate(RULE_I)}
     li["active"] = li["active"] != 0
     rules = RuleTable(threshold=t.rules_f, ewma_tau_s=t.taus, **li)
     zi = {f: t.zones_i[i] for i, f in enumerate(ZONE_I)}
     zi["active"] = zi["active"] != 0
     zones = ZoneTable(verts=t.zones_v, **zi)
-    return registry, rules, zones
+    return rules, zones
+
+
+PACK_BLOCK = 1 << 16   # devices packed at a time (bounds pack_state's temps)
 
 
 def pack_state(state: DeviceState) -> PackedState:
+    """The carry from its columns, :data:`PACK_BLOCK` devices at a time,
+    each block written into the carry in place.  Laying a ``[D]`` column
+    into a lane makes a ``[D, 1]`` sliver that the chip pads to 128 lanes:
+    packed in one piece, a registry holds a carry-sized temporary per
+    column (38 GB at 1<<22 slots, and the chip's compiler turns every
+    whole-array transpose into just that); a block's slivers are 32 MB."""
     M, K = state.num_mtype_slots, state.num_ewma_scales
-    si = jnp.concatenate([
-        jnp.stack([getattr(state, f).astype(jnp.int32) for f in STATE_I]),
-        state.last_value_ts_s.T,
-        state.last_value_ts_ns.T,
-    ])
-    sf = jnp.concatenate([
-        jnp.stack([getattr(state, f) for f in STATE_F]),
-        state.last_values.T,
-        state.ewma_values.reshape(-1, M * K).T,
-    ])
-    return PackedState(si=si, sf=sf, num_mtype_slots=M, num_ewma_scales=K)
+    D = state.capacity
+    W = packed_row_width(M, K)
+    blk = PACK_BLOCK if D % PACK_BLOCK == 0 else D
+    named = [_bits(getattr(state, f)) for f in STATE_LANES]
+    meas = [state.last_value_ts_s, state.last_value_ts_ns,
+            _bits(state.last_values),
+            _bits(state.ewma_values).transpose(0, 2, 1).reshape(D, K * M)]
+
+    def pack_block(i, rows):
+        def block(x):
+            return jax.lax.dynamic_slice_in_dim(x, i * blk, blk)
+
+        return jax.lax.dynamic_update_slice_in_dim(rows, jnp.concatenate([
+            jnp.stack([block(c) for c in named], axis=1),
+            jnp.zeros((blk, MEAS_LANE - len(STATE_LANES)), jnp.int32),
+            *[block(x) for x in meas],
+            jnp.zeros((blk, W - MEAS_LANE - M * (3 + K)), jnp.int32),
+        ], axis=1), i * blk, axis=0)
+
+    rows = jax.lax.fori_loop(
+        0, D // blk, pack_block, jnp.zeros((D, W), jnp.int32))
+    return PackedState(rows=rows, num_mtype_slots=M, num_ewma_scales=K)
 
 
 def unpack_state(ps: PackedState) -> DeviceState:
     M, K = ps.num_mtype_slots, ps.num_ewma_scales
-    D = ps.capacity
-    n = len(STATE_I)
-    cols = {f: ps.si[i] for i, f in enumerate(STATE_I)}
+    lanes = ps.rows.T                                        # [W, D]
+    cols = {}
+    for f in STATE_LANES:
+        c = lanes[_LANE[f]]
+        cols[f] = _floats(c) if f in STATE_FLOAT else c
     cols["presence_missing"] = cols["presence_missing"] != 0
-    fcols = {f: ps.sf[i] for i, f in enumerate(STATE_F)}
+
+    def meas(j, n=1):
+        return lanes[MEAS_LANE + j * M:MEAS_LANE + (j + n) * M]
+
     return DeviceState(
-        last_values=ps.sf[len(STATE_F):len(STATE_F) + M].T,
-        last_value_ts_s=ps.si[n:n + M].T,
-        last_value_ts_ns=ps.si[n + M:n + 2 * M].T,
-        ewma_values=ps.sf[len(STATE_F) + M:].T.reshape(D, M, K),
-        **cols, **fcols,
+        last_value_ts_s=meas(0).T,
+        last_value_ts_ns=meas(1).T,
+        last_values=_floats(meas(2)).T,
+        ewma_values=_floats(meas(3, K)).reshape(K, M, -1).transpose(2, 1, 0),
+        **cols,
     )
 
 
@@ -274,12 +487,13 @@ def packed_pipeline_step(
     tables: PackedTables, ps: PackedState, bi: jax.Array, bf: jax.Array
 ) -> Tuple[PackedState, jax.Array, jax.Array, jax.Array]:
     """The fused step over the packed interface (semantics identical to
-    :func:`pipeline_step`; jit with ``donate_argnums=(1,)``)."""
-    registry, rules, zones = unpack_tables(tables)
-    state = unpack_state(ps)
+    :func:`pipeline_step`; jit with ``donate_argnums=(1,)``): the same
+    :func:`~sitewhere_tpu.pipeline.step.fused_step`, gathering from and
+    scattering into the packed carry — the carry is never unpacked."""
+    rules, zones = _unpack_rule_tables(tables)
     batch = unpack_batch(bi, bf)
-    new_state, out = pipeline_step(registry, state, rules, zones, batch)
-    return pack_state(new_state), *pack_outputs(out, batch)
+    new_ps, out = fused_step(tables, ps, rules, zones, batch)
+    return new_ps, *pack_outputs(out, batch)
 
 
 def build_packed_chain(k: int, donate: bool = True) -> Callable:
@@ -397,12 +611,9 @@ def packed_env_override() -> Optional[bool]:
 def packed_step_default() -> bool:
     """Interface choice for the PURE step (bench microbenchmarks).
 
-    Backend-adaptive (same spirit as the sort-vs-scatter winner choice
-    in ``ops/scatter.py``): on TPU the per-call win (~100 fewer buffers
-    per step; dispatch cost scales with buffer count) is taken to
-    outweigh the repack's fused HBM traffic (not measured on the chip),
-    while the CPU backend materializes the packs as real memcpys and
-    measures ~25% SLOWER per bare call.
+    Backend-adaptive: on TPU the per-call win (~100 fewer buffers per
+    step; dispatch cost scales with buffer count) decides, while on the
+    CPU backend a bare call gains nothing from it.
 
     The DISPATCHER defaults packed on EVERY backend regardless
     (``Instance._packed_step_enabled``): its egress fetches many output
@@ -419,12 +630,17 @@ def packed_step_default() -> bool:
 
 
 def packed_presence_sweep(ps: PackedState, now_s, missing_after_s):
-    """Presence sweep over the packed carry (one fused unpack→sweep→pack;
-    jit with ``donate_argnums=(0,)``)."""
-    from sitewhere_tpu.state.presence import presence_sweep
+    """Presence sweep over the packed carry: reads three lanes, writes
+    one (jit with ``donate_argnums=(0,)``)."""
+    from sitewhere_tpu.state.presence import newly_missing
 
-    state, newly = presence_sweep(unpack_state(ps), now_s, missing_after_s)
-    return pack_state(state), newly
+    rows = ps.rows
+    missing = rows[:, PRESENCE_LANE] != 0
+    newly = newly_missing(
+        rows[:, _LANE["last_event_type"]], rows[:, _LANE["last_event_ts_s"]],
+        missing, now_s, missing_after_s)
+    rows = rows.at[:, PRESENCE_LANE].set((missing | newly).astype(rows.dtype))
+    return ps.replace(rows=rows), newly
 
 
 # -- host side --------------------------------------------------------------
@@ -722,7 +938,7 @@ __all__ = [
     "pack_batch_host", "stage_packed_batch", "start_host_copy",
     "supports_batch_staging",
     "F_ACCEPTED", "F_UNREGISTERED", "F_UNASSIGNED", "F_DERIVED",
-    "BATCH_I", "BATCH_F", "OUT_I", "PRESENCE_ROW",
+    "BATCH_I", "BATCH_F", "OUT_I", "PRESENCE_LANE",
     "METRIC_SCALARS", "TELEMETRY_SCALARS",
     "TENANT_METER_COUNTERS", "TENANT_METER_SLOTS", "TENANT_METER_BLOCK",
 ]

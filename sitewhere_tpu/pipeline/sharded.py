@@ -131,52 +131,46 @@ def build_sharded_step(mesh: Mesh, donate: bool = True):
     return jax.jit(mapped, donate_argnums=(1,) if donate else ())
 
 
+def _local_packed_step(tables, ps, bi, bf):
+    """One shard's packed step inside ``shard_map``: global device ids
+    become local registry rows (foreign rows fall outside ``[0,
+    rows_local)`` and are reported unregistered by the range check),
+    then the single-chip :func:`packed_pipeline_step` runs on the
+    shard's block of the carry.  Derived-alert/enrich ids in ``oi`` are
+    table indices (replicated tables → already global); device ids never
+    leave the host columns."""
+    from sitewhere_tpu.pipeline.packed import BATCH_I, packed_pipeline_step
+
+    row = BATCH_I.index("device_id")
+    rows_local = ps.capacity
+    offset = jax.lax.axis_index(SHARD_AXIS).astype(jnp.int32) * rows_local
+    ids = bi[row]
+    bi = bi.at[row].set(jnp.where(ids >= 0, ids - offset, -1))
+    return packed_pipeline_step(tables, ps, bi, bf)
+
+
 def build_sharded_packed_step(mesh: Mesh):
     """The packed interface over the mesh (the multi-chip deployment
     form): same local-step semantics as :func:`build_sharded_step`, but
     the per-step host surface is the packed buffer set — batch crosses
-    as ``[12, B] + [4, B]`` sharded on axis 1, state rides as two wide
-    planes, outputs as one ``[10, B]`` block + psum-ed metrics.  Per-
-    call placement cost on a mesh scales with buffer count × hosts, so
-    this is the packed step's ~10× buffer reduction where it matters
-    most.  NO donation: the carry is the state manager's live epoch.
+    as ``[12, B] + [4, B]`` sharded on axis 1, state rides as one
+    buffer of device rows sharded on axis 0, outputs as one ``[10, B]``
+    block + psum-ed metrics.  Per-call placement cost on a mesh scales
+    with buffer count × hosts, so this is the packed step's ~10× buffer
+    reduction where it matters most.  NO donation: the carry is the
+    state manager's live epoch.
     """
-    from sitewhere_tpu.pipeline.packed import (
-        pack_outputs,
-        pack_state,
-        unpack_batch,
-        unpack_state,
-        unpack_tables,
-    )
-
-    tables_specs = _packed_tables_specs()
-    # PackedState carries static pytree metadata (slot counts), so its
-    # spec is a bare PREFIX — both leaves shard the same way on axis 1.
-    state_specs = _PACKED_STATE_SPEC
-    in_specs = (tables_specs, state_specs,
-                P(None, SHARD_AXIS), P(None, SHARD_AXIS))
-    out_specs = (state_specs, P(None, SHARD_AXIS), P(), P(SHARD_AXIS))
+    in_specs = (_packed_tables_specs(), _PACKED_STATE_SPEC,
+                _PACKED_BATCH_SPEC, _PACKED_BATCH_SPEC)
+    out_specs = (_PACKED_STATE_SPEC, _PACKED_BATCH_SPEC, P(), P(SHARD_AXIS))
 
     def local_step(tables, ps, bi, bf):
-        registry, rules, zones = unpack_tables(tables)
-        state = unpack_state(ps)
-        batch = unpack_batch(bi, bf)
-
-        rows_local = registry.capacity
-        offset = jax.lax.axis_index(SHARD_AXIS).astype(jnp.int32) * rows_local
-        local_ids = jnp.where(batch.device_id >= 0,
-                              batch.device_id - offset, -1)
-        local_batch = batch.replace(device_id=local_ids)
-        new_state, out = pipeline_step(
-            registry, state, rules, zones, local_batch)
+        new_ps, oi, metrics, present = _local_packed_step(tables, ps, bi, bf)
         # telemetry rides the psum-ed metrics vector: occupancy counters
         # aggregate over shards exactly like the step scalars
-        oi, metrics, present = pack_outputs(out, local_batch)
         with jax.named_scope("mesh_reduce"):
             metrics = jax.lax.psum(metrics, SHARD_AXIS)
-        # derived-alert/enrich ids in `oi` are table indices (replicated
-        # tables → already global); device ids never leave the host cols
-        return pack_state(new_state), oi, metrics, present
+        return new_ps, oi, metrics, present
 
     mapped = shard_map(
         local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
@@ -192,10 +186,11 @@ def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
     dedup and presence sharded by device-id).
 
     Same layout authority as the single step — ``_packed_tables_specs``
-    for the resident tables, :data:`_PACKED_STATE_SPEC` for the state
-    planes and every staged batch slot — so host-side placement
-    (:func:`place_packed_batch` / :func:`place_packed_state`) feeds both
-    paths identically.  Inside the ``shard_map`` body the local chain is
+    for the resident tables, :data:`_PACKED_STATE_SPEC` for the carry
+    and :data:`_PACKED_BATCH_SPEC` for every staged batch slot — so
+    host-side placement (:func:`place_packed_batch` /
+    :func:`place_packed_state`) feeds both paths identically.  Inside
+    the ``shard_map`` body the local chain is
     :func:`~sitewhere_tpu.pipeline.packed.chain_over_slots` over the
     id-offsetting local step; rule eval stays data-parallel (rule/zone
     tables are replicated, so no gather crosses shards — the all-gather
@@ -208,39 +203,18 @@ def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
     by capacity.  ``donate=True`` donates the state carry: the mesh ring
     runs on a ``DeviceStateManager.lease_packed`` exclusive hand-off, so
     unlike :func:`build_sharded_packed_step` (which steps the live
-    epoch) the chain may consume its input planes.
+    epoch) the chain may consume its input buffer.
     """
-    from sitewhere_tpu.pipeline.packed import (
-        chain_over_slots,
-        pack_outputs,
-        pack_state,
-        unpack_batch,
-        unpack_state,
-        unpack_tables,
-    )
+    from sitewhere_tpu.pipeline.packed import chain_over_slots
 
-    tables_specs = _packed_tables_specs()
-    state_specs = _PACKED_STATE_SPEC
-    slot_spec = P(None, SHARD_AXIS)
-    in_specs = (tables_specs, state_specs) + (slot_spec,) * (2 * k)
-    out_specs = (state_specs, P(None, None, SHARD_AXIS), P(), P(SHARD_AXIS))
-
-    def local_step(tables, ps, bi, bf):
-        registry, rules, zones = unpack_tables(tables)
-        state = unpack_state(ps)
-        batch = unpack_batch(bi, bf)
-        rows_local = registry.capacity
-        offset = jax.lax.axis_index(SHARD_AXIS).astype(jnp.int32) * rows_local
-        local_ids = jnp.where(batch.device_id >= 0,
-                              batch.device_id - offset, -1)
-        local_batch = batch.replace(device_id=local_ids)
-        new_state, out = pipeline_step(
-            registry, state, rules, zones, local_batch)
-        return pack_state(new_state), *pack_outputs(out, local_batch)
+    in_specs = (_packed_tables_specs(), _PACKED_STATE_SPEC) + (
+        _PACKED_BATCH_SPEC,) * (2 * k)
+    out_specs = (_PACKED_STATE_SPEC, P(None, None, SHARD_AXIS), P(),
+                 P(SHARD_AXIS))
 
     def local_chain(tables, ps, *slots):
-        c, ois, mets, present = chain_over_slots(local_step, k, tables,
-                                                 ps, slots)
+        c, ois, mets, present = chain_over_slots(
+            _local_packed_step, k, tables, ps, slots)
         # one collective per chain: psum of the stacked [K, n] block is
         # the per-step psum the single sharded step would have done K×
         with jax.named_scope("mesh_reduce"):
@@ -256,14 +230,15 @@ def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
 
 # The packed-mesh sharding layout lives HERE, once: the shard_map specs
 # and every host-side placement read these, so they cannot drift.
-_PACKED_STATE_SPEC = P(None, SHARD_AXIS)
+_PACKED_STATE_SPEC = P(SHARD_AXIS)         # device rows, by capacity
+_PACKED_BATCH_SPEC = P(None, SHARD_AXIS)   # [C, B] columns, by width
 
 
 def _packed_tables_specs():
     from sitewhere_tpu.pipeline.packed import PackedTables
 
     return PackedTables(
-        reg_i=P(None, SHARD_AXIS),   # registry shards by capacity
+        reg_i=P(SHARD_AXIS),   # registry rows shard by capacity
         rules_i=P(), rules_f=P(), taus=P(),   # small broadcast tables
         zones_i=P(), zones_v=P(),
     )
@@ -271,7 +246,7 @@ def _packed_tables_specs():
 
 def place_packed_batch(mesh: Mesh, bi, bf):
     """Device-put one packed wire batch sharded along its width axis."""
-    s = NamedSharding(mesh, _PACKED_STATE_SPEC)
+    s = NamedSharding(mesh, _PACKED_BATCH_SPEC)
     return jax.device_put(bi, s), jax.device_put(bf, s)
 
 
@@ -285,9 +260,8 @@ def place_packed_tables(mesh: Mesh, t):
 def place_packed_state(mesh: Mesh, ps):
     """Device-put a PackedState sharded by capacity (no-op once the
     epoch already carries the sharding, i.e. after the first step)."""
-    s = NamedSharding(mesh, _PACKED_STATE_SPEC)
-    return ps.replace(si=jax.device_put(ps.si, s),
-                      sf=jax.device_put(ps.sf, s))
+    return ps.replace(rows=jax.device_put(
+        ps.rows, NamedSharding(mesh, _PACKED_STATE_SPEC)))
 
 
 def place_inputs(

@@ -31,9 +31,11 @@ from flax import struct
 from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.ops.geo_pallas import points_in_polygons_auto
 from sitewhere_tpu.ops.scatter import (
-    apply_winners,
     bincount_fixed,
-    winner_rows,
+    drop_targets,
+    newer_or_equal,
+    set_rows,
+    winning_rows,
 )
 from sitewhere_tpu.schema import (
     DEFAULT_EWMA_TAUS,
@@ -101,38 +103,53 @@ class PipelineOutputs:
     metrics: StepMetrics
 
 
+#: Registry columns in the order :func:`validate_rows` reads them (and
+#: ``pipeline/packed.py`` packs them)
+REGISTRY_FIELDS = ("active", "tenant_id", "assignment_status",
+                   "device_type_id", "assignment_id", "area_id",
+                   "customer_id", "asset_id")
+
+
+class RegistryColumns:
+    """The unpacked :class:`Registry` (one array per column) as the fused
+    step sees a registry: ``rows_at`` gathers the batch's B rows.
+    ``PackedTables`` offers the same over its packed table."""
+
+    def __init__(self, registry: Registry):
+        self.registry = registry
+        self.capacity = registry.capacity
+
+    def rows_at(self, ids_safe: jax.Array) -> jax.Array:
+        """``int32[B, 8]`` (:data:`REGISTRY_FIELDS`): one B-row gather
+        per column — nothing registry-sized is stacked first."""
+        return jnp.stack(
+            [getattr(self.registry, f)[ids_safe].astype(jnp.int32)
+             for f in REGISTRY_FIELDS], axis=1)
+
+
 def validate_and_enrich(
     registry: Registry, batch: EventBatch
+) -> Tuple[jax.Array, jax.Array, jax.Array, dict]:
+    """:func:`validate_rows` for an unpacked :class:`Registry`."""
+    return validate_rows(RegistryColumns(registry), batch)
+
+
+def validate_rows(
+    registry, batch: EventBatch
 ) -> Tuple[jax.Array, jax.Array, jax.Array, dict]:
     """Registry gather replacing the per-event device/assignment lookups.
 
     Reference: ``InboundPayloadProcessingLogic.validateAssignment:185-219``
     — device-by-token then assignment lookup over cached gRPC; missing
     device → unregistered dead-letter (``:228-233``), missing/inactive
-    assignment → unassigned dead-letter.
+    assignment → unassigned dead-letter.  ``registry`` is anything with a
+    ``capacity`` and ``rows_at`` (:class:`RegistryColumns`,
+    ``PackedTables``).
     """
     cap = registry.capacity
     ids = batch.device_id
     in_range = (ids >= 0) & (ids < cap)
-    safe = jnp.clip(ids, 0, cap - 1)
-
-    # ONE packed [B, 8] gather instead of eight per-column gathers: a
-    # [B]-sized gather costs ~1 ms at width 131k on v5e while the packed
-    # multi-column form costs barely more than one — the registry is tiny
-    # (capacity x 8 int32), so the per-step stack is free.
-    packed = jnp.stack(
-        [
-            registry.active.astype(jnp.int32),
-            registry.tenant_id,
-            registry.assignment_status,
-            registry.device_type_id,
-            registry.assignment_id,
-            registry.area_id,
-            registry.customer_id,
-            registry.asset_id,
-        ],
-        axis=1,
-    )[safe]  # [B, 8]
+    packed = registry.rows_at(jnp.clip(ids, 0, cap - 1))  # [B, 8]
 
     registered = in_range & (packed[:, 0] != 0)
     # Tenant isolation: an event claiming tenant T must hit a device owned
@@ -156,31 +173,134 @@ def validate_and_enrich(
     return accepted, unregistered, unassigned, enrich
 
 
+@struct.dataclass
+class StateRows:
+    """The pre-batch state of the slots a batch names: one entry per
+    BATCH row, gathered once a step at the row's device (and, for the
+    measurement columns, the row's measurement slot).  Rule evaluation
+    and the state update both read it; nothing registry-sized is built
+    to produce it."""
+
+    ev_s: jax.Array      # int32[B] — last_event_ts_s of the row's device
+    ev_ns: jax.Array     # int32[B]
+    loc_s: jax.Array     # int32[B] — last_location_ts_s
+    loc_ns: jax.Array    # int32[B]
+    alert_s: jax.Array   # int32[B] — last_alert_ts_s
+    alert_ns: jax.Array  # int32[B]
+    val_s: jax.Array     # int32[B] — last_value_ts_s[device, slot]
+    val_ns: jax.Array    # int32[B]
+    value: jax.Array     # float32[B] — last_values[device, slot]
+    ewma: jax.Array      # float32[B, K] — ewma_values[device, slot]
+    # the gathered rows as the carry holds them, for a carry whose
+    # scatter writes whole rows back (PackedState); None otherwise
+    raw: Optional[jax.Array] = None
+
+
+@struct.dataclass
+class RowWrites:
+    """Which batch rows write which state family (``bool[B]`` each): the
+    row won its slot among the batch's rows AND is at least as new as
+    what the slot holds.  ``present`` is the any-event winner whatever
+    its age — it clears ``presence_missing`` and marks ``present_now``."""
+
+    present: jax.Array
+    event: jax.Array
+    location: jax.Array
+    alert: jax.Array
+    measurement: jax.Array
+
+
+def slot_address(batch: EventBatch, capacity: int, num_mtype_slots: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``(device row clipped into range, measurement slot)`` per batch
+    row — where :class:`StateRows` is gathered.  Slot = ``mtype_id mod
+    M``; rows without a measurement type read slot 0 and never write."""
+    ids_safe = jnp.clip(batch.device_id, 0, capacity - 1)
+    slot = jnp.where(batch.mtype_id >= 0, batch.mtype_id % num_mtype_slots, 0)
+    return ids_safe, slot
+
+
 def _gather_meas_state(
     state: DeviceState, batch: EventBatch
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Per-row previous measurement-slot state via TWO packed gathers.
+    """Per-row previous measurement-slot state, read straight from the
+    ``[D, M]`` / ``[D, M, K]`` columns at ``(device, slot)``: B rows are
+    gathered from each, nothing is stacked or reshaped first.
 
-    Returns ``(prev_ts, prev_ns, prev_value, ewma_prev[B, K])``.  Packing
-    the int columns into ``[D*M, 2]`` and the float columns into
-    ``[D*M, 1+K]`` replaces five separate [B]-sized gathers (each ~1 ms at
-    width 131k on v5e; multi-column gathers cost barely more than one).
+    Returns ``(prev_ts, prev_ns, prev_value, ewma_prev[B, K])``.
     """
-    cap = state.capacity
-    M = state.num_mtype_slots
-    ids_safe = jnp.clip(batch.device_id, 0, cap - 1)
-    slot = jnp.where(batch.mtype_id >= 0, batch.mtype_id % M, 0)
-    flat = ids_safe * M + slot
-    ipack = jnp.stack(
-        [state.last_value_ts_s.reshape(-1), state.last_value_ts_ns.reshape(-1)],
-        axis=1,
-    )[flat]  # [B, 2]
-    fpack = jnp.concatenate(
-        [state.last_values.reshape(-1, 1),
-         state.ewma_values.reshape(-1, state.num_ewma_scales)],
-        axis=1,
-    )[flat]  # [B, 1 + K]
-    return ipack[:, 0], ipack[:, 1], fpack[:, 0], fpack[:, 1:]
+    ids_safe, slot = slot_address(
+        batch, state.capacity, state.num_mtype_slots)
+    return (state.last_value_ts_s[ids_safe, slot],
+            state.last_value_ts_ns[ids_safe, slot],
+            state.last_values[ids_safe, slot],
+            state.ewma_values[ids_safe, slot])
+
+
+class StateColumns:
+    """The unpacked carry (:class:`DeviceState`, one array per column) as
+    the fused step sees a carry: ``gather`` the batch's rows, ``scatter``
+    the winners back.  :class:`~sitewhere_tpu.pipeline.packed.PackedState`
+    offers the same two methods over its one packed buffer."""
+
+    def __init__(self, state: DeviceState):
+        self.state = state
+        self.capacity = state.capacity
+        self.num_mtype_slots = state.num_mtype_slots
+        self.num_ewma_scales = state.num_ewma_scales
+
+    def gather(self, batch: EventBatch) -> StateRows:
+        s = self.state
+        ids_safe, _ = slot_address(batch, s.capacity, s.num_mtype_slots)
+        val_s, val_ns, value, ewma = _gather_meas_state(s, batch)
+        return StateRows(
+            ev_s=s.last_event_ts_s[ids_safe],
+            ev_ns=s.last_event_ts_ns[ids_safe],
+            loc_s=s.last_location_ts_s[ids_safe],
+            loc_ns=s.last_location_ts_ns[ids_safe],
+            alert_s=s.last_alert_ts_s[ids_safe],
+            alert_ns=s.last_alert_ts_ns[ids_safe],
+            val_s=val_s, val_ns=val_ns, value=value, ewma=ewma)
+
+    def scatter(self, batch: EventBatch, cur: StateRows, writes: RowWrites,
+                ewma: jax.Array, nonfinite: Optional[jax.Array] = None,
+                ) -> DeviceState:
+        """Write the winning rows into their columns (one unique-index
+        scatter of B rows per column) and count ``nonfinite`` rows per
+        device; returns the new state."""
+        s = self.state
+        cap = s.capacity
+        ids = batch.device_id
+        _, slot = slot_address(batch, cap, s.num_mtype_slots)
+        put = set_rows
+        t_present = drop_targets(ids, writes.present, cap)
+        t_event = drop_targets(ids, writes.event, cap)
+        t_loc = drop_targets(ids, writes.location, cap)
+        t_alert = drop_targets(ids, writes.alert, cap)
+        t_meas = (drop_targets(ids, writes.measurement, cap), slot)
+        nonfinite_count = s.nonfinite_count
+        if nonfinite is not None:
+            nf = jnp.where(nonfinite & (ids >= 0) & (ids < cap), ids, cap)
+            nonfinite_count = nonfinite_count.at[nf].add(1, mode="drop")
+        return s.replace(
+            last_event_ts_s=put(s.last_event_ts_s, t_event, batch.ts_s),
+            last_event_ts_ns=put(s.last_event_ts_ns, t_event, batch.ts_ns),
+            last_event_type=put(s.last_event_type, t_event, batch.event_type),
+            presence_missing=put(s.presence_missing, t_present, False),
+            last_location_ts_s=put(s.last_location_ts_s, t_loc, batch.ts_s),
+            last_location_ts_ns=put(s.last_location_ts_ns, t_loc, batch.ts_ns),
+            last_lat=put(s.last_lat, t_loc, batch.lat),
+            last_lon=put(s.last_lon, t_loc, batch.lon),
+            last_elevation=put(s.last_elevation, t_loc, batch.elevation),
+            last_alert_ts_s=put(s.last_alert_ts_s, t_alert, batch.ts_s),
+            last_alert_ts_ns=put(s.last_alert_ts_ns, t_alert, batch.ts_ns),
+            last_alert_code=put(s.last_alert_code, t_alert, batch.alert_code),
+            last_value_ts_s=put(s.last_value_ts_s, t_meas, batch.ts_s),
+            last_value_ts_ns=put(s.last_value_ts_ns, t_meas, batch.ts_ns),
+            last_values=put(s.last_values, t_meas, batch.value),
+            ewma_values=put(s.ewma_values, t_meas, ewma),
+            nonfinite_count=nonfinite_count,
+        )
 
 
 def fold_ewma_arrays(
@@ -213,24 +333,17 @@ def _fold_ewma_from(
     batch: EventBatch,
     taus: jax.Array,
 ) -> jax.Array:
-    """EWMA fold given pre-gathered slot state (see :func:`fold_ewma`)."""
-    return fold_ewma_arrays(prev_ts, prev_ns, ewma_prev,
-                            batch.ts_s, batch.ts_ns, batch.value, taus)
-
-
-def fold_ewma(
-    state: DeviceState, batch: EventBatch, taus: jax.Array
-) -> jax.Array:
-    """Per-row candidate EWMAs after folding this row's sample.
+    """Per-row candidate EWMAs after folding this row's sample into its
+    pre-gathered slot state.
 
     Irregular-sampling EWMA: ``alpha = 1 - exp(-dt / tau)`` with ``dt``
     the gap since the device's previous sample in that measurement slot;
     the first sample seeds the average (no zero bias).  Returns
-    ``float32[B, K]`` — rows are CANDIDATES; the time-ordered scatter in
-    :func:`update_device_state` picks each slot's winner.
+    ``float32[B, K]`` — rows are CANDIDATES; the state update picks each
+    slot's winner.
     """
-    prev_ts, prev_ns, _, ewma_prev = _gather_meas_state(state, batch)
-    return _fold_ewma_from(prev_ts, prev_ns, ewma_prev, batch, taus)
+    return fold_ewma_arrays(prev_ts, prev_ns, ewma_prev,
+                            batch.ts_s, batch.ts_ns, batch.value, taus)
 
 
 def compare_select(op: jax.Array, val: jax.Array,
@@ -256,6 +369,17 @@ def eval_threshold_rules(
     rules: RuleTable, state: DeviceState, batch: EventBatch,
     accepted: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`threshold_rules_on` for an unpacked :class:`DeviceState`:
+    gathers the batch's measurement-slot rows from it first."""
+    return threshold_rules_on(
+        rules, _gather_meas_state(state, batch), batch, accepted)
+
+
+def threshold_rules_on(
+    rules: RuleTable,
+    prev: Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
+    batch: EventBatch, accepted: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dense [B, R] rule evaluation over measurement events.
 
     Each rule compares the quantity its ``kind`` selects — the current
@@ -263,7 +387,9 @@ def eval_threshold_rules(
     since the device's previous sample — against its threshold, in ONE
     fused pass (reference SPI is per-event callbacks,
     ``spi/IRuleProcessor.java:50-97``; windowed logic there would be
-    host-side processor state).
+    host-side processor state).  ``prev`` is the batch's pre-batch
+    measurement-slot state ``(ts_s, ts_ns, value, ewma[B, K])``, as the
+    carry's ``gather`` returns it.
 
     Returns ``(fired_any, first_rule_id, ewma_candidates)`` — the
     candidates feed :func:`update_device_state` so the trailing stats
@@ -272,7 +398,7 @@ def eval_threshold_rules(
     is_meas = accepted & (batch.event_type == EventType.MEASUREMENT)
     v = batch.value
 
-    prev_ts, prev_ns, prev_v, ewma_prev = _gather_meas_state(state, batch)
+    prev_ts, prev_ns, prev_v, ewma_prev = prev
     seeded = prev_ts > 0
     # sub-second resolution (rate rules must fire for > 1 Hz sensors)
     dt = jnp.maximum(
@@ -349,127 +475,121 @@ def eval_zone_rules(
     return fired_any, jnp.where(fired_any, first, NULL_ID)
 
 
+def default_ewma_taus(num_ewma_scales: int) -> jax.Array:
+    """The default EWMA time-scales, the last repeated up to ``K``."""
+    base = list(DEFAULT_EWMA_TAUS)
+    return jnp.asarray(
+        (base + [base[-1]] * num_ewma_scales)[:num_ewma_scales], jnp.float32)
+
+
+def plan_state_writes(
+    cur: StateRows, batch: EventBatch, accepted: jax.Array,
+    capacity: int, num_mtype_slots: int,
+) -> RowWrites:
+    """Elect the batch rows that write each state family — the whole
+    state merge short of the scatter, on B rows.
+
+    Rows with ``update_state=False`` (system-generated events, reference
+    ``IDeviceEvent.isUpdateState()``) are persisted/fanned-out upstream
+    but never merged — and never mark a device present.
+
+    TWO sorts elect every winner (:func:`winning_rows`): one over the
+    device id for the any-event columns, and one for the three typed
+    families together — a row is a location, an alert or a measurement,
+    never two, so their slots share one id space (``[0, D*M)`` the
+    measurement matrix, then D location slots, then D alert slots) and
+    one sort ranks each family exactly as a sort of its own would.
+    Measurement slot = ``mtype_id mod M`` (host keeps mtype handles
+    dense per tenant; collisions degrade to "newest of colliding types",
+    documented in schema.DeviceState); unknown measurement types
+    (``mtype_id == NULL_ID``) are dropped, not aliased onto slot 0.
+    """
+    D, M = capacity, num_mtype_slots
+    if D * (M + 2) + 1 >= 2 ** 31:
+        raise ValueError(
+            f"capacity {D} x ({M} + 2) slot ids overflow int32")
+    ids = batch.device_id
+    ok = accepted & batch.update_state & (ids >= 0) & (ids < D)
+    is_loc = ok & (batch.event_type == EventType.LOCATION)
+    is_alert = ok & (batch.event_type == EventType.ALERT)
+    is_meas = ok & (batch.event_type == EventType.MEASUREMENT) & (
+        batch.mtype_id >= 0)
+    typed_id = jnp.where(
+        is_meas, ids * M + batch.mtype_id % M,
+        jnp.where(is_loc, D * M + ids, D * (M + 1) + ids))
+    key = (batch.ts_s, batch.ts_ns)
+    present = winning_rows(ids, key, ok, D)
+    typed_win = winning_rows(
+        typed_id, key, is_loc | is_alert | is_meas, D * (M + 2))
+    cur_s = jnp.where(is_meas, cur.val_s,
+                      jnp.where(is_loc, cur.loc_s, cur.alert_s))
+    cur_ns = jnp.where(is_meas, cur.val_ns,
+                       jnp.where(is_loc, cur.loc_ns, cur.alert_ns))
+    typed = typed_win & newer_or_equal(batch.ts_s, batch.ts_ns, cur_s, cur_ns)
+    return RowWrites(
+        present=present,
+        event=present & newer_or_equal(
+            batch.ts_s, batch.ts_ns, cur.ev_s, cur.ev_ns),
+        location=typed & is_loc,
+        alert=typed & is_alert,
+        measurement=typed & is_meas,
+    )
+
+
+def merge_into_carry(
+    carry, cur: StateRows, batch: EventBatch, accepted: jax.Array,
+    ewma_candidates: Optional[jax.Array] = None,
+    nonfinite: Optional[jax.Array] = None,
+):
+    """Merge accepted events into ``carry`` (:class:`StateColumns` or a
+    ``PackedState``) given the rows ``cur`` gathered from it: elect the
+    writers (:func:`plan_state_writes`), scatter them.  Returns
+    ``(new_state, present_now)`` in the carry's own form.
+
+    EWMA candidates fold each row's sample against PRE-batch state; the
+    newest-wins pick applies them consistently with values.  (Multiple
+    same-slot events in one batch collapse to the newest — sub-deadline
+    granularity, documented EWMA approximation.)  Callers outside
+    pipeline_step (direct state updates in tests/tools) get the default
+    time-scales; pass the RuleTable's taus to stay in sync with rule
+    evaluation.
+    """
+    if ewma_candidates is None:
+        ewma_candidates = _fold_ewma_from(
+            cur.val_s, cur.val_ns, cur.ewma, batch,
+            default_ewma_taus(carry.num_ewma_scales))
+    cap = carry.capacity
+    writes = plan_state_writes(
+        cur, batch, accepted, cap, carry.num_mtype_slots)
+    # the one registry-sized write of the step: a zero fill and B rows
+    present_now = set_rows(
+        jnp.zeros((cap,), bool),
+        drop_targets(batch.device_id, writes.present, cap), True)
+    return (carry.scatter(batch, cur, writes, ewma_candidates, nonfinite),
+            present_now)
+
+
 def update_device_state(
     state: DeviceState, batch: EventBatch, accepted: jax.Array,
     ewma_candidates: Optional[jax.Array] = None,
 ) -> Tuple[DeviceState, jax.Array]:
-    """Merge accepted events into last-known state (time-ordered scatters).
+    """Merge accepted events into last-known state.
 
     Reference: ``DeviceStateProcessingLogic.java:46-80`` merges each event
-    into the per-device state doc; here each event-type family updates its
-    columns via :func:`scatter_last_by_time`.  Rows with
-    ``update_state=False`` (system-generated events, reference
-    ``IDeviceEvent.isUpdateState()``) are persisted/fanned-out upstream but
-    never merged here — and never mark a device present.
+    into the per-device state doc.  Here the update reads and writes only
+    the rows the batch names: the slots' current time keys are gathered
+    at the batch's ids, the writers are elected among the B rows
+    (:func:`plan_state_writes`), and each column takes one unique-index
+    scatter of B rows.  Nothing is sized by the registry but
+    ``present_now``.
 
     Returns ``(new_state, present_now)`` where ``present_now`` is
     ``bool[capacity]`` — devices this step merged at least one event into
-    (the presence signal, free from the any-event winner map).
+    (the presence signal: a zero fill and one B-row scatter).
     """
-    ids = batch.device_id
-    accepted = accepted & batch.update_state
-
-    # One sort-based winner map per state family (sorts measured ~0.1 ms
-    # each at width 131k on v5e; a batched segmented associative scan
-    # sharing one sort was tried and measured 11 ms — log-depth scans do
-    # 17 unfused HBM passes, sorts are native).  The any-event map doubles
-    # as the presence signal, so presence costs no extra scatter.
-    M = state.num_mtype_slots
-    is_loc = accepted & (batch.event_type == EventType.LOCATION)
-    is_alert = accepted & (batch.event_type == EventType.ALERT)
-    # Measurement matrix: slot = mtype_id mod M (host keeps mtype handles
-    # dense per tenant; collisions degrade to "newest of colliding types",
-    # documented in schema.DeviceState).  Unknown measurement types
-    # (mtype_id == NULL_ID) are dropped, not aliased onto slot 0.
-    is_meas = accepted & (batch.event_type == EventType.MEASUREMENT) & (
-        batch.mtype_id >= 0
-    )
-    flat_ids = ids * M + batch.mtype_id % M
-    any_rows = winner_rows(ids, batch.ts_s, batch.ts_ns, accepted, state.capacity)
-    loc_rows = winner_rows(ids, batch.ts_s, batch.ts_ns, is_loc, state.capacity)
-    alert_rows = winner_rows(ids, batch.ts_s, batch.ts_ns, is_alert, state.capacity)
-    meas_rows = winner_rows(
-        flat_ids, batch.ts_s, batch.ts_ns, is_meas, state.capacity * M)
-
-    # Any-event columns.
-    new_s, new_ns, (new_type,) = apply_winners(
-        any_rows,
-        state.last_event_ts_s,
-        state.last_event_ts_ns,
-        (state.last_event_type,),
-        batch.ts_s,
-        batch.ts_ns,
-        (batch.event_type,),
-    )
-    # An accepted event marks the device present again (reference:
-    # DevicePresenceManager resets on new events).
-    presence = state.presence_missing & ~(any_rows >= 0)
-
-    # Location columns.
-    loc_s, loc_ns, (lat, lon, elev) = apply_winners(
-        loc_rows,
-        state.last_location_ts_s,
-        state.last_location_ts_ns,
-        (state.last_lat, state.last_lon, state.last_elevation),
-        batch.ts_s,
-        batch.ts_ns,
-        (batch.lat, batch.lon, batch.elevation),
-    )
-
-    # Alert columns.
-    alert_s, alert_ns, (alert_code,) = apply_winners(
-        alert_rows,
-        state.last_alert_ts_s,
-        state.last_alert_ts_ns,
-        (state.last_alert_code,),
-        batch.ts_s,
-        batch.ts_ns,
-        (batch.alert_code,),
-    )
-
-    # EWMA candidates fold each row's sample against PRE-batch state; the
-    # scatter's newest-wins pick applies them consistently with values.
-    # (Multiple same-slot events in one batch collapse to the newest —
-    # sub-deadline granularity, documented EWMA approximation.)  Callers
-    # outside pipeline_step (direct state updates in tests/tools) get the
-    # default time-scales; pass the RuleTable's taus to stay in sync with
-    # rule evaluation.
-    if ewma_candidates is None:
-        base = list(DEFAULT_EWMA_TAUS)
-        k = state.num_ewma_scales
-        taus = jnp.asarray((base + [base[-1]] * k)[:k], jnp.float32)
-        ewma_candidates = fold_ewma(state, batch, taus)
-    val_s, val_ns, (values, ewma) = apply_winners(
-        meas_rows,
-        state.last_value_ts_s.reshape(-1),
-        state.last_value_ts_ns.reshape(-1),
-        (state.last_values.reshape(-1),
-         state.ewma_values.reshape(-1, state.num_ewma_scales)),
-        batch.ts_s,
-        batch.ts_ns,
-        (batch.value, ewma_candidates),
-    )
-
-    mshape = state.last_value_ts_s.shape
-    new_state = state.replace(
-        last_event_ts_s=new_s,
-        last_event_ts_ns=new_ns,
-        last_event_type=new_type,
-        presence_missing=presence,
-        last_location_ts_s=loc_s,
-        last_location_ts_ns=loc_ns,
-        last_lat=lat,
-        last_lon=lon,
-        last_elevation=elev,
-        last_alert_ts_s=alert_s,
-        last_alert_ts_ns=alert_ns,
-        last_alert_code=alert_code,
-        last_value_ts_s=val_s.reshape(mshape),
-        last_value_ts_ns=val_ns.reshape(mshape),
-        last_values=values.reshape(state.last_values.shape),
-        ewma_values=ewma.reshape(state.ewma_values.shape),
-    )
-    return new_state, any_rows >= 0
+    carry = StateColumns(state)
+    return merge_into_carry(
+        carry, carry.gather(batch), batch, accepted, ewma_candidates)
 
 
 def _build_derived_alerts(
@@ -491,8 +611,8 @@ def _build_derived_alerts(
 
     safe_rule = jnp.clip(rule_id, 0, rules.capacity - 1)
     safe_zone = jnp.clip(zone_id, 0, zones.capacity - 1)
-    # Packed [B, 2] gathers (code, level) per table — halves the [B]-sized
-    # gather count (each ~1 ms at width 131k on v5e).
+    # Packed [B, 2] gathers (code, level) per table: one gather of B
+    # two-column rows instead of two of B scalars.
     rpack = jnp.stack([rules.alert_code, rules.alert_level], axis=1)[safe_rule]
     zpack = jnp.stack([zones.alert_code, zones.alert_level], axis=1)[safe_zone]
     code = jnp.where(zone_fired, zpack[:, 0], rpack[:, 0])
@@ -532,12 +652,28 @@ def pipeline_step(
     """The fused inbound step: validate → enrich → rules → state → outputs.
 
     Pure function of its inputs — jit/pjit it once and feed batches forever.
+    :func:`fused_step` over the unpacked carry.
+    """
+    return fused_step(RegistryColumns(registry), StateColumns(state),
+                      rules, zones, batch)
+
+
+def fused_step(registry, carry, rules: RuleTable,
+               zones: ZoneTable, batch: EventBatch):
+    """The step over any registry that can look up the batch's rows
+    (:class:`RegistryColumns`, ``PackedTables``) and any carry that can
+    ``gather`` them and ``scatter`` the winners back
+    (:class:`StateColumns`, ``PackedState``): registry and state are
+    touched exactly there, and every stage between works on batch-sized
+    arrays.  Returns ``(new_state,
+    outputs)``, the state in the carry's own form.
+
     The five stages carry ``jax.named_scope`` names (:data:`STEP_STAGES`)
     — metadata only: a profiler capture shows them as each op's
     ``op_name`` prefix, the compiled program is the same.
     """
     with jax.named_scope("validate_enrich"):
-        accepted, unregistered, unassigned, enrich = validate_and_enrich(
+        accepted, unregistered, unassigned, enrich = validate_rows(
             registry, batch)
     # Numeric integrity: a NaN/Inf in any float column would flow through
     # the EWMA fold, the rule compares (NE is True for NaN!) and the
@@ -549,23 +685,19 @@ def pipeline_step(
     nonfinite = batch.valid & ~finite
     clean = accepted & finite
     with jax.named_scope("threshold_rules"):
-        rule_fired, rule_id, ewma_candidates = eval_threshold_rules(
-            rules, state, batch, clean)
+        cur = carry.gather(batch)
+        rule_fired, rule_id, ewma_candidates = threshold_rules_on(
+            rules, (cur.val_s, cur.val_ns, cur.value, cur.ewma), batch, clean)
     with jax.named_scope("zone_rules"):
         zone_fired, zone_id = eval_zone_rules(
             zones, batch, clean, enrich["area_id"])
     with jax.named_scope("state_update"):
-        new_state, present_now = update_device_state(
-            state, batch, clean, ewma_candidates)
-        # Per-device attribution rides device state (one scatter-add, no
-        # host sync): the quarantine threshold is evaluated host-side
-        # from the packed telemetry scalar + this counter.
-        cap = state.capacity
-        nf_idx = jnp.where(nonfinite & (batch.device_id >= 0)
-                           & (batch.device_id < cap), batch.device_id, cap)
-        new_state = new_state.replace(
-            nonfinite_count=new_state.nonfinite_count.at[nf_idx].add(
-                1, mode="drop"))
+        # Per-device nonfinite attribution rides device state (one
+        # scatter-add, no host sync): the quarantine threshold is
+        # evaluated host-side from the packed telemetry scalar + this
+        # counter.
+        new_state, present_now = merge_into_carry(
+            carry, cur, batch, clean, ewma_candidates, nonfinite)
     with jax.named_scope("derived_alerts"):
         derived = _build_derived_alerts(
             batch, rules, zones, rule_id, zone_id)

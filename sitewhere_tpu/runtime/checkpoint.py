@@ -708,8 +708,8 @@ class Checkpointer(LifecycleComponent):
                     continue
                 updates[k] = jnp.asarray(arr)
             if "ewma_values" in skipped or "ewma_values" not in z:
-                # fold_ewma seeds on last_value_ts_s > 0 — restoring the
-                # timestamps without the EWMAs would treat zeroed averages
+                # fold_ewma_arrays seeds on last_value_ts_s > 0 — restoring
+                # the timestamps without the EWMAs would treat zeroed averages
                 # as seeded and drag windowed rules toward 0; drop the
                 # measurement stats together so seeding re-occurs
                 for k in ("last_value_ts_s", "last_value_ts_ns",

@@ -317,8 +317,9 @@ class PipelineDispatcher(LifecycleComponent):
             # would leave concurrent readers (checkpointer, presence
             # sweep, REST queries) holding deleted buffers until
             # commit_packed lands.  Donation is for private carries
-            # (bench loops); here XLA just allocates fresh output
-            # buffers (~3 MB/step, HBM-trivial).
+            # (the ring's leased chain); here the step scatters into a
+            # copy of the carry, the one registry-sized move it makes
+            # (PERF.md §5).
             from sitewhere_tpu.pipeline.packed import packed_pipeline_step
 
             self._packed_step = jax.jit(packed_pipeline_step)

@@ -195,15 +195,15 @@ def _packed_codecs():
 
 
 @jax.jit
-def _merge_presence(new_si, cur_si, present_now):
+def _merge_presence(new_rows, cur_rows, present_now):
     """Packed-form presence reconciliation (see :meth:`commit` docstring):
     a concurrent sweep's missing flags survive unless THIS step merged an
     event for the device."""
-    from sitewhere_tpu.pipeline.packed import PRESENCE_ROW
+    from sitewhere_tpu.pipeline.packed import PRESENCE_LANE
 
-    merged = (new_si[PRESENCE_ROW] != 0) | (
-        (cur_si[PRESENCE_ROW] != 0) & ~present_now)
-    return new_si.at[PRESENCE_ROW].set(merged.astype(new_si.dtype))
+    merged = (new_rows[:, PRESENCE_LANE] != 0) | (
+        (cur_rows[:, PRESENCE_LANE] != 0) & ~present_now)
+    return new_rows.at[:, PRESENCE_LANE].set(merged.astype(new_rows.dtype))
 
 
 class DeviceStateManager(LifecycleComponent):
@@ -360,8 +360,8 @@ class DeviceStateManager(LifecycleComponent):
                 or (lease_token is not None and self._state is lease_token))
             if not unchanged:
                 cur = self.current_packed
-                new_packed = new_packed.replace(
-                    si=_merge_presence(new_packed.si, cur.si, present_now))
+                new_packed = new_packed.replace(rows=_merge_presence(
+                    new_packed.rows, cur.rows, present_now))
             self._packed = new_packed
             self._state = None
 

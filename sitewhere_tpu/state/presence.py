@@ -40,6 +40,15 @@ STATE_CHANGE_PRESENCE_MISSING = 1
 STATE_CHANGE_QUARANTINED = 2
 
 
+def newly_missing(last_event_type, last_event_ts_s, presence_missing,
+                  now_s, missing_after_s) -> jax.Array:
+    """``bool[D]``: devices a sweep at ``now_s`` flags — seen at least
+    once, not flagged yet, last event older than the missing interval."""
+    has_events = last_event_type != NULL_ID
+    overdue = (now_s - last_event_ts_s) > missing_after_s
+    return has_events & overdue & ~presence_missing
+
+
 @jax.jit
 def presence_sweep(
     state: DeviceState, now_s: jax.Array, missing_after_s: jax.Array
@@ -49,13 +58,10 @@ def presence_sweep(
     Returns ``(new_state, newly_missing)`` where ``newly_missing`` is a
     ``bool[D]`` mask of devices flagged by THIS sweep (the send-once set).
     """
-    has_events = state.last_event_type != NULL_ID
-    overdue = (now_s - state.last_event_ts_s) > missing_after_s
-    newly_missing = has_events & overdue & ~state.presence_missing
-    return (
-        state.replace(presence_missing=state.presence_missing | newly_missing),
-        newly_missing,
-    )
+    newly = newly_missing(
+        state.last_event_type, state.last_event_ts_s, state.presence_missing,
+        now_s, missing_after_s)
+    return state.replace(presence_missing=state.presence_missing | newly), newly
 
 
 def state_changes_for(

@@ -42,6 +42,26 @@ def test_chip_side_programs_compile_for_v5e(capsys):
             == rows["packed_chain_k8_donated"]["alias_bytes"])
 
 
+@pytest.mark.parametrize("capacity", [1 << 14, 1 << 18])
+def test_chip_side_step_costs_the_batch_not_the_registry(capacity):
+    """The chip's own compile of the packed step and of the donated
+    chain, at one width and two capacities: nothing sized by the registry
+    but the carry (scattered into in place — one copy for the step, whose
+    carry is the live epoch, NONE for the donated chain), the registry
+    table and the ``present_now`` vector."""
+    rows = {r["program"]: r for r in aot_check.aot_check(
+        capacity=capacity, width=32, ring_depth=8,
+        programs=("packed_step", "packed_chain"))}
+    aot_check.assert_step_costs_the_batch(
+        rows["packed_step"]["hlo"], capacity, donated=False)
+    aot_check.assert_step_costs_the_batch(
+        rows["packed_chain_k8_donated"]["hlo"], capacity, donated=True)
+    # no scatter was expanded into a serial loop over the batch: the one
+    # while of the chain is its own fori_loop
+    assert rows["packed_step"]["hlo"].count(" while(") == 0
+    assert rows["packed_chain_k8_donated"]["hlo"].count(" while(") == 1
+
+
 def test_chip_side_tracing_is_scoped():
     assert jax.default_backend() == "cpu"
     with aot_check.chip_side_tracing():

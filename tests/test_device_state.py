@@ -6,6 +6,7 @@ overdue devices (DevicePresenceManager), send-once notification semantics,
 and re-arming when a device comes back.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -281,8 +282,8 @@ class TestLeasePacked:
         new_ps, _oi, _mets, present = self._packed_step(
             ps, [measurement(0, ts=5000)])
         # simulate the donation: the chain consumed the leased buffers
-        ps.si.delete()
-        ps.sf.delete()
+        for leaf in jax.tree.leaves(ps):
+            leaf.delete()
         # a reader arriving mid-chain sees the pre-chain epoch from the
         # materialized twin — never the deleted/donated buffers
         assert manager.get_device_state("dev-0")["last_event_ts_s"] == 1000

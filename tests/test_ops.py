@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sitewhere_tpu.ops.geo import points_in_polygons
 from sitewhere_tpu.ops.scatter import (
@@ -148,21 +149,64 @@ def test_pad_polygon_contract():
         pad_poly([[0, 0]] * 9, 6)      # too many
 
 
-def test_winner_rows_sort_and_scatter_paths_agree():
-    """The TPU (sort) and CPU (scatter) winner-selection paths are
-    interchangeable: same winners, same tie-breaks, same drops."""
-    from sitewhere_tpu.ops.scatter import _winner_rows_scatter, _winner_rows_sort
+def _winning_rows_loop(ids, keys, mask, cap):
+    """Plain loop: per slot the masked row with the largest key tuple,
+    the highest row on exact ties."""
+    best = {}
+    for r in range(len(ids)):
+        if not mask[r] or not 0 <= ids[r] < cap:
+            continue
+        k = tuple(int(key[r]) for key in keys)
+        if ids[r] not in best or k >= best[ids[r]][0]:
+            best[ids[r]] = (k, r)
+    won = np.zeros(len(ids), bool)
+    won[[r for _, r in best.values()]] = True
+    return won
+
+
+@pytest.mark.parametrize("n_keys", [2, 1])
+def test_winning_rows_match_the_plain_loop(n_keys):
+    """The batch-sized winner election: same winners, same tie-breaks,
+    same drops as a per-row loop — two-part time key and the single-key
+    form (scatter_max_by_key's)."""
+    from sitewhere_tpu.ops.scatter import winning_rows
 
     rng = np.random.default_rng(7)
     b, cap = 4096, 257
-    ids = jnp.asarray(rng.integers(-3, cap + 3, b).astype(np.int32))
-    ts_s = jnp.asarray(rng.integers(100, 110, b).astype(np.int32))
-    ts_ns = jnp.asarray(rng.integers(0, 4, b).astype(np.int32))
-    mask = jnp.asarray(rng.random(b) < 0.7)
-    a = _winner_rows_sort(ids, (ts_s, ts_ns), mask, cap)
-    c = _winner_rows_scatter(ids, (ts_s, ts_ns), mask, cap)
-    assert a.tolist() == c.tolist()
-    # single-key form too (scatter_max_by_key path)
-    a1 = _winner_rows_sort(ids, (ts_s,), mask, cap)
-    c1 = _winner_rows_scatter(ids, (ts_s,), mask, cap)
-    assert a1.tolist() == c1.tolist()
+    ids = rng.integers(-3, cap + 3, b).astype(np.int32)
+    keys = (rng.integers(100, 110, b).astype(np.int32),
+            rng.integers(0, 4, b).astype(np.int32))[:n_keys]
+    mask = rng.random(b) < 0.7
+    got = winning_rows(jnp.asarray(ids), tuple(map(jnp.asarray, keys)),
+                       jnp.asarray(mask), cap)
+    assert got.shape == (b,)    # batch-sized: no [capacity] map
+    assert np.asarray(got).tolist() == _winning_rows_loop(
+        ids, keys, mask, cap).tolist()
+
+
+@pytest.mark.parametrize("b,cap", [(512, 4096), (64, 64), (256, 7)])
+def test_merge_rows_by_id_sums_a_batchs_changes_per_id(b, cap):
+    """One merged row per id, at the id's last sorted row: the gathered
+    row plus every change the batch states for the id (wrapping int32);
+    ids out of range and the other rows of a run aim out of range."""
+    from sitewhere_tpu.ops.scatter import merge_rows_by_id
+
+    rng = np.random.default_rng(b)
+    table = rng.integers(-2**31, 2**31, (cap, 8), dtype=np.int64).astype(np.int32)
+    ids = rng.integers(-2, cap + 2, b).astype(np.int32)
+    change = rng.integers(-2**31, 2**31, (b, 8), dtype=np.int64).astype(np.int32)
+    base = table[np.clip(ids, 0, cap - 1)]
+    targets, merged = merge_rows_by_id(
+        jnp.asarray(ids), jnp.asarray(base), jnp.asarray(change), cap)
+    targets, merged = np.asarray(targets), np.asarray(merged)
+    want = table.astype(np.int64)
+    for r in range(b):
+        if 0 <= ids[r] < cap:
+            want[ids[r]] += change[r]
+    want = want.astype(np.int32)          # wraps as int32 adds do
+    written = targets < cap
+    assert sorted(targets[written]) == sorted(set(ids[(ids >= 0) & (ids < cap)]))
+    assert len(set(targets.tolist())) == b     # unique, as the scatter promises
+    got = table.copy()
+    got[targets[written]] = merged[written]
+    np.testing.assert_array_equal(got, want)

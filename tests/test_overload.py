@@ -823,3 +823,93 @@ class TestRpcBackpressure:
             chan.close()
         finally:
             srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# a plan in flight across a checkpoint save (PERF.md §6, PR 28): the
+# dispatcher's own lag signal into the ladder as Instance wires it,
+# every clock injected
+# ---------------------------------------------------------------------------
+
+class TestPlanInFlightAcrossASave:
+    """What PR 25's two shed sends were read as, pinned: a background save
+    that holds the GIL ages the one plan it catches in flight, and the
+    ladder must tell that from a wedged pipeline.  Times are the cell's
+    (``fleet-1m.wire-steady``, my chip runs, PR 28): a plan lives ~23 ms,
+    the save's longest hold is the stores' pickle, ~0.8 s of a 1.3–1.4 s
+    save."""
+
+    STEP_TO_SEAL_S = 0.023
+
+    @pytest.fixture
+    def wired(self, monkeypatch):
+        from test_host_pipeline import ingest_window_at, make_ring_dispatcher
+
+        clock = FakeClock()
+        monkeypatch.setattr(time, "monotonic", clock)
+        disp, store, _ = make_ring_dispatcher(
+            ring_depth=0, egress_offload=False)   # not started: no threads
+        disp.batcher.clock = clock                # stamps plan.created_at
+        disp.steps = 1                            # past the warm-up gate
+        ladder = OverloadController(
+            confirm_samples=2, sample_interval_s=0.1,   # runtime/config.py
+            signals_fn=lambda: OverloadSignals(
+                seal_lag_s=disp.oldest_unsealed_wait_s()),
+            clock=clock, metrics=MetricsRegistry())
+
+        def dispatch():
+            ingest_window_at(disp, 0)
+            assert len(disp._inflight) == 1
+        return clock, disp, store, ladder, dispatch
+
+    def test_a_plan_that_seals_in_time_never_reads_as_lag(self, wired):
+        clock, disp, store, ladder, dispatch = wired
+        shed_at = Watermarks().seal_lag_s
+        for _ in range(20):                # a payload every 1.6 s
+            dispatch()
+            clock.t += self.STEP_TO_SEAL_S
+            assert 0.0 < disp.oldest_unsealed_wait_s() < shed_at[0]
+            assert ladder.tick() == OverloadState.NORMAL
+            disp.flush()
+            assert disp.oldest_unsealed_wait_s() == 0.0
+            clock.t += 1.6 - self.STEP_TO_SEAL_S
+            assert ladder.tick() == OverloadState.NORMAL
+        assert ladder.transitions == 0
+
+    @pytest.mark.parametrize("hold_s", [0.8, 1.4])
+    def test_one_plan_caught_by_the_save_does_not_shed(self, wired, hold_s):
+        """The hold starves the dispatch loop and the egress worker alike;
+        when it ends the loop's first sample reads the plan's whole age,
+        past the SHEDDING watermark — and the plan seals before the
+        second sample the ladder asks for, so no send is refused."""
+        clock, disp, store, ladder, dispatch = wired
+        degraded, shedding, _ = Watermarks().seal_lag_s
+        dispatch()
+        clock.t += 0.005 + hold_s          # caught 5 ms into its life
+        assert disp.oldest_unsealed_wait_s() >= shedding
+        assert ladder.tick() == OverloadState.NORMAL     # one sample
+        disp.flush()                       # the worker gets the GIL back
+        clock.t += 0.1
+        assert disp.oldest_unsealed_wait_s() < degraded
+        assert ladder.tick() == OverloadState.NORMAL
+        assert ladder.admit(PriorityClass.TELEMETRY, n=1024)
+        assert ladder.transitions == 0 and store.rows > 0
+
+    def test_a_plan_that_stays_in_flight_still_sheds(self, wired):
+        """The same age held over two samples is a wedged pipeline: the
+        ladder goes to SHEDDING, refuses telemetry, and comes back one
+        cooldown after the plan seals."""
+        clock, disp, store, ladder, dispatch = wired
+        dispatch()
+        clock.t += 0.8
+        ladder.tick()
+        clock.t += 0.1
+        assert ladder.tick() == OverloadState.SHEDDING
+        assert ladder.last_driver == "seal_lag_s"
+        assert not ladder.admit(PriorityClass.TELEMETRY, n=1024)
+        disp.flush()
+        clock.t += 0.1
+        ladder.tick()
+        clock.t += ladder.cooldown_s
+        assert ladder.tick() == OverloadState.NORMAL
+        assert ladder.admit(PriorityClass.TELEMETRY, n=1024)

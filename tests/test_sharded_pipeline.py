@@ -138,8 +138,6 @@ def test_sharded_packed_matches_single_chip(mesh8):
     """The packed mesh form (deployment config): same outputs and state
     as the single-chip unpacked step, through the [C, B]-sharded wire
     interface."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     from sitewhere_tpu.pipeline.packed import (
         PackedView,
         pack_batch_host,
@@ -150,6 +148,8 @@ def test_sharded_packed_matches_single_chip(mesh8):
     from sitewhere_tpu.pipeline.sharded import (
         build_sharded_packed_step,
         place_packed_batch,
+        place_packed_state,
+        place_packed_tables,
     )
     from sitewhere_tpu.schema import as_numpy
 
@@ -172,14 +172,8 @@ def test_sharded_packed_matches_single_chip(mesh8):
     ref = as_numpy(ref_out)
 
     # packed + placed inputs
-    tables = pack_tables(reg, rules, zones)
-    tables = tables.replace(
-        reg_i=jax.device_put(tables.reg_i,
-                             NamedSharding(mesh8, P(None, "shard"))))
-    ps = pack_state(DeviceState.empty(CAP))
-    ps = ps.replace(
-        si=jax.device_put(ps.si, NamedSharding(mesh8, P(None, "shard"))),
-        sf=jax.device_put(ps.sf, NamedSharding(mesh8, P(None, "shard"))))
+    tables = place_packed_tables(mesh8, pack_tables(reg, rules, zones))
+    ps = place_packed_state(mesh8, pack_state(DeviceState.empty(CAP)))
     cols = {f: np.asarray(getattr(as_numpy(batch), f))
             for f in batch.__dataclass_fields__}
     bi, bf = pack_batch_host(cols, WIDTH)
@@ -207,4 +201,6 @@ def test_sharded_packed_matches_single_chip(mesh8):
     assert int(m.processed) == 6 and int(m.accepted) == 5
     assert int(m.threshold_alerts) == 1 and int(m.zone_alerts) == 1
     # steady-state: the packed carry keeps its sharding
-    assert new_ps.si.sharding == ps.si.sharding
+    for new, old in zip(jax.tree.leaves(new_ps), jax.tree.leaves(ps)):
+        assert new.sharding == old.sharding
+        assert len(new.sharding.device_set) == 8

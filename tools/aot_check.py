@@ -48,8 +48,8 @@ _COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
 @contextlib.contextmanager
 def chip_side_tracing():
     """Make ``jax.default_backend()`` answer ``"tpu"`` while tracing, so
-    the ``default_backend()`` switches (sort-vs-scatter winner map, the
-    Pallas geofence threshold) lower the side the chip runs."""
+    the ``default_backend()`` switches (the Pallas geofence threshold)
+    lower the side the chip runs."""
     import jax
 
     real = jax.default_backend
@@ -96,6 +96,100 @@ def _packed_avals(capacity: int, width: int, mtype_slots: int):
     return tables, state, bi, bf
 
 
+_SHAPE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
+# how a registry-sized value may come to be: handed on, or updated in place
+_HANDED_ON = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+              "call", "conditional", "optimization-barrier"}
+_CARRY_OPS = _HANDED_ON | {"fusion", "scatter", "dynamic-update-slice"}
+_COPY_OPS = {"copy", "copy-start"}
+_TABLE_OPS = _HANDED_ON | {"copy-start", "copy-done"}
+
+
+def assert_step_costs_the_batch(hlo: str, capacity: int, donated: bool,
+                                mtype_slots: int = 8) -> None:
+    """Hold a compiled step program (its HLO text) to the batch: every
+    value with at least ``capacity`` elements must be
+
+    - the packed carry ``[capacity, W]`` (or its flat bitcast), handed
+      on or updated in place (``scatter`` / ``dynamic-update-slice``),
+      copied at most once where the carry is not ``donated`` and never
+      where it is (a small carry the chip's compiler stages through
+      fast memory, ``S(1)``, may move in and out of it once besides);
+    - the packed registry table (8 x capacity elements), only read;
+    - a vector of ``capacity`` elements (``present_now``: its zero fill,
+      its one scatter, the chain's OR and the telemetry count).
+
+    Anything else — a ``[capacity x M, k]`` pack, a rewritten state
+    column, a ``[capacity]`` map per family — raises ``AssertionError``.
+    Batch-sized values must stay under ``capacity`` elements for this to
+    tell them apart: lower at a width of 64 or so.
+    """
+    from sitewhere_tpu.pipeline.packed import packed_row_width
+
+    carry = capacity * packed_row_width(mtype_slots, 3)
+    table = capacity * 8
+    copies, staged, offenders = 0, 0, []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        shapes, op = m.groups()
+        for _, dims in _SHAPE.findall(shapes):
+            sizes = [int(d) for d in dims.split(",") if d]
+            n = 1
+            for d in sizes:
+                n *= d
+            if n < capacity:
+                continue
+            if n == capacity and max(sizes) == capacity:
+                continue                      # a [capacity] vector
+            if n == carry and op in _CARRY_OPS:
+                continue
+            if n == carry and op in _COPY_OPS:
+                if "S(1)" in line:
+                    staged += 1
+                else:
+                    copies += 1
+                break                  # one instruction, one copy
+            if n == carry and op == "copy-done":
+                continue
+            if n == table and op in _TABLE_OPS:
+                continue
+            offenders.append(f"{op} {shapes.strip()[:80]}")
+    if offenders:
+        raise AssertionError(
+            f"values sized by the registry (capacity {capacity}): "
+            + "; ".join(sorted(set(offenders))[:8]))
+    allowed = 0 if donated else 1
+    if staged > 2 or copies > allowed:
+        raise AssertionError(
+            f"{copies} copies of the carry (and {staged} through fast "
+            f"memory), sized by the registry (capacity {capacity}); at most "
+            f"{allowed} {'with' if donated else 'without'} donation")
+
+
+def compiled_hlo(program: str, capacity: int, width: int,
+                 mtype_slots: int = 8, ring_depth: int = 8) -> str:
+    """Optimized HLO of ``packed_step`` or ``packed_chain_k<K>_donated``
+    compiled for the DEFAULT backend (the CPU in tier-1) from shapes."""
+    import jax
+
+    from sitewhere_tpu.pipeline.packed import (
+        build_packed_chain,
+        packed_pipeline_step,
+    )
+
+    tables, state, bi, bf = _packed_avals(capacity, width, mtype_slots)
+    if program == "packed_step":
+        lowered = jax.jit(packed_pipeline_step).lower(tables, state, bi, bf)
+    else:
+        k = ring_depth
+        lowered = build_packed_chain(k, donate=True).lower(
+            tables, state, *([bi] * k), *([bf] * k))
+    return lowered.compile().as_text()
+
+
 def _report(name: str, lowered, t0: float) -> Dict[str, object]:
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
@@ -115,15 +209,20 @@ def _report(name: str, lowered, t0: float) -> Dict[str, object]:
             if (n := len(re.findall(rf"= \S+ {op}(?:-start)?\(", hlo)))},
     }
     print(json.dumps(row), flush=True)
+    row["hlo"] = hlo
     return row
 
 
 def aot_check(capacity: int = 4096, width: int = 1024, ring_depth: int = 8,
               mtype_slots: int = 8,
               pallas_shape: Tuple[int, int, int] = (4096, 100, 8),
+              programs: Tuple[str, ...] = (
+                  "packed_step", "packed_chain", "sharded_chain",
+                  "geo_pallas"),
               ) -> List[Dict[str, object]]:
-    """Compile the four chip-side programs for the v5e topology; returns
-    one row per program (raises if any fails to lower or compile)."""
+    """Compile the chip-side ``programs`` (all four by default) for the
+    v5e topology; returns one row per program, its optimized HLO under
+    ``"hlo"`` (raises if any fails to lower or compile)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -137,6 +236,7 @@ def aot_check(capacity: int = 4096, width: int = 1024, ring_depth: int = 8,
         packed_pipeline_step,
     )
     from sitewhere_tpu.pipeline.sharded import (
+        _PACKED_BATCH_SPEC,
         _PACKED_STATE_SPEC,
         _packed_tables_specs,
         build_sharded_packed_chain,
@@ -150,41 +250,45 @@ def aot_check(capacity: int = 4096, width: int = 1024, ring_depth: int = 8,
                 (SHARD_AXIS, MODEL_AXIS))
     tables, state, bi, bf = _packed_avals(capacity, width, mtype_slots)
     rows = []
+    k = ring_depth
     with chip_side_tracing():
         args1 = _abstract((tables, state, bi, bf), one)
-        t0 = time.perf_counter()
-        rows.append(_report(
-            "packed_step",
-            jax.jit(packed_pipeline_step).lower(*args1), t0))
-
-        k = ring_depth
-        t0 = time.perf_counter()
-        rows.append(_report(
-            f"packed_chain_k{k}_donated",
-            build_packed_chain(k, donate=True).lower(
-                args1[0], args1[1], *([args1[2]] * k), *([args1[3]] * k)),
-            t0))
-
-        m_tables = jax.tree.map(
-            lambda a, spec: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
-            tables, _packed_tables_specs())
-        m_state, m_bi, m_bf = _abstract(
-            (state, bi, bf), NamedSharding(mesh, _PACKED_STATE_SPEC))
-        t0 = time.perf_counter()
-        rows.append(_report(
-            f"sharded_chain_k{k}_2x2",
-            build_sharded_packed_chain(mesh, k, donate=True).lower(
-                m_tables, m_state, *([m_bi] * k), *([m_bf] * k)), t0))
-
-        b, z, v = pallas_shape
-        pts = jax.ShapeDtypeStruct((b, 2), jnp.float32, sharding=one)
-        verts = jax.ShapeDtypeStruct((z, v, 2), jnp.float32, sharding=one)
-        t0 = time.perf_counter()
-        rows.append(_report(
-            f"geo_pallas_{b}x{z}x{v}",
-            points_in_polygons_pallas.lower(pts, verts, interpret=False),
-            t0))
+        if "packed_step" in programs:
+            t0 = time.perf_counter()
+            rows.append(_report(
+                "packed_step",
+                jax.jit(packed_pipeline_step).lower(*args1), t0))
+        if "packed_chain" in programs:
+            t0 = time.perf_counter()
+            rows.append(_report(
+                f"packed_chain_k{k}_donated",
+                build_packed_chain(k, donate=True).lower(
+                    args1[0], args1[1], *([args1[2]] * k),
+                    *([args1[3]] * k)), t0))
+        if "sharded_chain" in programs:
+            m_tables = jax.tree.map(
+                lambda a, spec: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+                tables, _packed_tables_specs())
+            m_state = _abstract(
+                state, NamedSharding(mesh, _PACKED_STATE_SPEC))
+            m_bi, m_bf = _abstract(
+                (bi, bf), NamedSharding(mesh, _PACKED_BATCH_SPEC))
+            t0 = time.perf_counter()
+            rows.append(_report(
+                f"sharded_chain_k{k}_2x2",
+                build_sharded_packed_chain(mesh, k, donate=True).lower(
+                    m_tables, m_state, *([m_bi] * k), *([m_bf] * k)), t0))
+        if "geo_pallas" in programs:
+            b, z, v = pallas_shape
+            pts = jax.ShapeDtypeStruct((b, 2), jnp.float32, sharding=one)
+            verts = jax.ShapeDtypeStruct((z, v, 2), jnp.float32,
+                                         sharding=one)
+            t0 = time.perf_counter()
+            rows.append(_report(
+                f"geo_pallas_{b}x{z}x{v}",
+                points_in_polygons_pallas.lower(
+                    pts, verts, interpret=False), t0))
     return rows
 
 
