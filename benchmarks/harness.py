@@ -207,16 +207,21 @@ class Checks:
 
     def __init__(self, log) -> None:
         self.failed: list = []
+        self.compared: dict = {}      # name -> [number, its limit]
         self.log = log
 
-    def check(self, name: str, ok, detail: str = "") -> None:
+    def check(self, name: str, ok, detail: str = "", got=None,
+              limit=1) -> None:
+        """A yes-or-no comparison is the number 1 or 0 against 1."""
         self.log(f"  [{'ok' if ok else 'FAIL'}] {name}"
                  + (f": {detail}" if detail else ""))
+        self.compared[name] = [int(bool(ok)) if got is None else got, limit]
         if not ok:
             self.failed.append(name)
 
     def equal(self, name: str, got, want) -> None:
-        self.check(name, got == want, f"got {got}, want {want}")
+        """Exact: the limit is the reference's number, the gap 0."""
+        self.check(name, got == want, f"got {got}, want {want}", got, want)
 
 
 def _window_watch(dep, meter, seconds, t_begin, trace_dir, trace_s, out):
@@ -381,6 +386,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                 f"{checks.failed}")
         if on_run is not None:
             on_run(run)
+        # last key: every number compared beside its limit
+        result["compared"] = checks.compared
         return result
     finally:
         dep.close()

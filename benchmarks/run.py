@@ -46,6 +46,10 @@ def main() -> int:
         log=lambda line: print(line, flush=True))
     if result is None:
         return 1
+    # the deployment is closed: these are the last lines on standard error
+    for name, (got, limit) in result["compared"].items():
+        print(f"compared {name}: {got} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -78,6 +78,28 @@ def test_every_file_of_a_cell_resolves_by_name(name):
         assert cell["traffic"]["rate_events_per_s"] > 0
 
 
+@pytest.mark.parametrize("name", CELLS)
+def test_a_cells_own_file_carries_only_what_its_mix_takes(name):
+    """``cells/<cell>.json`` lays a rate over an open-loop mix or a depth
+    over a closed-loop one, never the other's, and says where the number
+    comes from; a depth in the mix itself says so too."""
+    cell = cells.resolve_cell(name)
+    traffic = cell["traffic"]
+    own_path = os.path.join(cells.HERE, "cells", name + ".json")
+    own = cells.load_json(own_path) if os.path.exists(own_path) else {}
+    for number, origin in (("clients", "clients_from"),
+                           ("rate_events_per_s", "rate_from")):
+        assert (number in own) <= (origin in own), (name, origin)
+        if number in traffic:
+            assert isinstance(traffic.get(origin), str) and traffic[origin]
+    # the cell's file is laid over the mix, so this holds both to it
+    if traffic["kind"].endswith("closed-loop"):
+        assert int(traffic["clients"]) >= 1
+        assert "rate_events_per_s" not in traffic
+    else:
+        assert "clients" not in traffic
+
+
 def test_metric_and_kind_files_all_belong_to_an_entry():
     here = os.path.join(cells.REPO, "benchmarks")
     for folder, section in (("end_to_end", "end_to_end"),

@@ -58,7 +58,9 @@ def test_the_cell_resolves_to_its_files_its_chips_and_its_metrics():
     assert cell["config"]["fleet"]["devices"] == 131072
     assert cell["config"]["reduced"] == ["fleet.devices", "chips"]
     assert cell["traffic"]["kind"] == "columns-closed-loop"
-    assert cell["traffic"]["clients"] == 16
+    # the mix's own depth (PR 30's sweep), the one-chip columns cell's too
+    assert cell["traffic"]["clients"] == 24 == cells.resolve_cell(
+        "fleet-1m.columns-saturate")["traffic"]["clients"]
     assert "rate_events_per_s" not in cell["traffic"]   # no cells/ file
     assert [e["name"] for e, _ in cell["end_to_end"]] \
         == ["events_per_s", "setup_s"]

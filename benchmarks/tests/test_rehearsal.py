@@ -18,9 +18,13 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_body_at_toy_size(name):
     result, run = toy_run(name, trace=False)
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["correct"] is True and result["failed"] == 0
+    # every number compared beside its limit, an exact one equal to it
+    assert len(result["compared"]) >= 20
+    for what, (got, limit) in result["compared"].items():
+        assert got == limit, what
     assert result["attempted"] > 0
     cell = cells.resolve_cell(name)
     assert set(result["metrics"]) == {e["name"] for e, _ in

@@ -102,4 +102,5 @@ def test_every_new_entry_has_its_reader_and_its_cells():
             assert entry["moves"] == "latency_p50_ms"
         else:
             assert entry["moves"] == "events_per_s"
-    assert "workloads" not in entries["dispatch_blocked_ms"]
+    assert entries["dispatch_blocked_ms"]["workloads"] == [
+        w["name"] for w in bench["workloads"]]
