@@ -597,7 +597,6 @@ def run_leg(checks: Checks, meter: CompileMeter, *, capacity: int,
             "egress_offload": d.egress_offload,
             "cost_analysis": d.cost_analysis,
             "batch_staging": packed.supports_batch_staging(),
-            "packed": inst.batcher.emit_packed,
         }
         report["cost"] = {
             k: inst.metrics.gauge(f"device.cost.{k}").value
@@ -634,7 +633,7 @@ def check_chip_side(checks: Checks, leg: dict) -> None:
     checks.equal(f"{tag}: ring_depth", sw["ring_depth"], 8)
     checks.equal(f"{tag}: inflight_depth", sw["inflight_depth"], 16)
     for name in ("ring_donate", "egress_offload", "cost_analysis",
-                 "batch_staging", "packed"):
+                 "batch_staging"):
         checks.equal(f"{tag}: {name}", sw[name], True)
     checks.check(f"{tag}: cost analysis of the compiled chain recorded",
                  leg["cost"]["flops"] > 0 and leg["cost"]["bytes_accessed"] > 0,

@@ -1,12 +1,12 @@
 """Hot-path allocation pass (HP): the per-batch allocation worklist.
 
-``HOSTPATH_r06.json`` attributes 4.0 ms/batch to dispatch bookkeeping —
-plan assembly, lease hand-off, metrics — and ROADMAP item 2's next move
-is "strip allocations off the per-batch path".  This pass turns that
-into a machine-generated worklist: functions marked ``@hot_path``
-(``sitewhere_tpu/analysis/markers.py``) are the per-batch critical
-path, and inside them (plus project-local callees one level down) every
-new-object allocation is a finding:
+Dispatch bookkeeping — plan assembly, lease hand-off, metrics — is
+per-batch host time the egress worker and the dispatch thread pay on
+every plan (PERF.md §5).  This pass turns "strip allocations off the
+per-batch path" into a machine-generated worklist: functions marked
+``@hot_path`` (``sitewhere_tpu/analysis/markers.py``) are the per-batch
+critical path, and inside them (plus project-local callees one level
+down) every new-object allocation is a finding:
 
 - ``HP001 container-alloc``: list/dict/set displays and
   comprehensions, ``list()``/``dict()``/``set()`` calls.

@@ -345,17 +345,14 @@ class Reservation:
 class BatchPlan:
     """A ready-to-dispatch batch plus its host-side bookkeeping.
 
-    ``host_cols`` keeps the numpy columns the device batch was built from
-    so egress never has to fetch the input batch back off the device —
-    only step *outputs* cross the host boundary after dispatch.
-
-    The device :class:`EventBatch` is materialized LAZILY: ``_emit``
-    runs under the dispatcher's intake lock, and building the unpacked
-    batch there meant 16 host→device transfers while every source
-    thread's intake was blocked (swlint lock-discipline LK004).  The
-    emitter now hands over only the numpy ``host_cols``; the first
-    ``plan.batch`` access — the dispatcher stages plans before taking
-    any lock — pays the transfers off-lock.
+    Every plan carries the packed wire form (``packed_i`` ``[12, B]``
+    int32 / ``packed_f`` ``[4, B]`` float32, pipeline/packed.py) — what
+    the dispatcher stages and steps, two transfers a plan — and
+    ``host_cols``, the numpy columns it was built from, so egress never
+    has to fetch the input batch back off the device: only step
+    *outputs* cross the host boundary after dispatch.  ``batch`` is the
+    same plan read as an :class:`EventBatch` (tests and reference
+    comparisons); nothing on the served path builds it.
     """
 
     __slots__ = ("_batch", "n_events", "width", "created_at", "max_wait_s",
@@ -364,16 +361,11 @@ class BatchPlan:
 
     def __init__(
         self,
-        batch: Optional[EventBatch] = None,
         n_events: int = 0,
         width: int = 1,
         created_at: float = 0.0,
         max_wait_s: float = 0.0,  # how long the oldest row waited
         host_cols: Optional[Dict[str, np.ndarray]] = None,
-        # Packed wire form ([12, B] int32 / [4, B] float32,
-        # pipeline/packed.py) when the batcher was built with
-        # ``emit_packed`` — then ``batch`` is None and the dispatcher
-        # feeds the packed step directly (2 transfers instead of 16).
         packed_i: Optional[np.ndarray] = None,
         packed_f: Optional[np.ndarray] = None,
         # Device-resident (bi, bf) pair staged ahead of the step by the
@@ -394,7 +386,7 @@ class BatchPlan:
         # flight-recorder stage attribution, stamped by the dispatcher.
         dispatch_s: float = 0.0,
     ):
-        self._batch = batch
+        self._batch = None
         self.n_events = n_events
         self.width = width
         self.created_at = created_at
@@ -407,20 +399,16 @@ class BatchPlan:
         self.reason = reason
         self.dispatch_s = dispatch_s
 
-    def materialize_batch(self) -> Optional[EventBatch]:
-        """Build (and cache) the device EventBatch from ``host_cols`` —
-        call OFF the intake/step locks; packed plans return None (they
-        ship ``packed_i``/``packed_f`` instead)."""
-        if self._batch is None and self.packed_i is None and self.host_cols:
+    @property
+    def batch(self) -> Optional[EventBatch]:
+        """The plan as an :class:`EventBatch` built (and cached) from
+        ``host_cols`` — device transfers, so never under a lock."""
+        if self._batch is None and self.host_cols:
             import jax.numpy as jnp
 
             self._batch = EventBatch(
                 **{k: jnp.asarray(v) for k, v in self.host_cols.items()})
         return self._batch
-
-    @property
-    def batch(self) -> Optional[EventBatch]:
-        return self.materialize_batch()
 
     @property
     def fill(self) -> float:
@@ -534,7 +522,6 @@ class Batcher:
                            # invocation-token correlation
         deadline_ms: float = 5.0,
         clock: Callable[[], float] = time.monotonic,
-        emit_packed: bool = False,
         metrics=None,
         controller: Optional[AdaptiveBatchController] = None,
     ):
@@ -558,7 +545,6 @@ class Batcher:
         # static value above is only the fallback after detach.
         self.controller = controller
         self.clock = clock
-        self.emit_packed = emit_packed
         self._pending: List[Deque[_Chunk]] = [
             collections.deque() for _ in range(n_shards)
         ]
@@ -970,7 +956,7 @@ class Batcher:
         host_cols = res.finalize_adopted(n)
         now, wait = self._emit_tail(n, reason)
         return BatchPlan(
-            batch=None, n_events=n, width=self.width, created_at=now,
+            n_events=n, width=self.width, created_at=now,
             max_wait_s=wait, host_cols=host_cols,
             packed_i=res.ibuf, packed_f=res.fbuf,
             seq=self.emitted_batches - 1, reason=reason,
@@ -984,16 +970,11 @@ class Batcher:
         allocates only for the mixed/deadline/flush leftovers whose rows
         genuinely have to be gathered out of multiple chunks.
 
-        Packed mode builds the host columns directly as rows of the
-        packed wire buffers — ``_emit``'s fill loop writes through the
-        ``out`` views, so emission costs no extra pass.  Bool columns
-        keep their own arrays (host_cols consumers expect bool dtype)
-        and land in their int rows at the end."""
-        if not self.emit_packed:
-            return None, None, {
-                name: np.full(self.width, fill, dtype=dt)
-                for name, dt, fill in _FIELDS
-            }
+        The host columns are built directly as rows of the packed wire
+        buffers — ``_emit``'s fill loop writes through the ``out``
+        views, so emission costs no extra pass.  Bool columns keep their
+        own arrays (host_cols consumers expect bool dtype) and land in
+        their int rows at the end."""
         from sitewhere_tpu.pipeline.packed import BATCH_F, BATCH_I
 
         ibuf = np.empty((len(BATCH_I), self.width), np.int32)
@@ -1013,16 +994,15 @@ class Batcher:
 
     @hot_path
     def _emit(self, reason: str = "fill") -> BatchPlan:
-        if self.emit_packed:
-            q = self._pending[0]
-            if self.n_shards == 1:
-                if len(q) == 1 and q[0].reserved is not None \
-                        and q[0].start == 0 \
-                        and q[0].reserved.cap == self.width:
-                    return self._emit_adopted(reason)
-            elif q and q[0].reserved is not None \
-                    and self._adoptable_sharded():
+        q = self._pending[0]
+        if self.n_shards == 1:
+            if len(q) == 1 and q[0].reserved is not None \
+                    and q[0].start == 0 \
+                    and q[0].reserved.cap == self.width:
                 return self._emit_adopted(reason)
+        elif q and q[0].reserved is not None \
+                and self._adoptable_sharded():
+            return self._emit_adopted(reason)
         ibuf, fbuf, out = self._assemble_buffers()
         n = 0
         for s in range(self.n_shards):
@@ -1050,22 +1030,13 @@ class Batcher:
         self._count_copied(n * _ROW_BYTES)
 
         now, wait = self._emit_tail(n, reason)
-        if self.emit_packed:
-            from sitewhere_tpu.pipeline.packed import BATCH_I
+        from sitewhere_tpu.pipeline.packed import BATCH_I
 
-            ibuf[BATCH_I.index("valid")] = out["valid"]
-            ibuf[BATCH_I.index("update_state")] = out["update_state"]
-            self._count_copied(2 * 4 * self.width)  # bool→int32 rows
-            return BatchPlan(
-                batch=None, n_events=n, width=self.width, created_at=now,
-                max_wait_s=wait, host_cols=out, packed_i=ibuf, packed_f=fbuf,
-                seq=self.emitted_batches - 1, reason=reason,
-            )
-        # No device work here: _emit runs under the dispatcher's intake
-        # lock, so the EventBatch H2D materializes lazily at first
-        # plan.batch access (the dispatcher stages plans off-lock).
+        ibuf[BATCH_I.index("valid")] = out["valid"]
+        ibuf[BATCH_I.index("update_state")] = out["update_state"]
+        self._count_copied(2 * 4 * self.width)  # bool→int32 rows
         return BatchPlan(
-            batch=None, n_events=n, width=self.width, created_at=now,
-            max_wait_s=wait, host_cols=out,
+            n_events=n, width=self.width, created_at=now,
+            max_wait_s=wait, host_cols=out, packed_i=ibuf, packed_f=fbuf,
             seq=self.emitted_batches - 1, reason=reason,
         )

@@ -531,12 +531,6 @@ class Instance(LifecycleComponent):
             resolve_alert=self.identity.alert_type.mint,
             invocations=self.identity.invocation,
             deadline_ms=float(self.config["pipeline.deadline_ms"]),
-            # Emit plans in the packed wire form so the dispatcher
-            # drives the ~11-buffer packed step — the default on EVERY
-            # backend and on the mesh (_packed_step_enabled: the
-            # dispatcher's many-output egress favors packed even on CPU;
-            # on a mesh, per-call placement scales with buffer count).
-            emit_packed=self._packed_step_enabled(),
             metrics=self.metrics,
             controller=controller,
         )
@@ -931,25 +925,6 @@ class Instance(LifecycleComponent):
     def _process_id(self) -> int:
         return int(self.config.get("rpc.process_id", 0))
 
-    def _packed_step_enabled(self) -> bool:
-        """Config ``pipeline.packed_step`` (true/false) pins the step
-        interface; the default is ON for the dispatcher on every
-        backend.  The PURE step is backend-adaptive (CPU pays the
-        repack; ``packed_step_default``), but the dispatcher's egress
-        fetches many output buffers per step, which the packed [10, B]
-        block collapses — measured on CPU: dispatcher path 253k → 327k
-        events/s, p99 15 → 13.5 ms; on TPU it also removes the ~30 ms
-        per-call dispatch tax."""
-        cfg = self.config.get("pipeline.packed_step", "auto")
-        if isinstance(cfg, bool):
-            return cfg
-        if str(cfg).lower() in ("true", "false"):
-            return str(cfg).lower() == "true"
-        from sitewhere_tpu.pipeline.packed import packed_env_override
-
-        env = packed_env_override()
-        return True if env is None else env
-
     def _overload_signals(self):
         """One sample of the pressure signals the overload controller
         watches — all of them gauges/counters the system already
@@ -1041,8 +1016,8 @@ class Instance(LifecycleComponent):
 
     def run_device_profile(self, iters: int = 16,
                            repeats: int = 3) -> dict:
-        """On-demand device-stage calibration (the ``profile_step.py``
-        fori-chain methodology at the size of the step ONE chip runs):
+        """On-demand device-stage calibration (the fori-chain probes of
+        ``pipeline/telemetry.py`` at the size of the step ONE chip runs):
         records ``device.stage_ms.*`` histogram samples and returns the
         stage medians.  Compiles one probe chain per stage — seconds of
         work; REST exposes it admin-only for exactly that reason.

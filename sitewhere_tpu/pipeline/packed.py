@@ -498,8 +498,7 @@ def packed_pipeline_step(
 
 def build_packed_chain(k: int, donate: bool = True) -> Callable:
     """K packed steps chained in ONE compiled program — the device-resident
-    dispatch loop's kernel (the production form of ``bench.py``'s phase-C
-    ``packed_chain``).
+    dispatch loop's kernel.
 
     The returned jitted callable takes ``(tables, ps, *slots)`` where
     ``slots`` is K staged ``bi`` arrays followed by K staged ``bf`` arrays
@@ -579,54 +578,12 @@ def ring_depth_default() -> int:
     dispatch is a function call — the chain only adds compile time and
     batching delay, so the ring defaults OFF (forcible via
     ``pipeline.ring_depth`` for the tier-1 smoke of the chained path).
-    ``SW_TPU_RING_DEPTH`` overrides the default on any backend (operator
-    tuning knob; an explicit ``pipeline.ring_depth`` config still wins).
+    An explicit ``pipeline.ring_depth`` config wins on any backend.
     """
-    import os
-
-    env = os.environ.get("SW_TPU_RING_DEPTH")
-    if env is not None:
-        try:
-            return max(0, int(env))
-        except ValueError:
-            logger.warning("ignoring non-integer SW_TPU_RING_DEPTH=%r", env)
     try:
         return 8 if jax.default_backend() == "tpu" else 0
     except Exception:  # no backend at all
         return 0
-
-
-def packed_env_override() -> Optional[bool]:
-    """``SW_TPU_PACKED_STEP`` as a tristate (None = unset) — the ONE
-    parser for every consumer, so the dispatcher default and the pure-
-    step choice can never disagree on what the variable means."""
-    import os
-
-    env = os.environ.get("SW_TPU_PACKED_STEP")
-    if env is None:
-        return None
-    return env.strip().lower() not in ("0", "false", "")
-
-
-def packed_step_default() -> bool:
-    """Interface choice for the PURE step (bench microbenchmarks).
-
-    Backend-adaptive: on TPU the per-call win (~100 fewer buffers per
-    step; dispatch cost scales with buffer count) decides, while on the
-    CPU backend a bare call gains nothing from it.
-
-    The DISPATCHER defaults packed on EVERY backend regardless
-    (``Instance._packed_step_enabled``): its egress fetches many output
-    buffers per step, which the packed [10, B] block collapses —
-    measured faster on CPU too.  ``SW_TPU_PACKED_STEP=0/1`` overrides
-    both.
-    """
-    env = packed_env_override()
-    if env is not None:
-        return env
-    import jax
-
-    return jax.default_backend() == "tpu"
 
 
 def packed_presence_sweep(ps: PackedState, now_s, missing_after_s):

@@ -58,11 +58,13 @@ def build_sharded_step(mesh: Mesh, donate: bool = True):
 
     Returns ``step(registry, state, rules, zones, batch) -> (state, outputs)``
     operating on globally-sharded arrays (place inputs with
-    :func:`place_inputs` or equivalent ``device_put``).
+    :func:`place_inputs` or equivalent ``device_put``).  Not served: the
+    dispatcher runs :func:`build_sharded_packed_step`.  This unpacked form,
+    with :func:`place_inputs` and :func:`place_batch`, is the reference
+    the sharded packed programs are compared against in tests.
 
-    ``donate=False`` keeps the input state buffers alive — required by the
-    dispatcher, whose :class:`DeviceStateManager` still hands the previous
-    epoch to concurrent readers and the sweep-merge in ``commit``.
+    ``donate=False`` keeps the input state buffers alive for a caller
+    that reads the previous epoch after the step.
     """
     reg_t = Registry.empty(8)
     state_t = DeviceState.empty(8)
@@ -271,7 +273,8 @@ def place_inputs(
     rules: RuleTable,
     zones: ZoneTable,
 ) -> Tuple[Registry, DeviceState, RuleTable, ZoneTable]:
-    """Device-put the resident tables with their canonical shardings."""
+    """Device-put the resident tables with their canonical shardings
+    (for :func:`build_sharded_step`, the tests' reference; not served)."""
 
     def put(tree, specs):
         return jax.tree_util.tree_map(
@@ -287,7 +290,8 @@ def place_inputs(
 
 
 def place_batch(mesh: Mesh, batch: EventBatch) -> EventBatch:
-    """Device-put an event batch sharded along its width."""
+    """Device-put an event batch sharded along its width (for
+    :func:`build_sharded_step`, the tests' reference; not served)."""
     return jax.tree_util.tree_map(
         lambda x: jax.device_put(x, NamedSharding(mesh, P(SHARD_AXIS))), batch
     )

@@ -652,7 +652,10 @@ def pipeline_step(
     """The fused inbound step: validate → enrich → rules → state → outputs.
 
     Pure function of its inputs — jit/pjit it once and feed batches forever.
-    :func:`fused_step` over the unpacked carry.
+    :func:`fused_step` over the unpacked carry.  Not served: the
+    dispatcher steps packed plans only (pipeline/packed.py).  This is the
+    reference the packed programs are compared against in tests and the
+    carrier of the calibration probe (pipeline/telemetry.py).
     """
     return fused_step(RegistryColumns(registry), StateColumns(state),
                       rules, zones, batch)

@@ -4,10 +4,9 @@ Two complementary sources of on-device evidence feed the continuous-
 profiling surface (the third — per-step occupancy counters — rides the
 packed metrics vector itself, ``pipeline/packed.py TELEMETRY_SCALARS``):
 
-1. **Stage-time probes** (:func:`profile_device_stages`): the
-   ``tools/profile_step.py`` fori-chain methodology as a library —
-   every probe is a ``lax.fori_loop`` chain inside ONE jit call so
-   per-call dispatch amortizes away, inputs are perturbed by the loop
+1. **Stage-time probes** (:func:`profile_device_stages`): every probe
+   is a ``lax.fori_loop`` chain inside ONE jit call so per-call
+   dispatch amortizes away, inputs are perturbed by the loop
    index so XLA cannot hoist the work, the chain's result is FETCHED
    (the fetch cannot return before the work is done), and the measured
    trivial-program RTT is subtracted.  Samples land in
@@ -84,8 +83,7 @@ def profile_device_stages(width: int = 16_384, capacity: int = 16_384,
                           iters: int = 16, repeats: int = 3,
                           metrics=None) -> Dict[str, object]:
     """Measure per-stage DEVICE time for the fused pipeline step at the
-    given width (the ``profile_step.py`` methodology, callable from the
-    instance / REST / bench instead of a standalone script).
+    given width (callable from the instance and REST).
 
     Returns ``{"<stage>_ms": median_ms, ..., "host_rtt_ms": ...,
     "width": ..., "iters": ...}``.  When ``metrics`` (a

@@ -63,13 +63,12 @@ A plan's life on the host is a chain of timers, each also a profiler
 span of the same name (``runtime/metrics.py Timer.time``; spans of one
 plan share ``seq``): ``pipeline.stage_decode_s`` and
 ``ingest.journal_append_s`` per payload; per plan ``stage_batch_s`` →
-``stage_h2d_s`` (unpacked plans) or ``stage_place_s`` (packed plans on
-a mesh: the per-shard placement) → ``stage_dispatch_wait_s`` (full
-egress window + step-lock wait) → ``stage_dispatch_s`` →
-``stage_inflight_wait_s`` (queued for egress) → ``stage_egress_s``, of
-which ``pipeline.device_wait_s`` is the part blocked on the device and
-D2H; ring plans have ``stage_ring_wait_s`` / ``stage_ring_dispatch_s``
-in place of the dispatch pair.  With the batcher wait
+``stage_place_s`` (on a mesh: the plan's per-shard placement) →
+``stage_dispatch_wait_s`` (full egress window + step-lock wait) →
+``stage_dispatch_s`` → ``stage_inflight_wait_s`` (queued for egress)
+→ ``stage_egress_s``, of which ``pipeline.device_wait_s`` is the part
+blocked on the device and D2H; ring plans have ``stage_ring_wait_s`` /
+``stage_ring_dispatch_s`` in place of the dispatch pair.  With the batcher wait
 (``plan.max_wait_s``) they add up to the plan's latency; when the
 stage totals exceed wall elapsed, the stages are provably overlapping.
 """
@@ -92,7 +91,6 @@ from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.ingest.batcher import Batcher, BatchPlan
 from sitewhere_tpu.ingest.decoders import DecodedRequest
 from sitewhere_tpu.ingest.journal import Journal, JournalReader
-from sitewhere_tpu.pipeline.step import pipeline_step
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.runtime.resilience import dead_letter
@@ -123,12 +121,11 @@ class EgressColumns(collections.abc.Mapping):
     """Zero-copy egress column view over one plan's host columns plus
     the step's enrichment outputs.
 
-    Replaces the per-batch dict build in ``_columns`` (the tagged
-    ROADMAP-2 worklist entry: ~4.0 ms of dispatch bookkeeping in
-    ``HOSTPATH_r06``, dominated by the 5 EAGER ``np.asarray`` enrichment
-    fetches).  Host columns resolve straight out of ``plan.host_cols``;
-    enrichment columns (``device_type_id`` … ``asset_id``) fetch from
-    the step output LAZILY on first access and memoize, so an egress
+    Replaces the per-batch dict build in ``_columns`` and its 5 EAGER
+    ``np.asarray`` enrichment fetches.  Host columns resolve straight
+    out of ``plan.host_cols``; enrichment columns (``device_type_id`` …
+    ``asset_id``) fetch from the step output LAZILY on first access and
+    memoize, so an egress
     where no consumer touches them — store disabled, outbound-only
     fan-out — never pays the device sync at all, and the common path
     pays it exactly once per column (the segment store's
@@ -297,23 +294,18 @@ class PipelineDispatcher(LifecycleComponent):
             # Multi-chip: shard_map step over the mesh (Kafka-partitioning
             # analog, SURVEY.md §2.4) — the batcher already routes each row
             # to the sub-batch of the shard owning its registry block.
-            # When the batcher emits packed plans, the packed mesh form
-            # runs instead (per-call placement cost on a mesh scales with
-            # buffer count × hosts; see build_sharded_packed_step).
+            # The packed mesh form: per-call placement cost on a mesh
+            # scales with buffer count × hosts (build_sharded_packed_step).
             from sitewhere_tpu.pipeline.sharded import (
                 build_sharded_packed_step,
-                build_sharded_step,
             )
 
-            self._step = build_sharded_step(mesh, donate=False)
             self._packed_step = build_sharded_packed_step(mesh)
         else:
-            self._step = jax.jit(pipeline_step)
-            # Single-chip fast path: the packed step moves ~11 buffers per
-            # call instead of ~110 — per-call dispatch scales with buffer
-            # count (pipeline/packed.py).  Used whenever the batcher
-            # emits packed plans.  NO donation: the carry passed in is
-            # the state manager's LIVE epoch — donating it
+            # Single chip: the packed step moves ~11 buffers per call
+            # instead of ~110 — per-call dispatch scales with buffer
+            # count (pipeline/packed.py).  NO donation: the carry passed
+            # in is the state manager's LIVE epoch — donating it
             # would leave concurrent readers (checkpointer, presence
             # sweep, REST queries) holding deleted buffers until
             # commit_packed lands.  Donation is for private carries
@@ -327,10 +319,6 @@ class PipelineDispatcher(LifecycleComponent):
 
         self._pack_tables = jax.jit(pack_tables)
         self._tables_cache: Optional[tuple] = None
-        # Identity-keyed cache of mesh-placed epochs: providers return the
-        # same object while clean, so steady-state steps reuse the resident
-        # sharded arrays instead of re-placing every step.
-        self._placed_epochs: Dict[str, tuple] = {}
         # Commit-after-egress stream position (Kafka manual-commit analog,
         # MicroserviceKafkaConsumer.java:94): the highest journal offset
         # whose row has completed egress.  Committed only at quiescent
@@ -435,13 +423,12 @@ class PipelineDispatcher(LifecycleComponent):
         # egress_offload=False) every path degrades to the inline
         # synchronous egress, the pre-offload behavior.
         #
-        # Default is backend-adaptive (same spirit as inflight_depth and
-        # packed_step_default): ON off-CPU, where egress blocks on
-        # device→host fetches with the GIL released and the overlap is
-        # real; OFF on the CPU backend, where the GIL serializes the
-        # stages anyway and the offload's backpressure stalls read as
-        # idle to the adaptive batcher (measured: 151k→102k ev/s on the
-        # CPU wire bench with both on, 189k with inline egress).
+        # Default is backend-adaptive (same spirit as inflight_depth):
+        # ON off-CPU, where egress blocks on device→host fetches with
+        # the GIL released and the overlap is real; OFF on the CPU
+        # backend, where the GIL serializes the stages anyway and the
+        # offload's backpressure stalls read as idle to the adaptive
+        # batcher.
         if egress_offload is None:
             egress_offload = jax.default_backend() != "cpu"
         self.egress_offload = bool(egress_offload)
@@ -488,10 +475,6 @@ class PipelineDispatcher(LifecycleComponent):
                       # ring stages: per-slot wait before its chain
                       # launches, and the chain's host dispatch cost
                       "ring_wait", "ring_dispatch",
-                      # unpacked plans' lazy EventBatch H2D (moved off
-                      # the intake lock out of _emit — its own stage so
-                      # the batch timer's per-plan sample count stays 1)
-                      "h2d",
                       # the dispatch path blocked: a full egress window
                       # (_stall_for_egress_room) plus the wait for
                       # _step_lock, ending when the lock is held
@@ -505,16 +488,15 @@ class PipelineDispatcher(LifecycleComponent):
             # arrays x n_shards device_puts); never observed on one chip
             self._m_stage["place"] = metrics.timer("pipeline.stage_place_s")
         # The host BLOCKED on the device finishing a step and on its D2H
-        # (the views' blocking fetch in pipeline/packed.py, the unpacked
-        # fallback's metrics fetch in _egress): a child of the egress
-        # stage, so egress self time = stage_egress_s - device_wait_s.
+        # (the views' blocking fetch in pipeline/packed.py): a child of
+        # the egress stage, so egress self time = stage_egress_s -
+        # device_wait_s.
         # One observation per host sync (pipeline.host_syncs).
         self._m_device_wait = metrics.timer("pipeline.device_wait_s")
         # "How often does the host touch the device" as a first-class
         # metric: one inc per BLOCKING device→host sync on the dispatch/
         # egress path (the packed views' lazy fetch, the ring's shared
-        # fetch, the unpacked fallback's egress fetch).  The ring's whole
-        # point is host_syncs/steps → 1/K.
+        # fetch).  The ring's whole point is host_syncs/steps → 1/K.
         self._m_host_syncs = metrics.counter("pipeline.host_syncs")
         # Zero-copy ingest evidence: bytes memcpy'd per host stage.  The
         # fill-direct wire path contributes ZERO to decode (the C scan
@@ -744,25 +726,14 @@ class PipelineDispatcher(LifecycleComponent):
         return stage_packed_batch(bi, bf)
 
     def _stage_plan(self, plan: BatchPlan) -> None:
-        """Start the async H2D copy of a packed plan (double-buffer front
-        half, :meth:`_stage_packed`)."""
-        if plan.staged is None and plan.packed_i is not None:
+        """Start the async H2D copy of a plan (double-buffer front half,
+        :meth:`_stage_packed`)."""
+        if plan.staged is None:
             plan.staged = self._stage_packed(plan.packed_i, plan.packed_f,
                                              seq=plan.seq)
             if plan.staged is not None:
                 self._m_bytes["h2d"].inc(
                     plan.packed_i.nbytes + plan.packed_f.nbytes)
-        elif plan.packed_i is None and plan._batch is None \
-                and plan.host_cols:
-            # Unpacked plans: materialize the EventBatch HERE, off the
-            # intake and step locks — _emit no longer pays the 16 H2D
-            # transfers under the intake lock (swlint LK004 fix).
-            # Timed as its OWN stage (pipeline.stage_h2d_s): folding it
-            # into the batch timer would double that timer's per-plan
-            # sample count and halve the per-batch attribution the
-            # bench derives from totals/counts.
-            with self._m_stage["h2d"].time(seq=plan.seq):
-                plan.materialize_batch()
 
     def _shed_intake(self, payload: bytes, shed: Dict[object, int],
                      source_id: str, tenant: str,
@@ -1522,33 +1493,6 @@ class PipelineDispatcher(LifecycleComponent):
 
     # -- one step -----------------------------------------------------------
 
-    def _mesh_put(self, x, spec):
-        """One leaf's mesh placement — a bound method, not a per-call
-        closure, so the unpacked re-take path allocates no lambda per
-        step (swlint HP004)."""
-        from jax.sharding import NamedSharding
-
-        return jax.device_put(x, NamedSharding(self.mesh, spec))
-
-    def _placed(self, kind: str, obj, replicated: bool = False):
-        """Place a provider epoch on the mesh, cached by object identity."""
-        cached = self._placed_epochs.get(kind)
-        if cached is not None and cached[0] is obj:
-            return cached[1]
-        from sitewhere_tpu.pipeline.sharded import (
-            _specs_replicated,
-            _specs_sharded,
-        )
-        from jax.sharding import NamedSharding
-
-        specs = _specs_replicated(obj) if replicated else _specs_sharded(obj)
-        placed = jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            obj, specs,
-        )
-        self._placed_epochs[kind] = (obj, placed)
-        return placed
-
     def _tables_packed(self):
         """PackedTables for the current provider epochs, identity-cached
         (re-packs only when a registry/rule/zone epoch actually changed).
@@ -1572,8 +1516,8 @@ class PipelineDispatcher(LifecycleComponent):
     def _run_plan(self, plan: BatchPlan, replay_depth: int = 0) -> None:
         """Route one emitted plan: full-width fill plans join the
         device-resident dispatch ring (chained K at a time); everything
-        else — deadline/flush partials, re-injected plans, unpacked or
-        mesh plans — takes the single-step path, draining any ring-held
+        else — deadline/flush partials, re-injected plans, mesh
+        plans — takes the single-step path, draining any ring-held
         predecessors first so per-device event order is preserved."""
         if self._ring_eligible(plan, replay_depth):
             self._stage_plan(plan)
@@ -1601,7 +1545,7 @@ class PipelineDispatcher(LifecycleComponent):
 
     def _ring_eligible(self, plan: BatchPlan, replay_depth: int) -> bool:
         """May this plan wait in the ring for a chained dispatch?  Only
-        depth-0 full-width fill emissions on the packed path:
+        depth-0 full-width fill emissions:
         deadline/flush partials are latency-sensitive and re-injected
         plans (derived alerts, replay) must not recurse through the
         ring.  Mesh plans chain through the sharded packed chain — the
@@ -1611,7 +1555,6 @@ class PipelineDispatcher(LifecycleComponent):
         those are latency-carrying partials too."""
         return (self.ring_depth > 0
                 and replay_depth == 0
-                and plan.packed_i is not None
                 and plan.reason == "fill"
                 and plan.n_events == plan.width
                 # breaker demoted past CHAINED: bisectable single-step
@@ -1999,8 +1942,6 @@ class PipelineDispatcher(LifecycleComponent):
             return None
         shards: set = set()
         for plan in plans:
-            if plan.packed_i is None:
-                continue
             bf = np.asarray(plan.packed_f)
             valid = np.asarray(plan.packed_i[0]) != 0
             bad = valid & ~np.isfinite(bf).all(axis=0)
@@ -2045,8 +1986,6 @@ class PipelineDispatcher(LifecycleComponent):
             fallback = False
         seg = self._shard_seg
         for plan in plans:
-            if plan.packed_i is None:
-                continue
             valid = np.asarray(plan.packed_i[0]) != 0
             take = np.zeros(valid.shape[0], dtype=bool)
             for s in demoted:
@@ -2207,14 +2146,11 @@ class PipelineDispatcher(LifecycleComponent):
             wait.__exit__(None, None, None)
             failure = None
             with self._m_stage["dispatch"].time(seq=plan.seq) as span:
-                if plan.packed_i is not None:
-                    try:
-                        out = self._step_packed(plan, trace)
-                    except _StepFailed as e:
-                        failure = e.__cause__
-                        span.discard()   # the timer counts dispatched steps
-                else:
-                    out = self._step_unpacked(plan, trace)
+                try:
+                    out = self._step_packed(plan, trace)
+                except _StepFailed as e:
+                    failure = e.__cause__
+                    span.discard()   # the timer counts dispatched steps
             if failure is not None:
                 self._wd_end(plan)
                 self._contain_step_failure(plan, failure, replay_depth,
@@ -2289,39 +2225,6 @@ class PipelineDispatcher(LifecycleComponent):
         return PackedView(oi, metrics, present,
                           on_fetch=self._m_host_syncs.inc,
                           wait_timer=self._m_device_wait, seq=seq)
-
-    @hot_path
-    def _step_unpacked(self, plan: BatchPlan, trace):
-        """The unpacked fallback's single step (under ``_step_lock``,
-        inside the dispatch stage); returns its ``PipelineOutputs``."""
-        batch = plan.batch
-        state = self.state_manager.current
-        if self.mesh is not None:
-            from sitewhere_tpu.pipeline.sharded import place_batch
-
-            registry = self._placed("registry", self.registry_provider())
-            rules = self._placed("rules", self.rules_provider(),
-                                 replicated=True)
-            zones = self._placed("zones", self.zones_provider(),
-                                 replicated=True)
-            # State changes identity every commit, so caching would
-            # never hit; device_put is a no-op once the epoch already
-            # carries the mesh sharding (i.e. after the first step).
-            from sitewhere_tpu.pipeline.sharded import _specs_sharded
-
-            state = jax.tree_util.tree_map(
-                self._mesh_put, state, _specs_sharded(state))
-            batch = place_batch(self.mesh, batch)
-        else:
-            registry = self.registry_provider()
-            rules = self.rules_provider()
-            zones = self.zones_provider()
-        with trace.span("step.dispatch").tag("rows", plan.n_events):
-            new_state, out = self._step(registry, state, rules, zones,
-                                        batch)
-            self.state_manager.commit(new_state,
-                                      present_now=out.present_now)
-        return out
 
     def _contain_step_failure(self, plan: BatchPlan, exc,
                               replay_depth: int, trace) -> None:
@@ -2576,8 +2479,7 @@ class PipelineDispatcher(LifecycleComponent):
                         error=f"{type(e).__name__}: {e}")
                     self.flightrec.anomaly("egress-crash", detail=str(e))
                 plan = item[0]
-                if (self._copy_suspect and plan.packed_i is not None
-                        and item[2] == 0):
+                if self._copy_suspect and item[2] == 0:
                     # the async D2H copy for this window faulted
                     # (_on_host_copy_error flagged it); the egress fetch
                     # hit the dead buffer.  Re-dispatch the plan
@@ -2635,21 +2537,11 @@ class PipelineDispatcher(LifecycleComponent):
         fan out, re-inject.  Returns the plan's end-to-end latency."""
         host_cols = plan.host_cols
         with trace.span("egress.fetch-outputs"):
-            if hasattr(out, "_fetch"):
-                # packed/ring views count and time their own lazy fetch
-                # (on_fetch / wait_timer), which this access triggers
-                m = as_numpy(out.metrics)
-            else:
-                # unpacked fallback: this as_numpy IS the blocking
-                # device→host sync
-                self._m_host_syncs.inc()
-                with self._m_device_wait.time(seq=plan.seq):
-                    m = as_numpy(out.metrics)
-            # packed/ring views hand back the host mask memoized on the
-            # shared fetch; only the unpacked fallback still pays a
-            # device→host conversion here
-            accepted = (out.accepted if hasattr(out, "_fetch")
-                        else as_numpy(out.accepted))
+            # the view counts and times its own lazy fetch (on_fetch /
+            # wait_timer), which this access triggers; the accepted mask
+            # is memoized on that same fetch
+            m = as_numpy(out.metrics)
+            accepted = out.accepted
             cols = self._columns(host_cols, out)
         for key in ("processed", "accepted", "unregistered", "unassigned",
                     "threshold_alerts", "zone_alerts"):
@@ -2657,10 +2549,9 @@ class PipelineDispatcher(LifecycleComponent):
             self.totals[key] += count
             if count:
                 self._m_totals[key].inc(count)
-        # On-device occupancy telemetry: the packed views expose the
+        # On-device occupancy telemetry: the views expose the
         # TELEMETRY_SCALARS block from the SAME fetched metrics vector
-        # (zero additional syncs); the unpacked fallback still surfaces
-        # the counts derivable from the step metrics alone.
+        # (zero additional syncs).
         self._m_occ["rows_admitted"].set(int(m.processed))
         self._m_occ["rules_fired"].set(
             int(m.threshold_alerts) + int(m.zone_alerts))
@@ -2669,7 +2560,7 @@ class PipelineDispatcher(LifecycleComponent):
         # plan's real row count is host knowledge, so subtract here
         self._m_occ["rows_invalid"].set(
             max(0, int(plan.n_events) - int(m.processed)))
-        telemetry = getattr(out, "telemetry", None)
+        telemetry = out.telemetry
         if telemetry:
             for key in ("state_writes", "presence_merges"):
                 if key in telemetry:
@@ -2815,7 +2706,7 @@ class PipelineDispatcher(LifecycleComponent):
         apportioned) — no per-row host work on the common path.  The
         decode stage's running-total delta rides along so decode time
         is row-share-attributed to the same tenants."""
-        block = getattr(out, "tenant_meter", None)
+        block = out.tenant_meter
         tenants = host_cols.get("tenant_id") if host_cols else None
         if block is None or tenants is None:
             return
@@ -2988,26 +2879,17 @@ class PipelineDispatcher(LifecycleComponent):
                           replay_depth: int) -> None:
         if replay_depth >= self.max_replay_depth:
             return
-        if hasattr(out, "derived_cols"):
-            # Packed path: reconstruct the (rare) derived rows from host
-            # columns + the packed output block — no same-width EventBatch
-            # round-trip off the device.
-            rows = np.nonzero(out.derived_valid)[0]
-            if rows.size == 0:
-                return
-            self.totals["derived_alerts"] += int(rows.size)
-            cols = out.derived_cols(plan.host_cols, rows)
-            self._run_plans(self._take(
-                lambda: self.batcher.add_arrays(_copy=False, **cols)),
-                replay_depth + 1)
+        # Reconstruct the (rare) derived rows from host columns + the
+        # packed output block — no same-width EventBatch round-trip off
+        # the device.
+        rows = np.nonzero(out.derived_valid)[0]
+        if rows.size == 0:
             return
-        derived = as_numpy(out.derived_alerts)
-        mask = np.asarray(derived.valid)
-        count = int(mask.sum())
-        if count == 0:
-            return
-        self.totals["derived_alerts"] += count
-        self.inject_batch(derived, mask, replay_depth + 1)
+        self.totals["derived_alerts"] += int(rows.size)
+        cols = out.derived_cols(plan.host_cols, rows)
+        self._run_plans(self._take(
+            lambda: self.batcher.add_arrays(_copy=False, **cols)),
+            replay_depth + 1)
 
     def inject_batch(self, batch: EventBatch, mask: np.ndarray,
                      replay_depth: int = 0) -> None:

@@ -2,9 +2,8 @@
 
 The legacy store sealed on ONE flusher thread (plus the caller's, at
 commit gates): at sustained ingest the npz build + write of every
-shard funnels through a single writer — `HOSTPATH_r06.json` measured
-it as the slowest host stage by far (19.6 ms/batch vs 4.0 ms
-dispatch).  The pool replaces that funnel with N supervised workers
+shard funnels through a single writer, the slowest host stage of the
+legacy store.  The pool replaces that funnel with N supervised workers
 draining a seal queue, so the hot path's whole seal cost is a packed
 row copy + an O(1) enqueue, and seal wall time parallelizes across
 tenant/device shards.

@@ -398,3 +398,42 @@ def test_add_arrays_single_shard_copies_caller_arrays():
     got_val = plan.host_cols["value"][:3].tolist()
     assert got_dev == [0, 1, 2]
     assert got_val == [1.0, 2.0, 3.0]
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("reason", ["fill", "deadline", "flush"])
+def test_plan_views_agree(reason, n_shards):
+    """A plan has one form: whatever made the batcher emit it, it
+    carries the packed buffers the dispatcher stages, and they unpack to
+    ``plan.batch`` (the plan read from ``host_cols``) column by column."""
+    import dataclasses
+
+    from sitewhere_tpu.pipeline.packed import unpack_batch
+
+    clock = FakeClock()
+    b = Batcher(width=WIDTH, n_shards=n_shards, registry_capacity=CAP,
+                resolve_device=lambda t: NULL_ID, resolve_mtype=lambda n: 0,
+                resolve_alert=lambda n: 0, deadline_ms=5.0, clock=clock)
+    # "fill" fills every shard's segment at once; the others leave a
+    # partial in which no segment is full
+    n, stride = (WIDTH, CAP // WIDTH) if reason == "fill" else (5, 13)
+    ids = np.arange(n, dtype=np.int32) * stride
+    plans = b.add_arrays(
+        device_id=ids, ts_s=np.arange(n, dtype=np.int32) + 1000,
+        value=np.linspace(0.0, 1.0, n).astype(np.float32),
+        lat=np.full(n, 3.5, np.float32),
+        update_state=(np.arange(n) % 2 == 0))
+    if reason == "deadline":
+        clock.t = 1.0
+        plans = [b.poll()]
+    elif reason == "flush":
+        plans = [b.flush()]
+    (plan,) = plans
+    assert plan.reason == reason and plan.n_events == n
+    assert plan.packed_i is not None and plan.packed_f is not None
+    got, want = unpack_batch(plan.packed_i, plan.packed_f), plan.batch
+    for f in dataclasses.fields(want):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, f.name)),
+            np.asarray(getattr(want, f.name)), err_msg=f.name)
+    assert int(np.asarray(want.valid).sum()) == n

@@ -1270,7 +1270,7 @@ class TestCrashRecBench:
     def test_randomized_sweep(self, tmp_path):
         """Slow gate: a small randomized sweep across the full kill-point
         catalog (the ≥50-point acceptance sweep is the tool's own
-        ``--sweep 50``; CRASHREC_r01.json records one)."""
+        ``--sweep 50``)."""
         res = self._run("--sweep", "6", "--seed", "1234", "--json",
                         str(tmp_path / "crashrec.json"))
         assert res.returncode == 0, res.stdout + res.stderr

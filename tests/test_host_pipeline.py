@@ -20,7 +20,7 @@ import pytest
 from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.ingest.batcher import Batcher
 from sitewhere_tpu.ingest.sources import DecodePool, InboundEventSource
-from sitewhere_tpu.pipeline.step import StepMetrics
+from sitewhere_tpu.pipeline.packed import METRIC_SCALARS
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.dispatcher import PipelineDispatcher
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
@@ -174,24 +174,14 @@ class TestDecodePool:
 # dispatcher fixture with a stubbed (slow) step
 # ---------------------------------------------------------------------------
 
-class FakeOut:
-    """Duck-types the slice of PipelineOutputs the egress path consumes."""
-
-    def __init__(self, n):
-        z = np.zeros(n, np.int32)
-        self.accepted = np.ones(n, bool)
-        self.unregistered = np.zeros(n, bool)
-        self.present_now = None
-        self.device_type_id = z
-        self.assignment_id = z
-        self.area_id = z
-        self.customer_id = z
-        self.asset_id = z
-        self.metrics = StepMetrics(
-            processed=np.int32(n), accepted=np.int32(n),
-            unregistered=np.int32(0), unassigned=np.int32(0),
-            threshold_alerts=np.int32(0), zone_alerts=np.int32(0),
-            by_type=np.zeros(6, np.int32))
+def _fake_step_out(bi):
+    """A packed step's ``(oi, metrics)`` accepting every valid row."""
+    valid = (np.asarray(bi)[0] != 0).astype(np.int32)
+    oi = np.zeros((10, WIDTH), np.int32)
+    oi[0] = valid  # flags row: F_ACCEPTED for every valid row
+    mets = np.zeros(len(METRIC_SCALARS) + 6, np.int32)
+    mets[0] = mets[1] = int(valid.sum())  # processed / accepted
+    return oi, mets
 
 
 class FakeStateManager:
@@ -254,12 +244,14 @@ def make_dispatcher(step_s=0.0, egress_s=0.0, egress_offload=True,
         **kw,
     )
 
-    def slow_step(registry, state, rules, zones, batch):
+    def slow_step(tables, ps, bi, bf):
         if step_s:
             time.sleep(step_s)  # the stubbed "device step"
-        return state, FakeOut(WIDTH)
+        oi, mets = _fake_step_out(bi)
+        return ps, oi, mets, np.zeros(64, bool)
 
-    disp._step = slow_step
+    disp._tables_packed = lambda: None
+    disp._packed_step = slow_step
     return disp, store, metrics
 
 
@@ -270,16 +262,14 @@ def ingest_window(disp):
 def make_ring_dispatcher(ring_depth=2, egress_s=0.0, egress_offload=True,
                          **kw):
     """Dispatcher on the device-resident ring path with a STUBBED chain:
-    packed plans from an emit_packed batcher, a fake K-step chain whose
+    plans from a real batcher, a fake K-step chain whose
     stacked outputs accept every row, and no real jax dispatch — the
     ring's windowing/commit/ordering semantics in isolation."""
-    from sitewhere_tpu.pipeline.packed import METRIC_SCALARS
-
     metrics = MetricsRegistry()
     batcher = Batcher(
         width=WIDTH, n_shards=1, registry_capacity=64,
         resolve_device=lambda t: NULL_ID, resolve_mtype=lambda n: 0,
-        resolve_alert=lambda n: 0, deadline_ms=60_000.0, emit_packed=True)
+        resolve_alert=lambda n: 0, deadline_ms=60_000.0)
     store = SlowStore(egress_s)
     disp = PipelineDispatcher(
         batcher=batcher,
@@ -296,23 +286,15 @@ def make_ring_dispatcher(ring_depth=2, egress_s=0.0, egress_offload=True,
     disp._tables_packed = lambda: None
     chain_calls = []
 
-    def _step_out(bi):
-        valid = (np.asarray(bi)[0] != 0).astype(np.int32)
-        oi = np.zeros((10, WIDTH), np.int32)
-        oi[0] = valid  # flags row: F_ACCEPTED for every valid row
-        mets = np.zeros(len(METRIC_SCALARS) + 6, np.int32)
-        mets[0] = mets[1] = int(valid.sum())  # processed / accepted
-        return oi, mets
-
     def fake_chain(tables, ps, *slots):
         k = len(slots) // 2
         chain_calls.append(k)
-        outs = [_step_out(slots[i]) for i in range(k)]
+        outs = [_fake_step_out(slots[i]) for i in range(k)]
         return (ps, np.stack([o for o, _ in outs]),
                 np.stack([m for _, m in outs]), np.zeros(64, bool))
 
     def fake_packed_step(tables, ps, bi, bf):
-        oi, mets = _step_out(bi)
+        oi, mets = _fake_step_out(bi)
         return ps, oi, mets, np.zeros(64, bool)
 
     for k in range(1, ring_depth + 1):

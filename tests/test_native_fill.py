@@ -51,8 +51,7 @@ def _batcher(dev, mt, al, width=WIDTH, n_shards=1, deadline_ms=1e9):
     return Batcher(width=width, n_shards=n_shards,
                    registry_capacity=CAPACITY,
                    resolve_device=dev.lookup, resolve_mtype=mt.mint,
-                   resolve_alert=al.mint, deadline_ms=deadline_ms,
-                   emit_packed=True)
+                   resolve_alert=al.mint, deadline_ms=deadline_ms)
 
 
 def _fill(payload, dev, batcher, mt):

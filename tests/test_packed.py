@@ -291,12 +291,9 @@ def test_packed_chain_donation():
 class TestPackedDispatcher:
     """The Instance dispatcher driving the packed step end-to-end.
 
-    Packed is the dispatcher default on every backend; these PIN it on
-    via ``pipeline.packed_step`` (immune to env overrides) and run the
-    key dispatcher flows: persistence+state, derived-alert re-injection
-    (PackedView's host-side reconstruction), and auto-registration
-    replay.  ``test_per_column_dispatcher_still_works`` covers the
-    pinned-off branch.
+    The key dispatcher flows: persistence+state, derived-alert
+    re-injection (PackedView's host-side reconstruction), and
+    auto-registration replay.
     """
 
     @pytest.fixture()
@@ -309,12 +306,11 @@ class TestPackedDispatcher:
                          "data_dir": str(tmp_path / "data")},
             "pipeline": {"width": 64, "registry_capacity": 1024,
                          "mtype_slots": 4, "deadline_ms": 5.0,
-                         "n_shards": 1, "packed_step": True},
+                         "n_shards": 1},
             "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
         }, apply_env=False)
         inst = Instance(cfg)
         inst.start()
-        assert inst.batcher.emit_packed
         yield inst
         inst.stop()
         inst.terminate()
@@ -414,34 +410,70 @@ class TestPackedDispatcher:
             "dev-2")["presence_missing"]
 
 
-def test_per_column_dispatcher_still_works(tmp_path):
-    """pipeline.packed_step=False pins the per-column interface (the
-    sharded path's form) — kept covered now that packed is the single-
-    chip default."""
-    from sitewhere_tpu.ingest.decoders import DecodedRequest, RequestKind
+@pytest.mark.parametrize("n_shards", [1, 4], ids=["one-chip", "mesh4"])
+def test_dispatcher_serves_packed_plans_only(tmp_path, n_shards):
+    """A plan has one form and the dispatcher one served step: a default
+    ``Instance`` emits packed plans only, every dispatched plan enters
+    ``_step_packed``, the stage timers are the packed path's and no
+    other, every counted host sync is the view's own timed fetch, and
+    every row sent is accepted."""
     from sitewhere_tpu.instance import Instance
     from sitewhere_tpu.runtime.config import Config
 
+    width, n_dev, rounds = 64, 32, 3
     cfg = Config({
-        "instance": {"id": "percol-test",
+        "instance": {"id": "packed-only-test",
                      "data_dir": str(tmp_path / "data")},
-        "pipeline": {"width": 64, "registry_capacity": 1024,
+        "pipeline": {"width": width, "registry_capacity": 1024,
                      "mtype_slots": 4, "deadline_ms": 5.0,
-                     "n_shards": 1, "packed_step": False},
+                     "n_shards": n_shards},
         "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
     }, apply_env=False)
     inst = Instance(cfg)
     inst.start()
     try:
-        assert not inst.batcher.emit_packed
-        inst.device_management.create_device_type(token="sensor", name="S")
-        inst.device_management.create_device(token="d", device_type="sensor")
-        inst.device_management.create_device_assignment(device="d")
-        inst.dispatcher.ingest(DecodedRequest(
-            kind=RequestKind.MEASUREMENT, device_token="d",
-            ts_s=1000, mtype="temp", value=1.0))
-        inst.dispatcher.flush()
-        assert inst.dispatcher.metrics_snapshot()["accepted"] == 1
+        disp = inst.dispatcher
+        stepped = []
+        step_packed = disp._step_packed
+
+        def counting_step(plan, trace):
+            stepped.append(plan)
+            return step_packed(plan, trace)
+
+        disp._step_packed = counting_step
+        dm = inst.device_management
+        dm.create_device_type(token="sensor", name="S")
+        for i in range(n_dev):
+            dm.create_device(token=f"d-{i}", device_type="sensor")
+            dm.create_device_assignment(device=f"d-{i}")
+        handles = np.asarray(inst.identity.device.lookup_many(
+            [f"d-{i}" for i in range(n_dev)]), np.int32)
+        for r in range(rounds):
+            disp.ingest_arrays(
+                device_id=handles[np.arange(width) % n_dev],
+                event_type=np.zeros(width, np.int32),
+                ts_s=np.full(width, 1_753_800_000 + r, np.int32),
+                mtype_id=np.zeros(width, np.int32),
+                value=np.full(width, 1.0, np.float32))
+        disp.flush()
+        assert len(stepped) >= rounds
+        assert sum(p.n_events for p in stepped) == rounds * width
+        for plan in stepped:
+            assert plan.packed_i.shape == (12, width)
+            assert plan.packed_f.shape == (4, width)
+            assert plan._batch is None   # nothing served reads plan.batch
+        snap = inst.metrics.snapshot()
+        stages = {"decode", "batch", "dispatch", "egress", "ring_wait",
+                  "ring_dispatch", "dispatch_wait", "inflight_wait"}
+        if n_shards > 1:
+            stages.add("place")
+        assert {n for n in snap["timers"]
+                if n.startswith("pipeline.stage_")} == {
+                    f"pipeline.stage_{s}_s" for s in stages}
+        syncs = snap["counters"]["pipeline.host_syncs"]
+        assert syncs >= 1
+        assert snap["timers"]["pipeline.device_wait_s"]["count"] == syncs
+        assert disp.metrics_snapshot()["accepted"] == rounds * width
     finally:
         inst.stop()
         inst.terminate()

@@ -6,7 +6,7 @@ as a host-plane event of the timer's name on the profiler's clock.
 These tests hold it to that, and hold the stage timers built on it to
 the additive reading PERF.md gives them:
 
-    batch wait → dispatch_wait → h2d → dispatch → inflight_wait →
+    batch wait → dispatch_wait → dispatch → inflight_wait →
     egress (device_wait + host)          [single-step plans]
     batch wait → ring_wait → ring_dispatch → inflight_wait → egress
                                          [ring plans]
@@ -136,7 +136,7 @@ def test_stage_timers_add_up_to_the_plans_latency(tmp_path, ring_depth):
         def totals():
             snap = reg.snapshot()
             out = {s: reg.timer(f"pipeline.stage_{s}_s").total
-                   for s in ("h2d", "inflight_wait", "egress", "ring_wait",
+                   for s in ("inflight_wait", "egress", "ring_wait",
                              "ring_dispatch", "dispatch_wait", "dispatch")}
             out["batch_wait"] = snap["histograms"][
                 "pipeline.batch_assemble_s"]["sum"]

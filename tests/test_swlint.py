@@ -468,6 +468,8 @@ class TestFixedFindings:
 
         b = Batcher(width=4, n_shards=1, registry_capacity=16,
                     resolve_device=int, resolve_mtype=lambda s: 0,
-                    resolve_alert=lambda s: 0, emit_packed=True)
+                    resolve_alert=lambda s: 0)
         (plan,) = b.add_arrays(device_id=np.arange(4, dtype=np.int32))
-        assert plan.packed_i is not None and plan.batch is None
+        # emission (under the intake lock) builds no device EventBatch;
+        # ``plan.batch`` is a reader's view, built on first access
+        assert plan.packed_i is not None and plan._batch is None

@@ -135,7 +135,7 @@ def run(width: int = 2048, iters: int = 16, capacity: int = 16_384,
     fill_batcher = Batcher(
         width=width, n_shards=1, registry_capacity=capacity,
         resolve_device=devices.lookup, resolve_mtype=lambda n: 0,
-        resolve_alert=lambda n: 0, deadline_ms=1e9, emit_packed=True)
+        resolve_alert=lambda n: 0, deadline_ms=1e9)
     cap = payload.count(b"\n") + 1
 
     def decode_fill_once():
@@ -184,7 +184,7 @@ def run(width: int = 2048, iters: int = 16, capacity: int = 16_384,
     batcher = Batcher(
         width=width, n_shards=1, registry_capacity=capacity,
         resolve_device=devices.lookup, resolve_mtype=lambda n: 0,
-        resolve_alert=lambda n: 0, deadline_ms=1e9, emit_packed=True)
+        resolve_alert=lambda n: 0, deadline_ms=1e9)
     ids = np.arange(width, dtype=np.int32) % capacity
     vals = np.linspace(0.0, 1.0, width).astype(np.float32)
 

@@ -26,7 +26,7 @@ isolation invariants:
 Usage::
 
     python tools/tenant_fairness_bench.py [--devices 100000] [--json]
-                                          [--out TENANTFAIR_r01.json]
+                                          [--out <file>.json]
     python tools/tenant_fairness_bench.py --smoke --json   # tier-1 gate
 
 Exit status 0 = every check passed.
