@@ -70,6 +70,11 @@ FAMILIES: Dict[str, Optional[Set[str]]] = {
     "slo.alert": None,                  # slo.alert.<objective>
     "flightrec": {"records", "anomalies", "snapshots", "suppressed_dumps"},
     "pipeline.bytes_copied": {"decode", "batch", "h2d"},
+    # single steps dispatched under pipeline.width (the batcher's width
+    # ladder, ingest/batcher.py plan_rungs): the counter the benchmark's
+    # wire_narrow_step_share reads BY NAME — closed and memberless, so no
+    # suffix can split the series
+    "pipeline.steps_narrow": set(),
     "native": {"build_fallbacks"},
     # crash-recovery surface (runtime/checkpoint.py + Instance.start):
     # restore wall time, replayed-event count, replay wall time — the

@@ -72,6 +72,28 @@ _FILL_0D = {name: np.full((), fill, dt) for name, dt, fill in _FIELDS}
 # the pipeline.bytes_copied.batch accounting).
 _ROW_BYTES = sum(np.dtype(dt).itemsize for _, dt, _ in _FIELDS)
 
+# The narrowest rung of the width ladder (:func:`plan_rungs`).  It bounds
+# the compiles at boot for small widths: a batcher of 128 columns or
+# fewer keeps one program.  One vector tile of the chip (128 lanes, the
+# axis a plan's rows lie along) was the number at hand; no chip reading
+# stands behind it, and no shipped width meets it (65,536 -> 1,024,
+# 16,384 -> 256).
+_MIN_RUNG = 128
+
+
+def plan_rungs(width: int) -> tuple:
+    """The widths a single-shard batcher emits plans at, ascending, at
+    most four and always ending in ``width``: ``width / 64``, ``/ 16``,
+    ``/ 4`` and ``width`` itself, none under :data:`_MIN_RUNG`.  A
+    deadline or flush emission takes the smallest rung that holds its
+    rows, so a 1,024-row payload steps a 1,024-column program and not
+    the ``pipeline.width`` one (PERF.md §6, PR 33).  A fixed function of
+    the configured width: the dispatcher compiles one step a rung at
+    ``start()``, and nothing else chooses."""
+    return tuple(sorted({min(width, max(_MIN_RUNG, width >> shift))
+                         for shift in (6, 4, 2, 0)}))
+
+
 # Packed wire layout (pipeline/packed.py BATCH_I/BATCH_F), cached on
 # first use — reservations allocate their columns AS rows of a packed
 # buffer pair so a full-width reserved segment is H2D-ready as-is.
@@ -355,13 +377,15 @@ class BatchPlan:
     comparisons); nothing on the served path builds it.
     """
 
-    __slots__ = ("_batch", "n_events", "width", "created_at", "max_wait_s",
-                 "host_cols", "packed_i", "packed_f", "staged", "seq",
-                 "reason", "dispatch_s")
+    __slots__ = ("_batch", "n_events", "width", "full_width", "created_at",
+                 "max_wait_s", "host_cols", "packed_i", "packed_f", "staged",
+                 "seq", "reason", "dispatch_s")
 
     def __init__(
         self,
         n_events: int = 0,
+        # columns of this plan's buffers: the rung it was assembled at
+        # (plan_rungs), which is the shape the device steps
         width: int = 1,
         created_at: float = 0.0,
         max_wait_s: float = 0.0,  # how long the oldest row waited
@@ -385,10 +409,15 @@ class BatchPlan:
         # call; ring slot: its 1/K share of the chain dispatch) —
         # flight-recorder stage attribution, stamped by the dispatcher.
         dispatch_s: float = 0.0,
+        # The batcher's configured width (``pipeline.width``); None =
+        # ``width``.  What "full" means to the ring and to ``fill``,
+        # whatever rung the plan rides.
+        full_width: Optional[int] = None,
     ):
         self._batch = None
         self.n_events = n_events
         self.width = width
+        self.full_width = width if full_width is None else full_width
         self.created_at = created_at
         self.max_wait_s = max_wait_s
         self.host_cols = host_cols if host_cols is not None else {}
@@ -412,15 +441,18 @@ class BatchPlan:
 
     @property
     def fill(self) -> float:
-        return self.n_events / self.width
+        """Rows over the CONFIGURED width: a rung-full deadline plan is
+        as partial as it was before it rode a narrow program."""
+        return self.n_events / self.full_width
 
 
 class AdaptiveBatchController:
     """Load-adaptive emission window (the deadline the batcher emits on).
 
-    The batch WIDTH is compiled into the jitted step and cannot change
-    per plan — the adaptive knob is the *time window* a partial batch may
-    coalesce before the deadline forces it out.  The stream-processing
+    A plan's width is one of a few compiled into the jitted step
+    (:func:`plan_rungs`) and follows its row count alone — the adaptive
+    knob is the *time window* a partial batch may coalesce before the
+    deadline forces it out.  The stream-processing
     literature identifies exactly this trade (arxiv 1807.07724 §5,
     2307.14287 §4): small windows chase the latency SLO, large windows
     chase throughput, and a static setting is wrong at one end or the
@@ -533,6 +565,11 @@ class Batcher:
         self.width = width
         self.n_shards = n_shards
         self.seg = width // n_shards
+        # The widths this batcher emits at (plan_rungs).  A mesh keeps
+        # the one: a sharded plan is n_shards segments of ``seg`` rows,
+        # and that segment length is also the dispatcher's key from a
+        # batch row to its shard.
+        self.rungs = plan_rungs(width) if n_shards == 1 else (width,)
         self.capacity = registry_capacity
         self.rows_per_shard = registry_capacity // n_shards
         self.resolve_device = resolve_device
@@ -962,11 +999,22 @@ class Batcher:
             seq=self.emitted_batches - 1, reason=reason,
         )
 
-    def _assemble_buffers(self):
-        """Fallback batch-assembly buffers — the copying lane's
-        allocations, off the adopted path.  Full-width fill emissions
-        (single-shard AND segment-ordered sharded reservations) adopt
-        the reservation's packed buffers and never come here; this
+    def _rung(self, rows: int) -> int:
+        """The width the copying lane emits ``rows`` pending rows at:
+        the smallest rung that holds them (a plan never takes more than
+        the configured width, the last rung and a mesh's only one).
+        Nothing but the row count decides."""
+        for width in self.rungs:
+            if width >= rows:
+                return width
+        return self.width
+
+    def _assemble_buffers(self, width: int):
+        """Fallback batch-assembly buffers, ``width`` columns wide — the
+        copying lane's allocations, off the adopted path.  Full-width
+        fill emissions (single-shard AND segment-ordered sharded
+        reservations) adopt the reservation's packed buffers and never
+        come here; this
         allocates only for the mixed/deadline/flush leftovers whose rows
         genuinely have to be gathered out of multiple chunks.
 
@@ -977,12 +1025,12 @@ class Batcher:
         their int rows at the end."""
         from sitewhere_tpu.pipeline.packed import BATCH_F, BATCH_I
 
-        ibuf = np.empty((len(BATCH_I), self.width), np.int32)
-        fbuf = np.empty((len(BATCH_F), self.width), np.float32)
+        ibuf = np.empty((len(BATCH_I), width), np.int32)
+        fbuf = np.empty((len(BATCH_F), width), np.float32)
         out = {}
         for i, f in enumerate(BATCH_I):
             if f in ("valid", "update_state"):
-                out[f] = np.full(self.width, _FILL[f], np.bool_)
+                out[f] = np.full(width, _FILL[f], np.bool_)
             else:
                 ibuf[i].fill(_FILL[f])
                 out[f] = ibuf[i]
@@ -1003,15 +1051,17 @@ class Batcher:
         elif q and q[0].reserved is not None \
                 and self._adoptable_sharded():
             return self._emit_adopted(reason)
-        ibuf, fbuf, out = self._assemble_buffers()
+        width = self._rung(self.pending)
+        seg = width // self.n_shards
+        ibuf, fbuf, out = self._assemble_buffers(width)
         n = 0
         for s in range(self.n_shards):
-            base = s * self.seg
+            base = s * seg
             filled = 0
             q = self._pending[s]
-            while filled < self.seg and q:
+            while filled < seg and q:
                 ch = q[0]
-                take = min(ch.length - ch.start, self.seg - filled)
+                take = min(ch.length - ch.start, seg - filled)
                 lo, hi = base + filled, base + filled + take
                 for f in _COL_FIELDS:
                     out[f][lo:hi] = ch.cols[f][ch.start:ch.start + take]
@@ -1034,9 +1084,10 @@ class Batcher:
 
         ibuf[BATCH_I.index("valid")] = out["valid"]
         ibuf[BATCH_I.index("update_state")] = out["update_state"]
-        self._count_copied(2 * 4 * self.width)  # bool→int32 rows
+        self._count_copied(2 * 4 * width)  # bool→int32 rows
         return BatchPlan(
-            n_events=n, width=self.width, created_at=now,
+            n_events=n, width=width, created_at=now,
             max_wait_s=wait, host_cols=out, packed_i=ibuf, packed_f=fbuf,
             seq=self.emitted_batches - 1, reason=reason,
+            full_width=self.width,
         )

@@ -461,6 +461,9 @@ class PipelineDispatcher(LifecycleComponent):
         self._m_e2e = metrics.histogram("pipeline.e2e_latency_s")
         self._m_assemble = metrics.histogram("pipeline.batch_assemble_s")
         self._m_steps = metrics.counter("pipeline.steps")
+        # single steps dispatched under pipeline.width: partial plans on
+        # a narrow rung of the batcher's width ladder (plan_rungs)
+        self._m_steps_narrow = metrics.counter("pipeline.steps_narrow")
         # Per-stage host-time timers (the overlapped-pipeline instrument
         # surface): decode / batch-assembly / step-dispatch / egress each
         # accumulate the HOST time they consume, so `sum(stage totals) >
@@ -637,7 +640,7 @@ class PipelineDispatcher(LifecycleComponent):
         # bookkeeping, not plan state.
         self._wd_tokens: Dict[int, int] = {}
         self._cpu_step = None   # lazily-built FALLBACK-level step
-        # the boot warm-up's failure, if any (see _warm_ring)
+        # the boot warm-up's failure, if any (see _warm_programs)
         self.warm_error: Optional[BaseException] = None
         # XLA cost analysis of the compiled chain at warm-up (flops /
         # bytes as device.cost.* gauges — the static roofline half).
@@ -1127,53 +1130,59 @@ class PipelineDispatcher(LifecycleComponent):
                 on_restart=self._on_egress_restart,
                 metrics=self.metrics)
             self._egress_super.start()
-        self._warm_ring()
+        self._warm_programs()
         self._thread = threading.Thread(
             target=self._loop, name=f"{self.name}-loop", daemon=True
         )
         self._thread.start()
 
-    def _warm_ring(self) -> None:
-        """Compile the programs the ring path dispatches at boot — the
-        K-step chain and the single packed step its partial plans fall
-        back to — with all-invalid batches (a semantic no-op: zero valid
-        rows touch no state), so the first REAL dispatch doesn't charge
-        a jit compile (about a minute each at the shipped capacity) to
-        live traffic's p99 and to the hung-step watchdog's budgets.
+    def _warm_programs(self) -> None:
+        """Compile the programs live traffic dispatches at boot — the
+        single packed step at every width the batcher emits at (one
+        rung a partial plan, ``Batcher.rungs``) and, with the ring on,
+        the K-step chain — with all-invalid batches (a semantic no-op:
+        zero valid rows touch no state), so the first REAL dispatch
+        doesn't charge a jit compile (about a minute each at the shipped
+        capacity) to live traffic's p99 and to the hung-step watchdog's
+        budgets, whichever rung it is the first to need.
         Best-effort: a failure only defers the compile to the first
         dispatch, and stays readable in :attr:`warm_error`."""
         self.warm_error = None
-        if not self.ring_depth:
-            return
         try:
             from sitewhere_tpu.pipeline.packed import BATCH_F, BATCH_I
 
-            width = self.batcher.width
-            bi = np.zeros((len(BATCH_I), width), np.int32)
-            bf = np.zeros((len(BATCH_F), width), np.float32)
-            # staged as a live plan is: a slot that arrives with another
-            # placement is another program
-            bi, bf = self._stage_packed(bi, bf) or (bi, bf)
-            k = self.ring_depth
-            chain = self._ring_chain(k)
+            def staged(width: int):
+                bi = np.zeros((len(BATCH_I), width), np.int32)
+                bf = np.zeros((len(BATCH_F), width), np.float32)
+                # staged as a live plan is: a slot that arrives with
+                # another placement is another program
+                return self._stage_packed(bi, bf) or (bi, bf)
+
+            slots = [staged(width) for width in self.batcher.rungs]
             tables = self._tables_packed()
-            with self._step_lock:
-                # block=True: completion is forced BEFORE the commit, so
-                # an asynchronously-surfacing execution failure raises
-                # here (state manager still holds the pre-chain epoch)
-                # instead of poisoning the adopted epoch for every
-                # subsequent live dispatch
-                self._dispatch_chain(
-                    chain, tables, [bi] * k, [bf] * k, block=True)
-            # the single step: no lock, it commits nothing (zero valid
+            k = self.ring_depth
+            if k:
+                bi, bf = slots[-1]   # the configured width
+                chain = self._ring_chain(k)
+                with self._step_lock:
+                    # block=True: completion is forced BEFORE the commit,
+                    # so an asynchronously-surfacing execution failure
+                    # raises here (state manager still holds the
+                    # pre-chain epoch) instead of poisoning the adopted
+                    # epoch for every subsequent live dispatch
+                    self._dispatch_chain(
+                        chain, tables, [bi] * k, [bf] * k, block=True)
+            # the single steps: no lock, they commit nothing (zero valid
             # rows; the outputs are dropped)
             ps = self.state_manager.current_packed
             if self.mesh is not None:
                 from sitewhere_tpu.pipeline.sharded import place_packed_state
 
                 ps = place_packed_state(self.mesh, ps)
-            jax.block_until_ready(self._packed_step(tables, ps, bi, bf))
-            if self.cost_analysis:
+            for slot in slots:
+                jax.block_until_ready(
+                    self._packed_step(tables, ps, *slot))
+            if k and self.cost_analysis:
                 # static roofline of the compiled chain: flops/bytes as
                 # device.cost.* gauges (AOT lower+compile of the same
                 # shapes; best-effort, inside this try on purpose)
@@ -1188,7 +1197,7 @@ class PipelineDispatcher(LifecycleComponent):
                 record_cost_metrics(self.metrics, cost)
         except Exception as e:
             self.warm_error = e
-            logger.warning("ring warm-up failed (compile deferred to the "
+            logger.warning("warm-up failed (compile deferred to the "
                            "first dispatch)", exc_info=True)
 
     def _dispatch_chain(self, chain, tables, slots_i, slots_f,
@@ -1556,7 +1565,9 @@ class PipelineDispatcher(LifecycleComponent):
         return (self.ring_depth > 0
                 and replay_depth == 0
                 and plan.reason == "fill"
-                and plan.n_events == plan.width
+                # a full pipeline.width: a deadline plan that fills its
+                # narrow rung is still a latency-carrying partial
+                and plan.n_events == plan.full_width
                 # breaker demoted past CHAINED: bisectable single-step
                 # dispatch only, until a cooldown probe succeeds
                 and self.breaker.allow_chain())
@@ -2111,6 +2122,8 @@ class PipelineDispatcher(LifecycleComponent):
             "reason": plan.reason,
             "rows": int(plan.n_events),
             "fill": round(plan.fill, 4),
+            # the rung the plan stepped at (fill is of pipeline.width)
+            "width": int(plan.width),
             "slot": getattr(out, "slot", None),
             "replay_depth": int(replay_depth),
             "wait_ms": round(plan.max_wait_s * 1e3, 3),
@@ -2404,6 +2417,8 @@ class PipelineDispatcher(LifecycleComponent):
         THIS thread while the device computes.  Called under _step_lock."""
         self.steps += 1
         self._m_steps.inc()
+        if plan.width < plan.full_width:
+            self._m_steps_narrow.inc()
         # the queue-entry stamp rides the item: inflight_wait is the
         # interval to its pop in _egress_guarded, across threads
         self._inflight.append((plan, out, replay_depth, trace,

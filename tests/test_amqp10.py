@@ -364,7 +364,9 @@ def test_receiver_feeds_instance_pipeline(tmp_path):
         "request": {"name": "temp", "value": 20.0 + i,
                     "eventDate": 1_753_000_000 + i},
     }).encode() for i in range(3)]
-    broker = MiniEventHub(messages=lines)
+    # published once the device exists: a line that beats create_device
+    # to the pipeline is an unregistered device's, and never accepted
+    broker = MiniEventHub()
     cfg = Config({
         "instance": {"id": "eh-test", "data_dir": str(tmp_path / "data")},
         "pipeline": {"width": 64, "registry_capacity": 256,
@@ -384,6 +386,8 @@ def test_receiver_feeds_instance_pipeline(tmp_path):
         inst.device_management.create_device(token="eh-1",
                                              device_type="sensor")
         inst.device_management.create_device_assignment(device="eh-1")
+        for line in lines:
+            broker.push(line)
         assert _wait(
             lambda: inst.dispatcher.metrics_snapshot()["accepted"] == 3)
         inst.dispatcher.flush()

@@ -62,6 +62,25 @@ def test_chip_side_step_costs_the_batch_not_the_registry(capacity):
     assert rows["packed_chain_k8_donated"]["hlo"].count(" while(") == 1
 
 
+@pytest.mark.parametrize("width, capacity", [(65536, 1 << 20),
+                                             (16384, 1 << 18)])
+def test_chip_side_narrow_rung_costs_the_batch(width, capacity):
+    """The single step as a partial plan rides it (PR 33): lowered at
+    the narrowest rung of a deployment's width — 1,024 columns at the
+    shipped 65,536 against the shipped 1<<20 slots — the chip's compile
+    holds nothing sized by the registry but the carry's one copy, the
+    registry table and ``present_now``, and expands no scatter."""
+    from sitewhere_tpu.ingest.batcher import plan_rungs
+
+    (row,) = aot_check.aot_check(
+        capacity=capacity, width=plan_rungs(width)[0],
+        programs=("packed_step",))
+    aot_check.assert_step_costs_the_batch(row["hlo"], capacity,
+                                          donated=False)
+    assert row["hlo"].count(" while(") == 0
+    assert row["alias_bytes"] == 0
+
+
 def test_chip_side_tracing_is_scoped():
     assert jax.default_backend() == "cpu"
     with aot_check.chip_side_tracing():

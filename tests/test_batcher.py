@@ -437,3 +437,155 @@ def test_plan_views_agree(reason, n_shards):
             np.asarray(getattr(got, f.name)),
             np.asarray(getattr(want, f.name)), err_msg=f.name)
     assert int(np.asarray(want.valid).sum()) == n
+
+
+# -- the width ladder (PR 33): a partial plan is assembled at the width it
+# -- needs --------------------------------------------------------------
+
+LADDER_W = 8192  # the smallest width with four distinct rungs
+
+
+def ladder_batcher(width=LADDER_W, n_shards=1, clock=None, **kw):
+    return Batcher(width=width, n_shards=n_shards, registry_capacity=1 << 16,
+                   resolve_device=lambda t: NULL_ID,
+                   resolve_mtype=lambda n: 0, resolve_alert=lambda n: 0,
+                   deadline_ms=5.0, clock=clock or FakeClock(), **kw)
+
+
+@pytest.mark.parametrize("width, rungs", [
+    (65536, (1024, 4096, 16384, 65536)),
+    (16384, (256, 1024, 4096, 16384)),
+    (8192, (128, 512, 2048, 8192)),
+    (1024, (128, 256, 1024)),     # no rung under one lane tile
+    (256, (128, 256)),
+    (128, (128,)),
+    (64, (64,)),
+    (8, (8,)),
+])
+def test_plan_rungs_are_a_fixed_function_of_the_width(width, rungs):
+    from sitewhere_tpu.ingest.batcher import plan_rungs
+
+    assert plan_rungs(width) == rungs
+    assert len(rungs) <= 4 and rungs[-1] == width
+    assert ladder_batcher(width=width).rungs == rungs
+
+
+def _rung_cases():
+    from sitewhere_tpu.ingest.batcher import plan_rungs
+
+    rungs = plan_rungs(LADDER_W)
+    for i, rung in enumerate(rungs):
+        yield rung - 1, rung                 # one under
+        yield rung, rung                     # at
+        if i + 1 < len(rungs):
+            yield rung + 1, rungs[i + 1]     # one over
+
+
+@pytest.mark.parametrize("reason", ["deadline", "flush"])
+@pytest.mark.parametrize("n, rung", sorted(set(_rung_cases())))
+def test_partial_emission_takes_the_smallest_rung_that_holds_it(
+        reason, n, rung):
+    from sitewhere_tpu.pipeline.packed import BATCH_F, BATCH_I
+
+    clock = FakeClock()
+    b = ladder_batcher(clock=clock)
+    ids = np.arange(n, dtype=np.int32)
+    emitted = b.add_arrays(device_id=ids, value=ids.astype(np.float32),
+                           update_state=ids % 2 == 0)
+    if n == LADDER_W:
+        # a full width goes out as it fills, whatever would have come
+        (plan,) = emitted
+        assert plan.reason == "fill"
+    else:
+        assert emitted == []
+        clock.t = 1.0
+        plan = b.poll() if reason == "deadline" else b.flush()
+        assert plan.reason == reason
+    assert plan.n_events == n
+    assert plan.width == rung and plan.full_width == LADDER_W
+    assert plan.fill == n / LADDER_W      # of the configured width
+    assert plan.packed_i.shape == (len(BATCH_I), rung)
+    assert plan.packed_f.shape == (len(BATCH_F), rung)
+    assert set(plan.host_cols) == set(BATCH_I) | set(BATCH_F)
+    for f, col in plan.host_cols.items():
+        assert col.shape == (rung,), f
+    valid, upd = plan.host_cols["valid"], plan.host_cols["update_state"]
+    assert valid.dtype == np.bool_ and upd.dtype == np.bool_
+    assert valid[:n].all() and not valid[n:].any()
+    np.testing.assert_array_equal(upd[:n], ids % 2 == 0)
+    np.testing.assert_array_equal(plan.packed_i[0], valid.astype(np.int32))
+    np.testing.assert_array_equal(plan.host_cols["device_id"][:n], ids)
+    assert (plan.host_cols["device_id"][n:] == NULL_ID).all()
+    assert b.pending == 0
+
+
+def test_fill_emission_is_full_width_and_carries_the_rest_narrow():
+    b = ladder_batcher()
+    n = LADDER_W + 300
+    (plan,) = b.add_arrays(device_id=np.arange(n, dtype=np.int32))
+    assert plan.reason == "fill"
+    assert plan.width == plan.full_width == plan.n_events == LADDER_W
+    rest = b.flush()
+    assert (rest.n_events, rest.width) == (300, 512)
+    np.testing.assert_array_equal(
+        rest.host_cols["device_id"][:300], np.arange(LADDER_W, n))
+
+
+@pytest.mark.parametrize("n", [LADDER_W, 700])
+def test_adopted_lane_is_unchanged(n):
+    """A full-width reservation is the batch, full or partial: the
+    zero-copy lane keeps the configured width and copies nothing."""
+    b = ladder_batcher()
+    res = b.reserve(LADDER_W)
+    res.device_id[:n] = np.arange(n)
+    res.mtype_id[:n] = 0
+    res.ts_s[:n] = 1000
+    res.ts_ns[:n] = 0
+    res.update_state[:n] = 1
+    res.value[:n] = 1.0
+    res.set_const(tenant_id=0, payload_ref=5)
+    res.n = n
+    plans = res.commit()
+    plan = plans[0] if n == LADDER_W else b.flush()
+    assert plan.packed_i is res.ibuf and plan.packed_f is res.fbuf
+    assert plan.width == plan.full_width == LADDER_W
+    assert plan.n_events == n and b.copied_bytes == 0
+
+
+@pytest.mark.parametrize("n", [100, 128, 600])
+def test_emit_tail_reads_the_configured_width(n):
+    """A rung-full deadline plan is not a full one to the adaptive
+    deadline or to ``ingest.batch_fill``."""
+    from sitewhere_tpu.runtime.metrics import MetricsRegistry
+
+    seen = []
+
+    class Ctl:
+        deadline_s = 0.005
+
+        def on_emit(self, n_events, width, pending, reason):
+            seen.append((n_events, width, pending, reason))
+
+    clock, m = FakeClock(), MetricsRegistry()
+    b = ladder_batcher(clock=clock, metrics=m, controller=Ctl())
+    b.add_arrays(device_id=np.arange(n, dtype=np.int32))
+    clock.t = 1.0
+    plan = b.poll()
+    assert plan.width < LADDER_W
+    assert seen == [(n, LADDER_W, 0, "deadline")]
+    assert m.snapshot()["gauges"]["ingest.batch_fill"] == n / LADDER_W
+
+
+@pytest.mark.parametrize("reason", ["deadline", "flush"])
+@pytest.mark.parametrize("n", [1, 100, 129, 2049])
+def test_sharded_batcher_emits_at_one_width(reason, n):
+    clock = FakeClock()
+    b = ladder_batcher(n_shards=4, clock=clock)
+    assert b.rungs == (LADDER_W,)
+    b.add_arrays(device_id=(np.arange(n, dtype=np.int32) * 31) % (1 << 16))
+    clock.t = 1.0
+    plan = b.poll() if reason == "deadline" else b.flush()
+    assert plan.n_events == n
+    assert plan.width == plan.full_width == LADDER_W
+    assert plan.packed_i.shape[1] == LADDER_W
+    assert plan.host_cols["valid"].shape == (LADDER_W,)

@@ -895,3 +895,148 @@ class TestStageOverlap:
             assert (dispatch.total - d_total0) + e_spent > wall * 0.9
         finally:
             disp.stop()
+
+
+# ---------------------------------------------------------------------------
+# the width ladder (PR 33): every rung compiled at start(), partial plans
+# stepped at the width they need
+# ---------------------------------------------------------------------------
+
+LADDER_W = 1024          # rungs 128 / 256 / 1,024
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = []           # one entry a program XLA compiled, process-wide
+
+
+def _count_compiles():
+    """Register (once) the listener ``benchmarks/deployment.py``
+    counts compiles with; returns the running list."""
+    import jax.monitoring as monitoring
+
+    if not getattr(_count_compiles, "on", False):
+        monitoring.register_event_duration_secs_listener(
+            lambda event, s, **kw: _compiles.append(event)
+            if event == _COMPILE_EVENT else None)
+        _count_compiles.on = True
+    return _compiles
+
+
+def _ladder_instance(tmp_path, ring_depth, **pipeline):
+    from sitewhere_tpu.instance import Instance
+    from sitewhere_tpu.runtime.config import Config
+
+    inst = Instance(Config({
+        "instance": {"id": "ladder", "data_dir": str(tmp_path / "data")},
+        "pipeline": {"width": LADDER_W, "registry_capacity": 256,
+                     "mtype_slots": 4, "deadline_ms": 60_000.0,
+                     "n_shards": 1, "ring_depth": ring_depth, **pipeline},
+        "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
+    }, apply_env=False))
+    inst.start()
+    inst.device_management.create_device_type(token="sensor", name="S")
+    for i in range(8):
+        inst.device_management.create_device(token=f"d-{i}",
+                                             device_type="sensor")
+        inst.device_management.create_device_assignment(device=f"d-{i}")
+    return inst
+
+
+@pytest.fixture(scope="module", params=[0, 2], ids=["no-ring", "ring2"])
+def ladder(request, tmp_path_factory):
+    """A started instance (ring off, ring on) past its first full-width
+    plan, which takes every first-use program that is not a step —
+    what the benchmark's priming does."""
+    compiles = _count_compiles()
+    inst = _ladder_instance(tmp_path_factory.mktemp("ladder"), request.param)
+    d = inst.dispatcher
+    assert d.warm_error is None and d.ring_depth == request.param
+    d.ingest_arrays(device_id=np.arange(LADDER_W, dtype=np.int32) % 8,
+                    mtype_id=np.zeros(LADDER_W, np.int32),
+                    event_type=np.zeros(LADDER_W, np.int32),
+                    ts_s=np.full(LADDER_W, 1_754_000_000, np.int32))
+    d.flush()
+    inst.device_state.current   # the checkpoint's reader, as the harness
+    yield inst, compiles
+    inst.stop()
+    inst.terminate()
+
+
+@pytest.mark.parametrize("n, rung", [(1, 128), (128, 128), (129, 256),
+                                     (256, 256), (257, 1024), (1000, 1024)])
+def test_live_partial_plan_of_every_rung_compiles_nothing(ladder, n, rung):
+    inst, compiles = ladder
+    d = inst.dispatcher
+    narrow0 = inst.metrics.counter("pipeline.steps_narrow").value
+    steps0, accepted0, before = d.steps, d.totals["accepted"], len(compiles)
+    d.ingest_arrays(device_id=np.arange(n, dtype=np.int32) % 8,
+                    mtype_id=np.zeros(n, np.int32),
+                    event_type=np.zeros(n, np.int32),
+                    ts_s=np.full(n, 1_754_000_100, np.int32),
+                    value=np.ones(n, np.float32))
+    d.flush()
+    assert d.totals["accepted"] - accepted0 == n
+    assert d.steps - steps0 == 1
+    assert len(compiles) == before, compiles[before:]
+    rec = inst.flightrec.recent(1)[0]
+    assert (rec["rows"], rec["width"], rec["reason"]) == (n, rung, "flush")
+    assert rec["fill"] == round(n / LADDER_W, 4)   # of pipeline.width
+    # counted as narrow unless it stepped the configured width
+    assert (inst.metrics.counter("pipeline.steps_narrow").value - narrow0
+            == (1 if rung < LADDER_W else 0))
+
+
+def test_ring_refuses_a_rung_full_deadline_plan():
+    """Full means a full ``pipeline.width``: a deadline plan that fills
+    its narrow rung is a latency-carrying partial, not ring traffic."""
+    from sitewhere_tpu.ingest.batcher import BatchPlan
+
+    disp, _, _ = make_ring_dispatcher(ring_depth=2)
+    t = [0.0]
+    disp.batcher = Batcher(
+        width=LADDER_W, n_shards=1, registry_capacity=64,
+        resolve_device=lambda tok: NULL_ID, resolve_mtype=lambda n: 0,
+        resolve_alert=lambda n: 0, deadline_ms=5.0, clock=lambda: t[0])
+    disp.batcher.add_arrays(device_id=np.zeros(128, np.int32))
+    t[0] = 1.0
+    plan = disp.batcher.poll()
+    assert (plan.reason, plan.n_events, plan.width) == ("deadline", 128, 128)
+    assert not disp._ring_eligible(plan, replay_depth=0)
+    # nor would the reason alone admit it
+    forged = BatchPlan(n_events=128, width=128, full_width=LADDER_W,
+                       reason="fill", seq=7)
+    assert not disp._ring_eligible(forged, replay_depth=0)
+    (full,) = disp.batcher.add_arrays(device_id=np.zeros(LADDER_W, np.int32))
+    assert full.width == LADDER_W and disp._ring_eligible(full, 0)
+
+
+def test_step_failure_on_a_narrow_plan_bisects_and_dead_letters(tmp_path):
+    import json as _json
+
+    inst = _ladder_instance(tmp_path, 0, deadline_ms=5.0)
+    try:
+        lines = "\n".join(_json.dumps({
+            "deviceToken": "d-0", "type": "Measurement",
+            "request": {"name": "temp", "value": v,
+                        "eventDate": 1_754_600_000 + i},
+        }) for i, v in enumerate(
+            [1.0, float("nan"), 3.0, float("nan"), 5.0])).encode()
+        faults.device_inject("device.dispatch", times=None,
+                             when_nonfinite=True)
+        inst.dispatcher.ingest_wire_lines(lines)
+        inst.dispatcher.flush()
+        faults.device_clear()
+        inst.event_store.flush()
+        assert inst.event_store.total_events == 3
+        letters = [d for d in inst.list_dead_letters(limit=10)
+                   if d.get("kind") == "device-poison"]
+        assert sum(d["count"] for d in letters) == 2
+        c = inst.metrics.snapshot()["counters"]
+        assert c["device.fault.step_faults"] == 1
+        assert c["device.fault.poison_rows"] == 2
+        # the failed plan and every clean subset rode the 128-row rung
+        widths = {r["width"] for r in inst.flightrec.recent(50)}
+        assert widths == {128}
+        assert c["pipeline.steps_narrow"] == c["pipeline.steps"] >= 1
+    finally:
+        faults.device_clear()
+        inst.stop()
+        inst.terminate()

@@ -288,6 +288,86 @@ def test_packed_chain_donation():
             np.asarray(getattr(ref, f)), np.asarray(getattr(got, f)), err_msg=f)
 
 
+# -- the width ladder (PR 33): the same rows at every rung ----------------
+
+RUNG_W = 8192   # the smallest width with four distinct rungs
+
+
+def _rung_plans(n_rows, seeds):
+    """One plan a seed out of a real single-shard batcher of
+    width ``RUNG_W``, each of ``n_rows`` rows (so each at its rung), and
+    the same rows laid into buffers of the configured width."""
+    from sitewhere_tpu.ingest.batcher import Batcher
+
+    b = Batcher(width=RUNG_W, n_shards=1, registry_capacity=256,
+                resolve_device=lambda t: NULL_ID, resolve_mtype=lambda n: 0,
+                resolve_alert=lambda n: 0, deadline_ms=5.0,
+                clock=lambda: 0.0)
+    out = []
+    for seed in seeds:
+        cols = _batch(width=n_rows, seed=seed)
+        cols.pop("valid")   # the batcher owns it: every queued row is valid
+        # a full width goes out as it fills: the top rung's own case
+        (plan,) = b.add_arrays(**cols) or [b.flush()]
+        assert plan.n_events == n_rows
+        wide_i, wide_f, _ = b._assemble_buffers(RUNG_W)
+        wide_i[BATCH_I.index("valid")] = 0
+        wide_i[BATCH_I.index("update_state")] = 1   # its padding fill
+        wide_i[:, :n_rows] = plan.packed_i[:, :n_rows]
+        wide_f[:, :n_rows] = plan.packed_f[:, :n_rows]
+        out.append((plan, wide_i, wide_f))
+    return out
+
+
+def _rung_row_counts():
+    from sitewhere_tpu.ingest.batcher import plan_rungs
+
+    return [(rung, n) for rung in plan_rungs(RUNG_W)
+            for n in (rung, rung - 3)]
+
+
+@pytest.mark.parametrize("rung, n_rows", _rung_row_counts())
+def test_rows_step_the_same_at_their_rung_and_at_the_configured_width(
+        rung, n_rows):
+    """Rows past ``n_events`` are invalid at any width, and an invalid
+    row touches no state, fires no rule and counts nowhere: two steps
+    deep (the second reads what the first wrote), the narrow program
+    gives the wide one's carry, outputs on the plan's rows, metrics,
+    tenant block and ``present_now``, bit for bit."""
+    from sitewhere_tpu.pipeline.packed import METRIC_SCALARS, TELEMETRY_SCALARS
+    from sitewhere_tpu.pipeline.step import NUM_EVENT_TYPES
+
+    registry, rules, zones = _tables()
+    t = pack_tables(registry, rules, zones)
+    step = jax.jit(packed_pipeline_step)
+    narrow = wide = pack_state(_seeded_state())
+    invalid_at = (len(METRIC_SCALARS) + NUM_EVENT_TYPES
+                  + TELEMETRY_SCALARS.index("rows_invalid"))
+    for plan, wide_i, wide_f in _rung_plans(n_rows, seeds=(11, 12)):
+        assert plan.width == rung and plan.packed_i.shape[1] == rung
+        narrow, oi_n, m_n, present_n = step(
+            t, narrow, jnp.asarray(plan.packed_i), jnp.asarray(plan.packed_f))
+        wide, oi_w, m_w, present_w = step(
+            t, wide, jnp.asarray(wide_i), jnp.asarray(wide_f))
+        np.testing.assert_array_equal(
+            np.asarray(narrow.rows), np.asarray(wide.rows))
+        oi_n, oi_w = np.asarray(oi_n), np.asarray(oi_w)
+        np.testing.assert_array_equal(oi_n[:, :n_rows], oi_w[:, :n_rows])
+        assert not oi_n[0, n_rows:].any() and not oi_w[0, n_rows:].any()
+        # the one entry that counts the padding: width - processed
+        m_n, m_w = np.asarray(m_n).copy(), np.asarray(m_w).copy()
+        assert m_n[invalid_at] - rung == m_w[invalid_at] - RUNG_W
+        m_n[invalid_at] = m_w[invalid_at] = 0
+        np.testing.assert_array_equal(m_n, m_w)
+        vn = PackedView(oi_n, m_n, present_n)
+        vw = PackedView(oi_w, m_w, present_w)
+        assert int(vn.metrics.processed) > 0.8 * n_rows
+        np.testing.assert_array_equal(vn.tenant_meter, vw.tenant_meter)
+        np.testing.assert_array_equal(
+            np.asarray(present_n), np.asarray(present_w))
+        assert np.asarray(present_n).any()
+
+
 class TestPackedDispatcher:
     """The Instance dispatcher driving the packed step end-to-end.
 
