@@ -1,7 +1,7 @@
 """One running deployment from a configuration file: an ``Instance`` at
-the file's settings with its fleet registered, its rules created and
-its watchdog calibrated — what an operator does before traffic.  Copied
-from ``chip_smoke.py`` (fleet layout, drain) where that was sound."""
+the file's settings, populated by the file's kind (its fleet, its
+rules), with its watchdog calibrated — what an operator does before
+traffic.  Copied from ``chip_smoke.py`` (drain) where that was sound."""
 
 from __future__ import annotations
 
@@ -10,9 +10,7 @@ import shutil
 import tempfile
 import time
 
-import numpy as np
-
-from benchmarks import reference
+from benchmarks import cells
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -77,14 +75,9 @@ class Deployment:
         from sitewhere_tpu.outbound.connectors import CallbackConnector
         from sitewhere_tpu.pipeline import packed
         from sitewhere_tpu.runtime.config import Config
-        from sitewhere_tpu.schema import EventType
 
-        if (reference.MEASUREMENT, reference.LOCATION, reference.ALERT) != (
-                int(EventType.MEASUREMENT), int(EventType.LOCATION),
-                int(EventType.ALERT)):
-            raise RuntimeError("the reference's event-type constants are "
-                               "stale")
         self.config = config
+        self.kind = cells.load_kind(config)
         self.log = log
         self.copy_errors0 = packed.host_copy_errors
         self.tmp = tempfile.mkdtemp(prefix="sw-bench-")
@@ -111,28 +104,10 @@ class Deployment:
         self.ring_depth = int(self.d.ring_depth)
 
     def populate(self) -> None:
-        """Fleet, rules, measurement name, watchdog calibration."""
-        from sitewhere_tpu.schema import AlertLevel, ComparisonOp
-
+        """The kind's fleet and rules, measurement name, watchdog
+        calibration."""
         inst, config = self.inst, self.config
-        t0 = time.perf_counter()
-        self.tokens, self.handles = self._register_fleet(
-            int(config["fleet"]["devices"]))
-        dt = time.perf_counter() - t0
-        self.log(f"[deploy] registered {len(self.tokens)} devices in "
-                 f"{dt:.1f}s ({len(self.tokens) / dt:.0f}/s)")
-        for i, rule in enumerate(config["rules"]["thresholds"]):
-            inst.rules.create_rule(
-                mtype=None, op=ComparisonOp[rule["op"]],
-                threshold=float(rule["threshold"]), alert_type=f"t{i}",
-                alert_level=AlertLevel.WARNING)
-        for i, zone in enumerate(config["rules"]["zones"]):
-            (lat0, lat1), (lon0, lon1) = zone["lat"], zone["lon"]
-            inst.device_management.create_zone(
-                token=f"z{i}", name=f"Z{i}", area="hq",
-                alert_type=f"inside{i}",
-                bounds=[(lat0, lon0), (lat0, lon1), (lat1, lon1),
-                        (lat1, lon0)])
+        self.kind.populate(self)
         self.mtype = int(inst.identity.mtype.mint(config["measurement"]))
         self.slot = self.mtype % self.mtype_slots
         # The watchdog's shipped budgets (1 s / 10 s) assume a ~10 ms
@@ -148,31 +123,6 @@ class Deployment:
                  f"{profile.get('state_ms')}; watchdog soft {wd.soft_s:.2f}s"
                  f" hard {wd.hard_s:.2f}s")
         self.profile = profile
-
-    def _register_fleet(self, n_devices: int):
-        """Devices with assignments through the management API, a
-        ``1/n_shards`` of them on each shard: a registry block belongs
-        to shard ``handle // rows_per_shard`` and handles are minted
-        densely, so the handles in between are reserved."""
-        inst = self.inst
-        dm = inst.device_management
-        dm.create_device_type(token="sensor", name="Sensor")
-        dm.create_area_type(token="bldg", name="Building")
-        dm.create_area(token="hq", name="HQ", area_type="bldg")
-        per_shard = n_devices // self.n_shards
-        rows_per_shard = self.capacity // self.n_shards
-        tokens = []
-        for s in range(self.n_shards):
-            for i in range(len(inst.identity.device), s * rows_per_shard):
-                inst.identity.device.mint(f"reserved-{i}")
-            for i in range(per_shard):
-                token = f"d-{s}-{i}"
-                dm.create_device(token=token, device_type="sensor")
-                dm.create_device_assignment(device=token, area="hq")
-                tokens.append(token)
-        handles = np.asarray(inst.identity.device.lookup_many(tokens),
-                             np.int32)
-        return tokens, handles
 
     def drain(self, timeout_s: float = 300.0) -> None:
         """flush() until the dispatcher is quiescent: nothing pending and

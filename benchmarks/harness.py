@@ -1,13 +1,16 @@
 """One run of one cell: deployment up, traffic from the seed, a priming
-pass, the measured window, the drain, the comparison with the plain
-reference, and the run's record for the metric readers.
+pass, the measured window, the drain, the comparisons that decide
+``correct`` (the configuration's kind makes those with its plain
+reference, ``_compare`` those that hold whatever the deployment), and
+the run's record for the metric readers.
 
 The client's side is the yardstick.  A priority ``CallbackConnector``
 sees every row the system stored (``_egress`` appends to the store
-before it submits to outbound), bins the rows by the stamp their send
-carried and notes the time.  An event's latency is that time minus the
-time its send was DUE; an event refused, lost or still undelivered when
-the final drain ends is failed and takes the drain's end as its time.
+before it submits to outbound), bins the rows that are a send's own
+(the kind says which) by the stamp their send carried and notes the
+time.  An event's latency is that time minus the time its send was DUE;
+an event refused, lost or still undelivered when the final drain ends
+is failed and takes the drain's end as its time.
 """
 
 from __future__ import annotations
@@ -19,7 +22,7 @@ import time
 
 import numpy as np
 
-from benchmarks import cells, reference, trace_reduce
+from benchmarks import cells, trace_reduce
 from benchmarks.deployment import (CompileMeter, Deployment, device_doc,
                                    memory_peak_bytes)
 
@@ -72,15 +75,18 @@ class DeliveryLog:
 
         self._span = TraceAnnotation
         self.seq_of = None            # until bind(): no send was made
+        self.own_rows = None
         self.delivered = np.zeros(0, np.int64)
         self.rows: list = []          # (time, seqs, counts)
         self.stray = 0                # source rows with no send's stamp
         self.cond = threading.Condition()
 
-    def bind(self, capacity: int, seq_of) -> None:
+    def bind(self, capacity: int, seq_of, own_rows) -> None:
         """Size the log for ``capacity`` sends whose stamps ``seq_of``
-        turns back into sequence numbers."""
+        turns back into sequence numbers; ``own_rows(cols)`` marks the
+        delivered rows that are a send's own."""
         self.delivered = np.zeros(capacity, np.int64)
+        self.own_rows = own_rows
         self.seq_of = seq_of
 
     def __call__(self, cols, mask) -> None:
@@ -88,8 +94,7 @@ class DeliveryLog:
             return
         with self._span("bench.connector"):
             now = time.perf_counter()
-            keep = np.asarray(mask) & (
-                np.asarray(cols["event_type"]) != reference.ALERT)
+            keep = np.asarray(mask) & self.own_rows(cols)
             seq = self.seq_of(np.asarray(cols["ts_s"])[keep],
                               np.asarray(cols["ts_ns"])[keep])
             ok = (seq >= 0) & (seq < len(self.delivered))
@@ -298,7 +303,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         traffic = kind.build(params, dep, np.random.default_rng(seed))
         capacity = int(traffic.max_sends(seconds))
         sends = SendLog(capacity)
-        delivery.bind(capacity, traffic.seq_of)
+        delivery.bind(capacity, traffic.seq_of, dep.kind.own_rows)
         client = Client(dep, sends, delivery)
 
         with TraceAnnotation("bench.prime"):
@@ -396,52 +401,20 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
 def _compare(checks: Checks, dep: Deployment, traffic, run: Run,
              in_window: dict) -> None:
-    """The run against the plain reference and the default rung."""
+    """The kind's comparisons with its reference, then what holds
+    whatever the deployment (no kind can switch these off): the default
+    rung, nothing quarantined, lost or copied by mistake, nothing shed
+    but by admission, nothing compiled inside the window."""
     from sitewhere_tpu import native
     from sitewhere_tpu.pipeline import packed
-    from sitewhere_tpu.schema import EventType
 
-    sends, inst, config = run.sends, dep.inst, dep.config
-    accepted = np.nonzero(sends.status == OK)[0]
-    want = reference.expected_counts(
-        traffic.bodies, sends.body[accepted], config["rules"])
-    snap = dep.d.metrics_snapshot()
-    n, derived = want["events"], want["derived_alerts"]
-    checks.equal("processed", snap["processed"], n + derived)
-    checks.equal("accepted", snap["accepted"], n + derived)
-    for key in ("threshold_alerts", "zone_alerts", "derived_alerts"):
-        checks.equal(key, snap[key], want[key])
-    checks.equal("unregistered + unassigned",
-                 snap["unregistered"] + snap["unassigned"], 0)
-    store = inst.event_store
-    checks.equal("store total = source events + derived alerts",
-                 store.total_events, n + derived)
-    checks.equal("stored ALERT events = derived alerts",
-                 store.query(event_type=int(EventType.ALERT)).total, derived)
-    checks.equal("rows the connector saw of accepted sends",
-                 int(run.delivery.delivered[accepted].sum()), n)
-    checks.equal("rows the connector could not place", run.delivery.stray, 0)
-    checks.equal("sends partly admitted",
-                 int((sends.status == PARTIAL).sum()), 0)
+    sends, inst = run.sends, dep.inst
+    # rows its reference expects refused, each held to the reference by
+    # a comparison of the kind's own, are dead letters that no send shed
+    # by admission explains
+    refused = dep.kind.compare(checks, dep, traffic, run) or 0
 
-    rng = np.random.default_rng(0)
-    named = np.unique(np.concatenate([b["dev"] for b in traffic.bodies]))
-    picked = rng.choice(named, min(int(config.get("sample_devices", 128)),
-                                   len(named)), replace=False)
-    expect = reference.newest_state(
-        traffic.bodies, [(int(s), int(sends.body[s])) for s in accepted],
-        traffic.ts_s_of, picked)
-    bad = []
-    for dev, doc in expect.items():
-        row = dep.state_row(dev)
-        got = {k: row[k] for k in doc}
-        if got != doc:
-            bad.append((dev, got, doc))
-    checks.check(f"state of {len(expect)} sampled devices = their newest "
-                 f"events", expect and not bad,
-                 f"{len(bad)} differ, first: {bad[:1]}")
-
-    fault = snap["device_fault"]
+    fault = dep.d.metrics_snapshot()["device_fault"]
     checks.check("breaker at 'chained' with zero trips",
                  fault["breaker"]["levelName"] == "chained"
                  and fault["breaker"]["trips"] == 0, str(fault["breaker"]))
@@ -450,11 +423,9 @@ def _compare(checks: Checks, dep: Deployment, traffic, run: Run,
     checks.equal("host_copy_errors",
                  packed.host_copy_errors - dep.copy_errors0, 0)
     checks.equal("native.build_fallbacks", native.build_fallbacks, 0)
-    checks.equal("pipeline.bytes_copied.decode (native fill-direct decode)",
-                 int(inst.metrics.counter(
-                     "pipeline.bytes_copied.decode").value), 0)
+    dep.kind.compare_intake(checks, dep, traffic, run)
     checks.equal("dead letters = sends shed by admission",
-                 int(inst.dead_letters.end_offset),
+                 int(inst.dead_letters.end_offset) - refused,
                  int((sends.status == SHED).sum()))
     checks.equal("programs compiled inside the window",
                  in_window["programs"], 0)

@@ -1,6 +1,7 @@
 """BENCHMARK.json against the letter of the contract, and every file a
 cell or a metric names."""
 
+import ast
 import json
 import os
 import re
@@ -98,6 +99,68 @@ def test_a_cells_own_file_carries_only_what_its_mix_takes(name):
         assert "rate_events_per_s" not in traffic
     else:
         assert "clients" not in traffic
+
+
+CONFIG_FILES = {c["name"]: cells.load_json(os.path.join(cells.REPO, c["file"]))
+                for c in BENCH["configs"]}
+
+
+def _kinds_in(folder: str) -> set:
+    return {f[:-3] for f in os.listdir(os.path.join(cells.HERE, folder))
+            if f.endswith(".py")}
+
+
+@pytest.mark.parametrize("config", sorted(CONFIG_FILES))
+def test_every_configuration_names_a_kind_that_supplies_the_four_things(
+        config):
+    """The fleet and its rules, the plain reference, a send's own rows
+    with the comparisons, the controls: ``cells.KIND_SUPPLIES``."""
+    doc = CONFIG_FILES[config]
+    assert NAME.match(doc["kind"])
+    assert os.path.exists(cells.kind_file(doc["kind"]))
+    kind = cells.load_kind(doc)            # raises on a missing one
+    for name in ("populate", "own_rows", "compare", "compare_intake"):
+        assert callable(getattr(kind, name)), name
+    assert kind.reference.__file__ == cells.reference_file(doc["kind"])
+    assert kind.FAULTS
+    for fault, (when, plant, must_fail) in kind.FAULTS.items():
+        assert NAME.match(fault)
+        assert when in ("before", "after") and callable(plant)
+        assert isinstance(must_fail, str) and must_fail
+
+
+def test_no_kind_is_without_a_configuration_and_no_reference_without_a_kind():
+    named = {doc["kind"] for doc in CONFIG_FILES.values()}
+    assert _kinds_in(os.path.join("configs", "kinds")) == named
+    assert _kinds_in(os.path.join("configs", "references")) == named
+
+
+@pytest.mark.parametrize("kind", sorted(
+    _kinds_in(os.path.join("configs", "references"))))
+def test_a_plain_reference_imports_nothing_of_the_program(kind):
+    with open(cells.reference_file(kind)) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+    assert imported <= {"__future__", "numpy"}, imported
+
+
+def test_the_harness_names_nothing_that_is_a_kinds():
+    """No rule family, event kind or fault, and no import of a
+    reference, in the files that serve every deployment."""
+    kinds_own = ["EventType", "ALERT", "STATE_CHANGE", "thresholds", "zones",
+                 "create_rule", "create_zone", "import reference",
+                 "cells, reference"]
+    for doc in CONFIG_FILES.values():
+        kinds_own += list(cells.load_kind(doc).FAULTS)
+    for name in ("harness.py", "deployment.py", "control.py", "run.py"):
+        with open(os.path.join(cells.HERE, name)) as f:
+            text = f.read()
+        assert [w for w in kinds_own if w in text] == [], name
 
 
 def test_metric_and_kind_files_all_belong_to_an_entry():

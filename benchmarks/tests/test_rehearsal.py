@@ -57,8 +57,6 @@ def test_traced_run_reports_the_per_layer_metrics_it_can_read():
 
 
 def test_the_seed_changes_the_inputs_and_nothing_else():
-    from benchmarks import reference
-
     picks, shapes = [], []
     for seed in (1, 2):
         _, run = toy_run(CELLS[0], seed=seed, seconds=1.0)
@@ -88,6 +86,7 @@ def test_the_seed_changes_the_inputs_and_nothing_else():
     assert a.payloads[0] == a2.payloads[0]
     assert a.interval == b.interval and a.lines == b.lines
     rule = cell["config"]["rules"]["thresholds"][0]
+    reference = cells.load_kind(cell["config"]).reference
     for t in (a, b):
         fired = sum(int(reference.fires_threshold(
             rule, x["etype"], x["value"]).sum()) for x in t.bodies)
