@@ -15,6 +15,26 @@ boundary durability lives in a host-side segmented journal:
 
 Segment format: ``[u32 len][u32 crc32][len bytes]*``.  Offsets are logical
 record indices; a sparse index maps offsets to (segment, file position).
+
+**A record may say which tenant its payload came in for** (the wire
+intake's ``tenant``: upstream's per-tenant topic).  Encoding, version 2 of
+the record, told apart record by record and not by file: bit 31 of ``len``
+set means the ``len & 0x7FFFFFFF`` body bytes are ``[u8 n][n bytes: the
+tenant's token, UTF-8][the payload]``, and the CRC is over that whole body,
+tenant included.  A record with bit 31 clear is version 1 — everything
+written before the bit existed, and today every payload of the ``default``
+tenant and every journal that is not the ingest journal (dead letters,
+spools, streams) — and reads as it always did, so old segments and old
+sidecars stay valid and a journal holds both kinds side by side.  A
+payload is under 2 GiB (a segment rotates at ``segment_bytes``), so the
+bit was never set by a version-1 writer.  A reader from before this
+encoding takes a version-2 record for a torn tail or corruption: roll
+forward, not back.  One iterator reads both versions,
+:meth:`Journal.records`: ``(offset, payload, tenant token)``, the token
+``"default"`` for a version-1 record.  :meth:`Journal.scan`,
+:meth:`Journal.read_one` and :meth:`JournalReader.poll` are that iterator
+without the token (``payload_ref`` resolution is unchanged);
+:meth:`Journal.read_record` and :meth:`JournalReader.poll_records` keep it.
 """
 
 from __future__ import annotations
@@ -29,8 +49,18 @@ import time
 import zlib
 from typing import Iterator, List, Optional, Tuple
 
-_HEADER = struct.Struct("<II")  # (length, crc32)
+_HEADER = struct.Struct("<II")  # (length | _TENANT_BIT, crc32)
+_TENANT_BIT = 1 << 31  # version 2: the body opens with the tenant's token
 _INDEX_EVERY = 64  # sparse-index granularity (records)
+DEFAULT_TENANT = "default"
+
+
+def _split_body(body: bytes, tagged: int) -> Tuple[bytes, str]:
+    """(payload, tenant token) of one record's CRC-checked body."""
+    if not tagged:
+        return body, DEFAULT_TENANT
+    n = body[0]
+    return body[1 + n:], body[1:1 + n].decode()
 
 
 class CorruptJournal(Exception):
@@ -160,6 +190,7 @@ class Journal:
                             tf.truncate(pos)
                     break
                 length, crc = _HEADER.unpack(f.read(_HEADER.size))
+                length &= ~_TENANT_BIT
                 payload = f.read(length)
                 if len(payload) < length:
                     if not truncate_tail:
@@ -185,13 +216,22 @@ class Journal:
 
     # -- producer side ------------------------------------------------------
 
-    def append(self, payload: bytes) -> int:
-        """Append one record; returns its offset."""
+    def append(self, payload: bytes, tenant: str = DEFAULT_TENANT) -> int:
+        """Append one record; returns its offset.  A ``tenant`` other
+        than ``default`` rides the record (version 2, module docstring)."""
+        tagged = 0
+        if tenant != DEFAULT_TENANT:
+            token = tenant.encode()
+            if not 0 < len(token) < 256:
+                raise ValueError(f"tenant token of {len(token)} bytes")
+            payload = bytes((len(token),)) + token + payload
+            tagged = _TENANT_BIT
         with self._append_span(), self._lock:
             offset = self._next_offset
             if offset % self.index_every == 0:
                 self._index.append((offset, self._file.name, self._file.tell()))
-            self._file.write(_HEADER.pack(len(payload), zlib.crc32(payload)))
+            self._file.write(_HEADER.pack(len(payload) | tagged,
+                                          zlib.crc32(payload)))
             self._file.write(payload)
             self._next_offset += 1
             self._unsynced += 1
@@ -264,12 +304,23 @@ class Journal:
 
     def read_one(self, offset: int) -> bytes:
         """Read the record at ``offset`` (used to resolve ``payload_ref``)."""
-        for rec_offset, payload in self.scan(offset, offset + 1):
-            return payload
+        return self.read_record(offset)[0]
+
+    def read_record(self, offset: int) -> Tuple[bytes, str]:
+        """``(payload, tenant token)`` of the record at ``offset``."""
+        for _, payload, tenant in self.records(offset, offset + 1):
+            return payload, tenant
         raise KeyError(f"offset {offset} not in journal")
 
     def scan(self, start: int, stop: Optional[int] = None) -> Iterator[Tuple[int, bytes]]:
         """Yield ``(offset, payload)`` for offsets in ``[start, stop)``."""
+        for offset, payload, _ in self.records(start, stop):
+            yield offset, payload
+
+    def records(self, start: int, stop: Optional[int] = None
+                ) -> Iterator[Tuple[int, bytes, str]]:
+        """Yield ``(offset, payload, tenant token)`` for offsets in
+        ``[start, stop)``."""
         with self._lock:
             # Make appended bytes visible to readers of the same files;
             # durability (fsync) stays on the append policy.  Segments
@@ -311,6 +362,8 @@ class Journal:
                     if len(header) < _HEADER.size:
                         break
                     length, crc = _HEADER.unpack(header)
+                    tagged = length & _TENANT_BIT
+                    length &= ~_TENANT_BIT
                     payload = f.read(length)
                     if len(payload) < length:
                         break
@@ -319,7 +372,7 @@ class Journal:
                     if offset >= start:
                         if stop is not None and offset >= stop:
                             return
-                        yield offset, payload
+                        yield (offset, *_split_body(payload, tagged))
                     offset += 1
 
 
@@ -357,8 +410,14 @@ class JournalReader:
 
     def poll(self, max_records: int) -> List[Tuple[int, bytes]]:
         """Fetch up to ``max_records`` from the current (uncommitted) position."""
+        return [(offset, payload)
+                for offset, payload, _ in self.poll_records(max_records)]
+
+    def poll_records(self, max_records: int) -> List[Tuple[int, bytes, str]]:
+        """:meth:`poll` with each record's tenant token:
+        ``(offset, payload, tenant token)``."""
         out = list(
-            self.journal.scan(self.position, self.position + max_records)
+            self.journal.records(self.position, self.position + max_records)
         )
         if out:
             self.position = out[-1][0] + 1

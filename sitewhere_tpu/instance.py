@@ -154,6 +154,7 @@ class Instance(LifecycleComponent):
         from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
         self.metrics = MetricsRegistry()
+        self.device_state.bind_metrics(self.metrics)
 
         # durable stores — the log-structured sharded segment store
         # (sitewhere_tpu/store): parallel background seal off the hot
@@ -1140,15 +1141,15 @@ class Instance(LifecycleComponent):
         return engine
 
     def _tenant_ids_of_devices(self, device_ids):
-        import numpy as np
+        # the mirror's host column: the published registry is its copy
+        # on the device, and a report must not fetch a registry-sized
+        # column back for a few hundred rows
+        return self.mirror.tenant_id[device_ids]
 
-        reg = self.mirror.publish_registry()
-        return np.asarray(reg.tenant_id)[device_ids]
-
-    def _on_presence_changes(self, batch) -> None:
-        import numpy as np
-
-        self.dispatcher.inject_batch(batch, np.asarray(batch.valid))
+    def _on_presence_changes(self, cols) -> None:
+        """Re-inject a sweep's STATE_CHANGE rows (host columns) as
+        first-class events through the columnar intake edge."""
+        self.dispatcher.ingest_arrays(**cols)
 
     def _on_command_rows(self, cols, mask, trace=None) -> None:
         """Deliver pipeline COMMAND_INVOCATION events (reference:
@@ -1729,12 +1730,18 @@ class Instance(LifecycleComponent):
             missing: List[int] = []
             for ref in doc["refs"]:
                 try:
-                    payload = self.ingest_journal.read_one(int(ref))
+                    payload, tenant = self.ingest_journal.read_record(
+                        int(ref))
                     reqs = [r for r in decoder(payload)
                             if r.event_type is not None]
                 except Exception:
                     missing.append(int(ref))
                     continue
+                if tenant != "default":
+                    # the payload's tenant rides its journal record; a
+                    # line's own metadata.tenant wins, as it does live
+                    for r in reqs:
+                        r.metadata = {"tenant": tenant, **(r.metadata or {})}
                 if reqs:
                     self.dispatcher.ingest_many(reqs, payload)
                     rows += len(reqs)

@@ -588,15 +588,21 @@ def ring_depth_default() -> int:
 
 def packed_presence_sweep(ps: PackedState, now_s, missing_after_s):
     """Presence sweep over the packed carry: reads three lanes, writes
-    one (jit with ``donate_argnums=(0,)``)."""
+    one.  The served sweep (``DeviceStateManager.apply_presence_sweep``)
+    jits it without donation — a single step reads the epoch without a
+    lease — so it also pays the carry's copy; with ``donate_argnums=
+    (0,)`` it is in place."""
     from sitewhere_tpu.state.presence import newly_missing
 
-    rows = ps.rows
-    missing = rows[:, PRESENCE_LANE] != 0
-    newly = newly_missing(
-        rows[:, _LANE["last_event_type"]], rows[:, _LANE["last_event_ts_s"]],
-        missing, now_s, missing_after_s)
-    rows = rows.at[:, PRESENCE_LANE].set((missing | newly).astype(rows.dtype))
+    with jax.named_scope("presence_sweep"):
+        rows = ps.rows
+        missing = rows[:, PRESENCE_LANE] != 0
+        newly = newly_missing(
+            rows[:, _LANE["last_event_type"]],
+            rows[:, _LANE["last_event_ts_s"]],
+            missing, now_s, missing_after_s)
+        rows = rows.at[:, PRESENCE_LANE].set(
+            (missing | newly).astype(rows.dtype))
     return ps.replace(rows=rows), newly
 
 

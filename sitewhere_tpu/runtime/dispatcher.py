@@ -94,7 +94,7 @@ from sitewhere_tpu.ingest.journal import Journal, JournalReader
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.runtime.resilience import dead_letter
-from sitewhere_tpu.schema import EventBatch, EventType, as_numpy
+from sitewhere_tpu.schema import EventType, as_numpy
 from sitewhere_tpu.store import segment as _segment_schema
 
 logger = logging.getLogger("sitewhere_tpu.dispatcher")
@@ -490,6 +490,13 @@ class PipelineDispatcher(LifecycleComponent):
             # a packed plan's per-shard placement (_stage_packed: two
             # arrays x n_shards device_puts); never observed on one chip
             self._m_stage["place"] = metrics.timer("pipeline.stage_place_s")
+        # the per-tenant fold of one egressed plan into the usage ledger
+        # (_meter_plan); observed only where metering is on
+        self._m_stage["meter"] = metrics.timer("pipeline.stage_meter_s")
+        # rows taken through the wire intake, and those of them under a
+        # tenant other than ``default`` (ingest_wire_lines' ``tenant``)
+        self._m_wire_rows = metrics.counter("ingest.wire_rows")
+        self._m_wire_rows_tenant = metrics.counter("ingest.wire_rows_tenant")
         # The host BLOCKED on the device finishing a step and on its D2H
         # (the views' blocking fetch in pipeline/packed.py): a child of
         # the egress stage, so egress self time = stage_egress_s -
@@ -871,7 +878,8 @@ class PipelineDispatcher(LifecycleComponent):
             lambda: self.batcher.add_arrays(**columns)))
 
     def ingest_wire_lines(self, payload: bytes, source_id: str = "wire",
-                          raise_on_decode_error: bool = False) -> int:
+                          raise_on_decode_error: bool = False,
+                          tenant: str = "default") -> int:
         """Columnar NDJSON wire intake: bytes → column arrays → batcher.
 
         The true 1M events/sec edge (round-2 verdict weak #2): ONE
@@ -880,6 +888,19 @@ class PipelineDispatcher(LifecycleComponent):
         every row.  Host-plane lines (registrations) take the scalar
         path; an undecodable payload dead-letters whole.  Returns the
         number of event rows accepted into the batcher.
+
+        ``tenant`` is the token of the tenant the payload came in FOR —
+        its source's, as upstream's is the Kafka topic's
+        (``…tenant.<id>.event-source-decoded-events``), not a field of
+        the line: every row is stamped with ``resolve_tenant(tenant)``,
+        admission sheds and bills under it, and the journal record keeps
+        it so that a replay lands the rows in the same tenant.  The step
+        takes a row only when that tenant owns its device; a row of
+        another tenant's device is refused ``unregistered`` and
+        dead-lettered.  A token no tenant has is not an error here, as
+        it is none on the scalar path's ``metadata.tenant``:
+        ``resolve_tenant`` mints an id that owns no device, so every row
+        is refused the same way and none is taken under ``default``.
         """
         from sitewhere_tpu.ingest.decoders import DecodeError
 
@@ -895,7 +916,7 @@ class PipelineDispatcher(LifecycleComponent):
             self.ingest_failed_decode(payload, source_id, e)
             return 0
         return self.ingest_wire_decoded(payload, columns, host_reqs,
-                                        source_id=source_id)
+                                        source_id=source_id, tenant=tenant)
 
     def decode_wire_lines(self, payload: bytes):
         """The pure DECODE stage of :meth:`ingest_wire_lines` — no
@@ -934,10 +955,13 @@ class PipelineDispatcher(LifecycleComponent):
                 self._m_bytes["decode"].inc(tally.n)
             return out
 
-    def _admit_columns(self, columns, payload: bytes, source_id: str):
+    def _admit_columns(self, columns, payload: bytes, source_id: str,
+                       tenant: str = "default"):
         """Admission-filter one decoded wire-column dict (vectorized:
         one fancy-index classifies every row, one bucket take per class
-        per payload).  Returns ``(admitted_columns, shed_classes)`` —
+        per payload) under the payload's ``tenant``: its budget overlay
+        clips it, its shed counter and ledger are billed.  Returns
+        ``(admitted_columns, shed_classes)`` —
         columns may be the input unchanged, or None for zero admitted
         rows; dead-letters sheds; raising is the CALLER's decision
         (host-plane lines may still make the payload partially
@@ -971,14 +995,14 @@ class PipelineDispatcher(LifecycleComponent):
             count = int(m.sum())
             if count:
                 ok, reason = self.overload.admit_detail(
-                    cls, source=source_id, n=count)
+                    cls, tenant=tenant, source=source_id, n=count)
                 if not ok:
                     keep &= ~m
                     shed[cls] = count
                     budget_bound = budget_bound or reason == "budget"
         if not shed:
             return columns, shed
-        self._shed_intake(payload, shed, source_id, "default",
+        self._shed_intake(payload, shed, source_id, tenant,
                           budget_bound=budget_bound)
         if not keep.any():
             return None, shed
@@ -999,18 +1023,22 @@ class PipelineDispatcher(LifecycleComponent):
                 shed)
 
     def ingest_wire_decoded(self, payload: bytes, columns,
-                            host_reqs, source_id: str = "wire") -> int:
+                            host_reqs, source_id: str = "wire",
+                            tenant: str = "default") -> int:
         """The ordered INGEST tail of :meth:`ingest_wire_lines`: journal
-        once, route host-plane lines, resolve + batch the event rows.
+        once (with the payload's ``tenant``), route host-plane lines,
+        resolve + batch the event rows under that tenant.
         Must run in per-source submission order (the decode pool's
         delivery contract) so per-device event order and the journal's
         offset↔row correspondence are preserved."""
         from sitewhere_tpu.ingest.batcher import Reservation
 
         if isinstance(columns, Reservation):
-            return self._ingest_reserved(payload, columns, source_id)
+            return self._ingest_reserved(payload, columns, source_id,
+                                         tenant)
         if self.overload is not None:
-            columns, shed = self._admit_columns(columns, payload, source_id)
+            columns, shed = self._admit_columns(columns, payload, source_id,
+                                                tenant)
             if columns is None:
                 if host_reqs:
                     columns = {}   # host-plane lines still route below
@@ -1022,13 +1050,17 @@ class PipelineDispatcher(LifecycleComponent):
         # Decode validated the payload — journal once (at-least-once).
         ref = NULL_ID
         if self.journal is not None and payload:
-            ref = self.journal.append(payload)
+            ref = self.journal.append(payload, tenant=tenant)
             # chaos kill point: journaled, never batched — the record is
             # the durable truth and MUST reappear via replay
             faults.crosspoint("crash.post_journal")
         from sitewhere_tpu.ingest.decoders import RequestKind
 
         for req in host_reqs:
+            if tenant != "default":
+                # a host-plane line of a tenant's payload is that
+                # tenant's, like its event rows
+                req.metadata = {"tenant": tenant, **(req.metadata or {})}
             if req.kind == RequestKind.REGISTRATION:
                 self.ingest_registration(req, b"")
             elif self.on_host_request is not None:
@@ -1046,42 +1078,54 @@ class PipelineDispatcher(LifecycleComponent):
                 })
         if not columns:
             return 0   # every event row was shed; host-plane lines routed
-        return self._ingest_resolved_columns(columns, ref)
+        return self._ingest_resolved_columns(columns, ref, tenant)
 
-    def _ingest_reserved(self, payload: bytes, res, source_id: str) -> int:
+    def _ingest_reserved(self, payload: bytes, res, source_id: str,
+                         tenant: str = "default") -> int:
         """Ordered ingest tail of the fill-direct path: admission, ONE
         journal append, the per-payload constants, then commit under the
         intake lock.  Every scanned row is a MEASUREMENT (the resolved
         scanner accepts nothing else), so admission is exactly the
         whole-payload TELEMETRY decision the vector path would make —
-        same audit record, same backpressure exception."""
+        same audit record, same backpressure exception, under the
+        payload's ``tenant``."""
         n = res.n
         if self.overload is not None:
             from sitewhere_tpu.runtime.overload import PriorityClass
 
             ok, reason = self.overload.admit_detail(
-                PriorityClass.TELEMETRY, source=source_id, n=n)
+                PriorityClass.TELEMETRY, tenant=tenant, source=source_id,
+                n=n)
             if not ok:
                 res.abort()
                 self._shed_intake(payload, {PriorityClass.TELEMETRY: n},
-                                  source_id, "default",
+                                  source_id, tenant,
                                   budget_bound=reason == "budget")
                 raise self.overload.shed_exception(PriorityClass.TELEMETRY)
         ref = NULL_ID
         if self.journal is not None and payload:
-            ref = self.journal.append(payload)
+            ref = self.journal.append(payload, tenant=tenant)
             # chaos kill point: same contract as ingest_wire_decoded's
             faults.crosspoint("crash.post_journal")
-        res.set_const(tenant_id=self.resolve_tenant("default"),
+        res.set_const(tenant_id=self.resolve_tenant(tenant),
                       payload_ref=ref)
+        self._count_wire_rows(n, tenant)
         self._run_plans(self._take(res.commit))
         return n
 
-    def _ingest_resolved_columns(self, columns, ref: int) -> int:
+    def _count_wire_rows(self, n: int, tenant: str) -> None:
+        self._m_wire_rows.inc(n)
+        if tenant != "default":
+            self._m_wire_rows_tenant.inc(n)
+
+    def _ingest_resolved_columns(self, columns, ref: int,
+                                 tenant: str = "default") -> int:
         """Resolve one decoded column dict and queue its rows (shared by
         live wire intake and columnar journal replay — replay's
         equivalence argument depends on both using THIS code: rows get
-        ``ref`` as payload_ref and land in the default tenant)."""
+        ``ref`` as payload_ref and land in ``tenant``, which the live
+        intake takes from its caller and the replay from the journal
+        record)."""
         from sitewhere_tpu.ingest.columnar import n_rows, resolve_columns
 
         n = n_rows(columns)
@@ -1096,7 +1140,8 @@ class PipelineDispatcher(LifecycleComponent):
         )
         cols["payload_ref"] = np.full(n, ref, np.int32)
         cols["tenant_id"] = np.full(
-            n, self.resolve_tenant("default"), np.int32)
+            n, self.resolve_tenant(tenant), np.int32)
+        self._count_wire_rows(n, tenant)
         self._run_plans(self._take(
             lambda: self.batcher.add_arrays(_copy=False, **cols)))
         return n
@@ -1404,7 +1449,10 @@ class PipelineDispatcher(LifecycleComponent):
         # anything they accept is bit-identical under both paths — the
         # scalar decoder keeps handling everything else, including
         # per-request metadata tenants).  A custom recovery decoder
-        # disables the fast path outright.
+        # disables the fast path outright.  Either way a row lands in
+        # the tenant its payload was accepted under: the journal record
+        # keeps the wire intake's ``tenant`` (a line's own
+        # ``metadata.tenant`` still wins, as it does live).
         use_columnar = decoder is None and self.recovery_decoder is None
         decoder = decoder or self.recovery_decoder or JsonLinesDecoder()
         start = reader.committed
@@ -1418,15 +1466,15 @@ class PipelineDispatcher(LifecycleComponent):
         n = 0
         done = False
         while not done:
-            records = reader.poll(max_records)
+            records = reader.poll_records(max_records)
             if not records:
                 break
-            for offset, payload in records:
+            for offset, payload, tenant in records:
                 if upto is not None and offset >= upto:
                     done = True
                     break
                 if use_columnar:
-                    fast = self._replay_columnar(payload, offset)
+                    fast = self._replay_columnar(payload, offset, tenant)
                     if fast is not None:
                         n += fast
                         continue
@@ -1439,8 +1487,8 @@ class PipelineDispatcher(LifecycleComponent):
                 if not events:
                     continue
                 tenants = [
-                    self.resolve_tenant(r.metadata.get("tenant", "default")
-                                        if r.metadata else "default")
+                    self.resolve_tenant(r.metadata.get("tenant", tenant)
+                                        if r.metadata else tenant)
                     for r in events
                 ]
                 self._run_plans(self._take(
@@ -1462,14 +1510,18 @@ class PipelineDispatcher(LifecycleComponent):
             self.store_dedup_floor = 0
         return n
 
-    def _replay_columnar(self, payload: bytes, offset: int) -> Optional[int]:
+    def _replay_columnar(self, payload: bytes, offset: int,
+                         tenant: str = "default") -> Optional[int]:
         """Replay one journal record through the C columnar lane, or
         None when the STRICT measurement scanner doesn't accept it —
         the caller falls back to the scalar decoder.  Only the
         measurement scanner qualifies: it bails on ANY unknown request
-        key, so a payload it accepts carries no ``metadata`` and the
-        scalar decoder would produce bit-identical rows (default
-        tenant, no alternate ids).  The family scanner is deliberately
+        key, so a payload it accepts carries no ``metadata`` and every
+        row's tenant is the record's, ``tenant`` — the same the scalar
+        decoder's rows get, so the two replays produce bit-identical
+        rows (the record's tenant, no alternate ids), and the same the
+        live intake stamped (``_ingest_resolved_columns`` is shared).
+        The family scanner is deliberately
         NOT used here — it skips unknown request keys, so it would
         accept a metadata-carrying payload and silently drop the
         per-request tenant the scalar replay honors.  Rows keep the
@@ -1498,7 +1550,7 @@ class PipelineDispatcher(LifecycleComponent):
         if out is None:
             return None
         columns, _host = out
-        return self._ingest_resolved_columns(columns, offset)
+        return self._ingest_resolved_columns(columns, offset, tenant)
 
     # -- one step -----------------------------------------------------------
 
@@ -2591,7 +2643,8 @@ class PipelineDispatcher(LifecycleComponent):
         # Tenant metering: fold the device-side per-tenant scatter block
         # (same fetched vector — zero extra syncs) into the usage ledger
         if self.usage_ledger is not None:
-            self._meter_plan(out, host_cols)
+            with self._m_stage["meter"].time(seq=plan.seq):
+                self._meter_plan(out, host_cols)
         # monotonic receive time of the plan's oldest row — the watermark
         # the per-stage ingest→seal / ingest→ack gauges measure from
         ingest_t0 = plan.created_at - plan.max_wait_s
@@ -2794,28 +2847,48 @@ class PipelineDispatcher(LifecycleComponent):
                 detail=f"devices {[d for d, _ in newly]} crossed "
                        f"{self.quarantine_after} nonfinite rows")
         if replay_depth < self.max_replay_depth:
-            import jax.numpy as jnp
-
             from sitewhere_tpu.state.presence import (
                 STATE_CHANGE_QUARANTINED,
-                state_changes_for,
+                state_change_columns,
             )
 
-            n = len(newly)
-            batch = state_changes_for(
+            cols = state_change_columns(
                 np.asarray([d for d, _ in newly], np.int32),
                 np.asarray([t for _, t in newly], np.int32),
-                int(time.time()))
-            batch = batch.replace(
-                alert_code=jnp.full(n, STATE_CHANGE_QUARANTINED,
-                                    jnp.int32))
-            self.inject_batch(batch, np.ones(n, dtype=bool),
-                              replay_depth + 1)
+                int(time.time()), code=STATE_CHANGE_QUARANTINED)
+            self._run_plans(self._take(
+                lambda: self.batcher.add_arrays(_copy=False, **cols)),
+                replay_depth + 1)
 
     def _handle_unregistered(self, host_cols, out, replay_depth: int) -> None:
+        """Rows the step refused as ``unregistered``: the default
+        tenant's go to the registration manager (auto-register, replay;
+        what it turns down is dropped with its warning, as before); what
+        cannot replay, and every row that came in for a tenant other
+        than ``default``, dead-letters."""
         mask = np.asarray(out.unregistered)
         if not mask.any():
             return
+        # The registration manager registers into the DEFAULT tenant's
+        # device management.  A row that came in for another tenant (its
+        # source's, or its line's metadata) is never its business: the
+        # device is unknown to that tenant or another tenant's, and
+        # either way the row is refused for good — dead-lettered, never
+        # re-registered under tenant 0 and replayed there.
+        foreign = mask & (np.asarray(host_cols["tenant_id"])
+                          != self.resolve_tenant("default"))
+        if foreign.any():
+            if self.dead_letters is not None:
+                dead_letter(self.dead_letters, {
+                    "kind": "unregistered", "count": int(foreign.sum()),
+                    "tenant_ids": np.unique(
+                        host_cols["tenant_id"][foreign]).tolist(),
+                    "refs": [int(r) for r in np.unique(
+                        host_cols["payload_ref"][foreign])
+                        if int(r) != NULL_ID]})
+            mask = mask & ~foreign
+            if not mask.any():
+                return
         refs = host_cols["payload_ref"][mask]
         requests: List[DecodedRequest] = []
         unreplayable: List[int] = []
@@ -2905,23 +2978,6 @@ class PipelineDispatcher(LifecycleComponent):
         self._run_plans(self._take(
             lambda: self.batcher.add_arrays(_copy=False, **cols)),
             replay_depth + 1)
-
-    def inject_batch(self, batch: EventBatch, mask: np.ndarray,
-                     replay_depth: int = 0) -> None:
-        """Re-inject an already-dense event batch (derived alerts, presence
-        STATE_CHANGEs) through the pipeline as first-class events —
-        columnar: one mask-select per field, no per-row work."""
-        from sitewhere_tpu.ingest.batcher import _COL_FIELDS
-
-        host = as_numpy(batch)
-        rows = np.nonzero(np.asarray(mask))[0]
-        if rows.size == 0:
-            return
-        cols = {f: np.asarray(getattr(host, f))[rows] for f in _COL_FIELDS}
-        # fancy-indexed gathers above are fresh arrays — skip the copy
-        self._run_plans(self._take(
-            lambda: self.batcher.add_arrays(_copy=False, **cols)),
-            replay_depth)
 
     def inject_rule_alerts(self, cols: Dict[str, np.ndarray]) -> int:
         """Re-inject fired tenant-program alerts as first-class ALERT

@@ -15,6 +15,7 @@ resulting indices/rows copied back.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 from typing import Dict, List, Optional
@@ -27,7 +28,10 @@ from sitewhere_tpu.ids import NULL_ID, IdentityMap
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.schema import DeviceState, EventBatch
 from sitewhere_tpu.services.common import EntityNotFound, require
-from sitewhere_tpu.state.presence import presence_sweep, state_changes_for
+from sitewhere_tpu.state.presence import (
+    presence_sweep,
+    state_change_columns,
+)
 
 
 def _next_pow2(n: int) -> int:
@@ -194,6 +198,21 @@ def _packed_codecs():
     return _PACK, _UNPACK
 
 
+def _packed_sweep():
+    """Module-level jitted :func:`packed_presence_sweep` (lazy import, as
+    above).  NOT donated: a single step reads the packed epoch without a
+    lease (``dispatcher._step_packed``), so the manager never owns the
+    buffer alone and a donating sweep could delete it between a step's
+    read and its dispatch.  The sweep pays one carry copy instead, as the
+    single step does (ROADMAP S8 gives both the lease)."""
+    global _SWEEP
+    if "_SWEEP" not in globals():
+        from sitewhere_tpu.pipeline.packed import packed_presence_sweep
+
+        _SWEEP = jax.jit(packed_presence_sweep)
+    return _SWEEP
+
+
 @jax.jit
 def _merge_presence(new_rows, cur_rows, present_now):
     """Packed-form presence reconciliation (see :meth:`commit` docstring):
@@ -224,6 +243,10 @@ class DeviceStateManager(LifecycleComponent):
         num_ewma_scales: int = 3,
     ):
         super().__init__(name="device-state-manager")
+        from sitewhere_tpu.runtime.metrics import MetricsRegistry
+
+        # a registry of its own until the instance binds its own
+        self.bind_metrics(MetricsRegistry())
         self.identity = identity
         self._lock = threading.RLock()
         self._state: Optional[DeviceState] = DeviceState.empty(
@@ -244,6 +267,19 @@ class DeviceStateManager(LifecycleComponent):
         # pow2 rung ladders over the shared tensors, so one tenant's
         # registration churn recompiles only its own partition view
         self.partitions: Optional[TenantPartitions] = None
+
+    def bind_metrics(self, metrics) -> None:
+        """The presence sweep's instruments (a ``Timer.time()`` region
+        is a profiler span of the timer's name): ``presence.sweep_s`` is
+        the whole of :meth:`apply_presence_sweep`,
+        ``presence.sweep_device_s`` the sweep program from its dispatch
+        until its outputs are ready (before the mask's D2H);
+        ``presence.sweeps`` counts sweeps, ``presence.reported`` the rows
+        handed on for re-injection."""
+        self._m_sweep = metrics.timer("presence.sweep_s")
+        self._m_sweep_device = metrics.timer("presence.sweep_device_s")
+        self._m_sweeps = metrics.counter("presence.sweeps")
+        self._m_reported = metrics.counter("presence.reported")
 
     def attach_partitions(self, tenant_column_provider,
                           min_capacity: int = 64,
@@ -411,24 +447,71 @@ class DeviceStateManager(LifecycleComponent):
 
     def apply_presence_sweep(
         self, now_s: int, missing_after_s: int
-    ) -> Optional[EventBatch]:
-        """Run the jitted sweep, adopt the flagged state, and build the
-        STATE_CHANGE batch for newly-missing devices (None if none)."""
+    ) -> Optional[Dict[str, np.ndarray]]:
+        """Run the sweep, adopt the flagged epoch, and build the report:
+        the STATE_CHANGE rows of the newly-missing devices as host
+        columns for ``ingest_arrays`` (None if none).
+
+        Which lane: where the manager holds the packed epoch (every
+        served deployment between two steps) the sweep runs ON it —
+        :func:`packed_presence_sweep` reads three lanes of the carry and
+        writes one — and its output IS the next packed epoch: ``_packed``
+        is kept, the unpacked twin is dropped (``current`` unpacks lazily
+        if a query wants it), so the step after a sweep reads the carry
+        the sweep wrote and nothing is unpacked or re-packed.  A step
+        that read the epoch before the sweep and commits after it finds
+        ``_packed`` another object and merges the sweep's flags in
+        (:meth:`commit_packed`).  Where only the unpacked form is held —
+        an unpacked deployment's ``commit``, or a chain holding the
+        packed lease (the twin then IS the held epoch) — the unpacked
+        :func:`presence_sweep` runs on it as before.
+
+        The report is numpy from the mask on: no program depends on how
+        many devices went silent, so none is compiled for a new count.
+        """
+        with self._m_sweep.time(), contextlib.ExitStack() as device:
+            with self._lock:
+                # the device span opens once the lock is held
+                device.enter_context(self._m_sweep_device.time())
+                if self._packed is not None:
+                    self._packed, newly = _packed_sweep()(
+                        self._packed, jnp.int32(now_s),
+                        jnp.int32(missing_after_s))
+                    self._state = None
+                else:
+                    self._state, newly = presence_sweep(
+                        self.current, jnp.int32(now_s),
+                        jnp.int32(missing_after_s))
+            # outside the lock (a commit must not wait for the chip), and
+            # on the mask, which no later chain can donate
+            jax.block_until_ready(newly)
+            device.close()
+            self._m_sweeps.inc()
+            (idx,) = np.nonzero(np.asarray(newly))
+            if idx.size == 0:
+                return None
+            idx = idx.astype(np.int32)
+            if self._tenant_id_of_device is not None:
+                tenant_ids = np.asarray(
+                    self._tenant_id_of_device(idx), np.int32)
+            else:
+                tenant_ids = np.zeros(idx.size, np.int32)
+            self._m_reported.inc(int(idx.size))
+            return state_change_columns(idx, tenant_ids, now_s)
+
+    def warm_presence_programs(self) -> None:
+        """Compile (or load from the cache) what a served sweep runs on
+        the packed epoch — the sweep itself and the merge a step makes
+        when a sweep overtook it — on the live carry, dropping the
+        outputs: nothing is adopted, so nothing changes.  Called at
+        start so the first sweep in service compiles nothing."""
         with self._lock:
-            new_state, newly_missing = presence_sweep(
-                self.current, jnp.int32(now_s), jnp.int32(missing_after_s)
-            )
-            self._state = new_state
-            self._packed = None
-        (idx,) = np.nonzero(np.asarray(newly_missing))
-        if idx.size == 0:
-            return None
-        idx = idx.astype(np.int32)
-        if self._tenant_id_of_device is not None:
-            tenant_ids = np.asarray(self._tenant_id_of_device(idx), np.int32)
-        else:
-            tenant_ids = np.zeros(idx.size, np.int32)
-        return state_changes_for(idx, tenant_ids, now_s)
+            packed = self.current_packed
+        # [1]: the swept carry is dropped at once, so the warm-up never
+        # holds more than the epoch and one copy of it on the device
+        newly = _packed_sweep()(packed, jnp.int32(0), jnp.int32(0))[1]
+        jax.block_until_ready(
+            _merge_presence(packed.rows, packed.rows, newly))
 
     # -- queries (reference: DeviceStateImpl RPCs) --------------------------
 

@@ -18,15 +18,14 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from typing import Callable, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
-from sitewhere_tpu.schema import DeviceState, EventBatch, EventType
+from sitewhere_tpu.schema import DeviceState, EventType
 
 logger = logging.getLogger("sitewhere_tpu.state.presence")
 
@@ -64,37 +63,37 @@ def presence_sweep(
     return state.replace(presence_missing=state.presence_missing | newly), newly
 
 
-def state_changes_for(
-    device_ids: np.ndarray, tenant_ids: np.ndarray, now_s: int
-) -> EventBatch:
-    """Build a presence STATE_CHANGE event batch for the given devices.
-
-    Host-side (variable count → exact-width batch) — re-injected through
-    the normal ingest path like the reference's presence StateChange events
-    flow back through event management.  ``tenant_ids`` aligns with
-    ``device_ids`` row for row.
+def state_change_columns(
+    device_ids: np.ndarray, tenant_ids: np.ndarray, now_s: int,
+    code: int = STATE_CHANGE_PRESENCE_MISSING,
+) -> Dict[str, np.ndarray]:
+    """The STATE_CHANGE rows of the given devices as host columns for
+    the ``ingest_arrays`` edge — re-injected through the normal ingest
+    path like the reference's presence StateChange events flow back
+    through event management.  ``tenant_ids`` aligns with ``device_ids``
+    row for row.  Plain numpy, whatever the count: a program built at
+    the count's length would compile anew for every new count.
     """
-    width = int(device_ids.size)
-    batch = EventBatch.empty(width)
-    return batch.replace(
-        valid=jnp.ones(width, bool),
-        device_id=jnp.asarray(np.asarray(device_ids, np.int32)),
-        tenant_id=jnp.asarray(np.asarray(tenant_ids, np.int32)),
-        event_type=jnp.full(width, EventType.STATE_CHANGE, jnp.int32),
-        ts_s=jnp.full(width, now_s, jnp.int32),
-        alert_code=jnp.full(width, STATE_CHANGE_PRESENCE_MISSING, jnp.int32),
+    n = int(np.size(device_ids))
+    return {
+        "device_id": np.asarray(device_ids, np.int32),
+        "tenant_id": np.asarray(tenant_ids, np.int32),
+        "event_type": np.full(n, int(EventType.STATE_CHANGE), np.int32),
+        "ts_s": np.full(n, int(now_s), np.int32),
+        "alert_code": np.full(n, int(code), np.int32),
         # System-generated: must not mark the device present or bump its
         # last-event time (reference isUpdateState() semantics).
-        update_state=jnp.zeros(width, bool),
-    )
+        "update_state": np.zeros(n, bool),
+    }
 
 
 class PresenceManager(LifecycleComponent):
     """Background presence checker over a :class:`DeviceStateManager`.
 
-    ``on_state_changes`` receives the STATE_CHANGE :class:`EventBatch` for
-    each sweep that found newly-missing devices (the notification-strategy
-    hook); wire it to the ingest path for re-injection.
+    ``on_state_changes`` receives the STATE_CHANGE rows (host columns,
+    :func:`state_change_columns`) of each sweep that found newly-missing
+    devices (the notification-strategy hook); wire it to the ingest path
+    (``ingest_arrays``) for re-injection.
     """
 
     def __init__(
@@ -102,7 +101,7 @@ class PresenceManager(LifecycleComponent):
         state_manager,  # DeviceStateManager
         check_interval_s: float = 600.0,  # reference default "10m"
         missing_after_s: int = 8 * 3600,  # reference default "8h"
-        on_state_changes: Optional[Callable[[EventBatch], None]] = None,
+        on_state_changes: Optional[Callable[[Dict[str, np.ndarray]], None]] = None,
         clock: Optional[Callable[[], float]] = None,
     ):
         super().__init__(name="presence-manager")
@@ -115,6 +114,9 @@ class PresenceManager(LifecycleComponent):
         self._thread: Optional[threading.Thread] = None
         self.sweeps = 0
         self.total_marked_missing = 0
+        # the second the newest COMPLETED sweep judged overdue against
+        # (None until one ended): a device overdue by then is reported
+        self.last_sweep_s: Optional[int] = None
 
     def sweep_once(self, now_s: Optional[int] = None) -> int:
         """Run one sweep; returns how many devices were newly marked.
@@ -123,14 +125,15 @@ class PresenceManager(LifecycleComponent):
         """
         now = int(self._clock()) if now_s is None else now_s
         marked = self.state_manager.apply_presence_sweep(now, self.missing_after_s)
-        self.sweeps += 1
+        count = 0
         if marked is not None:
-            count = int(marked.valid.sum())
+            count = len(marked["device_id"])
             self.total_marked_missing += count
             if self.on_state_changes is not None:
                 self.on_state_changes(marked)
-            return count
-        return 0
+        self.sweeps += 1
+        self.last_sweep_s = now
+        return count
 
     def _loop(self) -> None:
         while not self._stop.wait(self.check_interval_s):
@@ -142,6 +145,14 @@ class PresenceManager(LifecycleComponent):
     def start(self) -> None:
         super().start()
         self._stop.clear()
+        # what a served sweep runs is compiled (or loaded) here, not by
+        # the first sweep in service; best-effort, like the dispatcher's
+        # warm-up: a failure only defers the compile
+        try:
+            self.state_manager.warm_presence_programs()
+        except Exception:
+            logger.warning("presence warm-up failed (compile deferred to "
+                           "the first sweep)", exc_info=True)
         self._thread = threading.Thread(
             target=self._loop, name="presence-checker", daemon=True
         )

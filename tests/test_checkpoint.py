@@ -352,8 +352,8 @@ def test_replay_columnar_fast_path_matches_scalar_semantics(tmp_path):
 
     orig = PipelineDispatcher._replay_columnar
 
-    def counting(self, payload, offset):
-        out = orig(self, payload, offset)
+    def counting(self, payload, offset, *tenant):
+        out = orig(self, payload, offset, *tenant)
         if out is not None:
             calls["fast"] += 1
         return out

@@ -82,9 +82,12 @@ class TestPresenceSweep:
         # dev-0 is 59k stale (> 30k) → missing; dev-1 is 10k stale → present.
         assert manager.missing_device_ids() == [0]
         assert batch is not None
-        ids = np.asarray(batch.device_id)[np.asarray(batch.valid)]
+        ids = batch["device_id"]
         assert list(ids) == [0]
-        assert int(np.asarray(batch.event_type)[0]) == EventType.STATE_CHANGE
+        assert int(batch["event_type"][0]) == EventType.STATE_CHANGE
+        # the report is host columns: no program is built at its length
+        assert all(type(col) is np.ndarray for col in batch.values())
+        assert not batch["update_state"].any()
 
     def test_devices_without_events_ignored(self, manager):
         batch = manager.apply_presence_sweep(now_s=10**9, missing_after_s=1)
@@ -150,7 +153,7 @@ class TestPresenceManager:
         # run_step's registry uses tenant 0; the emission callback uses the
         # injected mapping (tenant 3) — verifying the hook is honored.
         batch = mgr.apply_presence_sweep(50_000, 30_000)
-        assert int(np.asarray(batch.tenant_id)[0]) == 3
+        assert int(batch["tenant_id"][0]) == 3
 
 
 def test_presence_sweep_is_jittable_and_pure():

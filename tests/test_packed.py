@@ -544,7 +544,8 @@ def test_dispatcher_serves_packed_plans_only(tmp_path, n_shards):
             assert plan._batch is None   # nothing served reads plan.batch
         snap = inst.metrics.snapshot()
         stages = {"decode", "batch", "dispatch", "egress", "ring_wait",
-                  "ring_dispatch", "dispatch_wait", "inflight_wait"}
+                  "ring_dispatch", "dispatch_wait", "inflight_wait",
+                  "meter"}
         if n_shards > 1:
             stages.add("place")
         assert {n for n in snap["timers"]

@@ -429,22 +429,34 @@ class TestTenantBudgetDeadLetter:
             tenants={"t-noisy": {"overload": {
                 "degraded_telemetry_rate_per_s": 0.0,
                 "degraded_telemetry_burst": 0.0}}},
-            # refresh every admit: budget changes reprice immediately
-            overload={"budget_refresh_s": 0.0}))
+            # refresh every admit: budget changes reprice immediately.
+            # The uniform DEGRADED bucket is out of the way (1e9/s): a
+            # bucket refilled from empty grants by elapsed time, and at
+            # the shipped 10,000/s the three replayed rows need 0.3 ms
+            # between the refused replay and the granted one
+            overload={"budget_refresh_s": 0.0,
+                      "degraded_telemetry_rate_per_s": 1e9,
+                      "degraded_telemetry_burst": 1e9}))
         inst.start()
         try:
-            inst.device_management.create_device_type(token="sensor",
-                                                      name="Sensor")
-            inst.device_management.create_device(token="d-0",
-                                                 device_type="sensor")
-            inst.device_management.create_device_assignment(device="d-0")
+            # each tenant owns the device its events name: a row that
+            # claims another tenant than its device's owner is refused
+            # and dead-lettered, never taken under the owner instead
+            for tenant, token in (("t-quiet", "q-0"), ("t-noisy", "n-0")):
+                inst.tenants.create_tenant(
+                    token=tenant, name=tenant,
+                    auth_token=f"{tenant}-auth-token-123")
+                dm = inst.engines.get_engine(tenant).device_management
+                dm.create_device_type(token="sensor", name="Sensor")
+                dm.create_device(token=token, device_type="sensor")
+                dm.create_device_assignment(device=token)
             inst.overload.force(OverloadState.DEGRADED)
 
             # quiet tenant sails through DEGRADED on the uniform bucket
-            qp, qreqs = self._decoded(inst, "d-0", "t-quiet", 2)
+            qp, qreqs = self._decoded(inst, "q-0", "t-quiet", 2)
             inst.dispatcher.ingest_many(qreqs, qp, "src-q")
 
-            payload, reqs = self._decoded(inst, "d-0", "t-noisy", 3)
+            payload, reqs = self._decoded(inst, "n-0", "t-noisy", 3)
             with pytest.raises(OverloadShed):
                 inst.dispatcher.ingest_many(reqs, payload, "src-n")
             letters = [d for d in inst.list_dead_letters(limit=50)
