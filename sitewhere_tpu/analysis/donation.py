@@ -10,8 +10,9 @@ at worst silent corruption (the exact failure mode
   a variable passed in a donated position.  Donating callables are
   recognized from ``jax.jit(f, donate_argnums=(...))`` bindings in the
   same function, from configured constructors (``build_packed_chain``
-  donates argument 1 of the callable it returns unless built with a
-  literal ``donate=False``), and from configured parameter names
+  and the single step's ``build_packed_step`` donate argument 1 of the
+  callable they return unless built with a literal ``donate=False``),
+  and from configured parameter names
   (a parameter named ``chain`` is assumed donating at position 1 — the
   dispatcher's hand-off convention).
 - lease/commit: after ``commit_packed(..., lease_token=token)`` closes
@@ -49,6 +50,9 @@ PASS_ID = "donation"
 DEFAULT_DONATING_BUILDERS: Dict[str, Tuple[int, Optional[str]]] = {
     "build_packed_chain": (1, "donate"),
     "build_sharded_step": (1, "donate"),
+    # the single step, donating under DeviceStateManager.step_packed
+    "build_packed_step": (1, "donate"),
+    "build_sharded_packed_step": (1, "donate"),
     "_ring_chain": (1, None),   # dispatcher accessor over the chain cache
 }
 # parameters assumed to BE donating callables: param name -> donated idx

@@ -75,6 +75,9 @@ FAMILIES: Dict[str, Optional[Set[str]]] = {
     # wire_narrow_step_share reads BY NAME — closed and memberless, so no
     # suffix can split the series
     "pipeline.steps_narrow": set(),
+    # single steps dispatched with the state carry donated (dispatcher
+    # _step_epoch): wire_donated_step_share reads it BY NAME, as above
+    "pipeline.steps_donated": set(),
     "native": {"build_fallbacks"},
     # crash-recovery surface (runtime/checkpoint.py + Instance.start):
     # restore wall time, replayed-event count, replay wall time — the

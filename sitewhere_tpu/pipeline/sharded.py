@@ -151,7 +151,7 @@ def _local_packed_step(tables, ps, bi, bf):
     return packed_pipeline_step(tables, ps, bi, bf)
 
 
-def build_sharded_packed_step(mesh: Mesh):
+def build_sharded_packed_step(mesh: Mesh, donate: bool = False):
     """The packed interface over the mesh (the multi-chip deployment
     form): same local-step semantics as :func:`build_sharded_step`, but
     the per-step host surface is the packed buffer set — batch crosses
@@ -159,8 +159,11 @@ def build_sharded_packed_step(mesh: Mesh):
     buffer of device rows sharded on axis 0, outputs as one ``[10, B]``
     block + psum-ed metrics.  Per-call placement cost on a mesh scales
     with buffer count × hosts, so this is the packed step's ~10× buffer
-    reduction where it matters most.  NO donation: the carry is the
-    state manager's live epoch.
+    reduction where it matters most.  ``donate=True`` donates the
+    state carry (each shard scatters into its block in place): the
+    dispatcher hands it the state manager's epoch under the manager's
+    lock (``DeviceStateManager.step_packed``), as the ring hands the
+    chain its lease.
     """
     in_specs = (_packed_tables_specs(), _PACKED_STATE_SPEC,
                 _PACKED_BATCH_SPEC, _PACKED_BATCH_SPEC)
@@ -178,7 +181,7 @@ def build_sharded_packed_step(mesh: Mesh):
         local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return jax.jit(mapped, donate_argnums=(1,) if donate else ())
 
 
 def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
@@ -203,9 +206,7 @@ def build_sharded_packed_chain(mesh: Mesh, k: int, donate: bool = True):
     Returns ``(ps', ois [K, 10, B], metrics [K, n], present [D])`` with
     ``ois`` width-sharded, metrics replicated, ``present`` block-sharded
     by capacity.  ``donate=True`` donates the state carry: the mesh ring
-    runs on a ``DeviceStateManager.lease_packed`` exclusive hand-off, so
-    unlike :func:`build_sharded_packed_step` (which steps the live
-    epoch) the chain may consume its input buffer.
+    runs on a ``DeviceStateManager.lease_packed`` exclusive hand-off.
     """
     from sitewhere_tpu.pipeline.packed import chain_over_slots
 

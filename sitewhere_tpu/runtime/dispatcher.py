@@ -77,6 +77,7 @@ from __future__ import annotations
 
 import collections
 import collections.abc
+import functools
 import logging
 import os
 import threading
@@ -287,8 +288,12 @@ class PipelineDispatcher(LifecycleComponent):
         # deliberately bypass it — already-journaled work is never shed.
         self.overload = overload
         self.max_replay_depth = max_replay_depth
-        # No donation of `state`: DeviceStateManager.commit's sweep-merge
-        # and concurrent readers still reference the previous epoch.
+        # Donate the step's carry only where donation is real: the state
+        # manager hands its epoch over under its lock (step_packed for a
+        # single step, lease_packed for the ring's chain), so no reader
+        # can hold the buffers a donating program deletes.  Off on the
+        # CPU backend, where a carry is cheap to copy.
+        self._ring_donate = jax.default_backend() != "cpu"
         self.mesh = mesh
         if mesh is not None:
             # Multi-chip: shard_map step over the mesh (Kafka-partitioning
@@ -298,23 +303,23 @@ class PipelineDispatcher(LifecycleComponent):
             # scales with buffer count × hosts (build_sharded_packed_step).
             from sitewhere_tpu.pipeline.sharded import (
                 build_sharded_packed_step,
+                place_packed_state,
             )
 
-            self._packed_step = build_sharded_packed_step(mesh)
+            self._packed_step = build_sharded_packed_step(
+                mesh, donate=self._ring_donate)
+            # lays an epoch out on the mesh before a step or a chain
+            self._place_state = functools.partial(place_packed_state, mesh)
         else:
             # Single chip: the packed step moves ~11 buffers per call
             # instead of ~110 — per-call dispatch scales with buffer
-            # count (pipeline/packed.py).  NO donation: the carry passed
-            # in is the state manager's LIVE epoch — donating it
-            # would leave concurrent readers (checkpointer, presence
-            # sweep, REST queries) holding deleted buffers until
-            # commit_packed lands.  Donation is for private carries
-            # (the ring's leased chain); here the step scatters into a
-            # copy of the carry, the one registry-sized move it makes
-            # (PERF.md §5).
-            from sitewhere_tpu.pipeline.packed import packed_pipeline_step
+            # count (pipeline/packed.py).  Donated where donation is
+            # real: the scatter writes the named rows in place instead
+            # of into a copy of the whole carry (PERF.md §5).
+            from sitewhere_tpu.pipeline.packed import build_packed_step
 
-            self._packed_step = jax.jit(packed_pipeline_step)
+            self._packed_step = build_packed_step(donate=self._ring_donate)
+            self._place_state = None
         from sitewhere_tpu.pipeline.packed import pack_tables
 
         self._pack_tables = jax.jit(pack_tables)
@@ -394,11 +399,6 @@ class PipelineDispatcher(LifecycleComponent):
         # path allocates no per-dispatch K-length lists.
         self._ring_slots_i: List = [None] * self.ring_depth
         self._ring_slots_f: List = [None] * self.ring_depth
-        # Donate the chain carry only where donation is real (the CPU
-        # backend ignores it with a warning per call): the state manager
-        # hands the epoch over exclusively via lease_packed, so donation
-        # can never delete buffers a concurrent reader still holds.
-        self._ring_donate = jax.default_backend() != "cpu"
         if self.ring_depth:
             # the in-flight window must hold at least two rings so chain
             # N+1 dispatches while ring N's egress drains (double
@@ -464,6 +464,8 @@ class PipelineDispatcher(LifecycleComponent):
         # single steps dispatched under pipeline.width: partial plans on
         # a narrow rung of the batcher's width ladder (plan_rungs)
         self._m_steps_narrow = metrics.counter("pipeline.steps_narrow")
+        # single steps dispatched with the carry donated (_step_epoch)
+        self._m_steps_donated = metrics.counter("pipeline.steps_donated")
         # Per-stage host-time timers (the overlapped-pipeline instrument
         # surface): decode / batch-assembly / step-dispatch / egress each
         # accumulate the HOST time they consume, so `sum(stage totals) >
@@ -1217,16 +1219,12 @@ class PipelineDispatcher(LifecycleComponent):
                     # epoch for every subsequent live dispatch
                     self._dispatch_chain(
                         chain, tables, [bi] * k, [bf] * k, block=True)
-            # the single steps: no lock, they commit nothing (zero valid
-            # rows; the outputs are dropped)
-            ps = self.state_manager.current_packed
-            if self.mesh is not None:
-                from sitewhere_tpu.pipeline.sharded import place_packed_state
-
-                ps = place_packed_state(self.mesh, ps)
+            # the single steps, through the state manager's hand-off as
+            # a live step goes (a donating step consumes the carry it is
+            # handed): zero valid rows leave the state as it was
             for slot in slots:
                 jax.block_until_ready(
-                    self._packed_step(tables, ps, *slot))
+                    self._step_epoch(self._packed_step, tables, *slot))
             if k and self.cost_analysis:
                 # static roofline of the compiled chain: flops/bytes as
                 # device.cost.* gauges (AOT lower+compile of the same
@@ -1256,15 +1254,11 @@ class PipelineDispatcher(LifecycleComponent):
         relies on the fail-closed window for execution failures."""
         if self._ring_donate:
             ps, token = self.state_manager.lease_packed()
-            if self.mesh is not None:
+            if self._place_state is not None:
                 # a freshly-materialized lease pack has no mesh layout
                 # yet; device_put is a no-op once the planes already
                 # carry it (every lease after the first chain)
-                from sitewhere_tpu.pipeline.sharded import (
-                    place_packed_state,
-                )
-
-                ps = place_packed_state(self.mesh, ps)
+                ps = self._place_state(ps)
             out = chain(tables, ps, *slots_i, *slots_f)
             if block:
                 jax.block_until_ready(out)
@@ -1273,12 +1267,8 @@ class PipelineDispatcher(LifecycleComponent):
         else:
             epoch = self.state_manager.current_packed
             ps = epoch
-            if self.mesh is not None:
-                from sitewhere_tpu.pipeline.sharded import (
-                    place_packed_state,
-                )
-
-                ps = place_packed_state(self.mesh, ps)
+            if self._place_state is not None:
+                ps = self._place_state(ps)
             out = chain(tables, ps, *slots_i, *slots_f)
             if block:
                 jax.block_until_ready(out)
@@ -2232,21 +2222,14 @@ class PipelineDispatcher(LifecycleComponent):
         from sitewhere_tpu.pipeline.packed import start_host_copy
 
         tables = self._tables_packed()
-        epoch = self.state_manager.current_packed
-        ps = epoch
         # staged pair (H2D already in flight) off the CPU backend; the
         # raw numpy buffers otherwise (the jitted call then transfers
         # synchronously)
         bi, bf = plan.staged or (plan.packed_i, plan.packed_f)
-        if self.mesh is not None:
-            from sitewhere_tpu.pipeline.sharded import (
-                place_packed_batch,
-                place_packed_state,
-            )
+        if self.mesh is not None and plan.staged is None:
+            from sitewhere_tpu.pipeline.sharded import place_packed_batch
 
-            if plan.staged is None:
-                bi, bf = place_packed_batch(self.mesh, bi, bf)
-            ps = place_packed_state(self.mesh, ps)
+            bi, bf = place_packed_batch(self.mesh, bi, bf)
         # breaker at FALLBACK: the chip is presumed dead — route the
         # same jitted program to a CPU device (single-chip path only; a
         # mesh program keeps its own placement)
@@ -2270,9 +2253,8 @@ class PipelineDispatcher(LifecycleComponent):
                                    values=plan.packed_f,
                                    valid=plan.packed_i[0] != 0)
             with trace.span("step.dispatch").tag("rows", plan.n_events):
-                new_ps, oi, metrics, present = step_fn(tables, ps, bi, bf)
-                self.state_manager.commit_packed(
-                    new_ps, present_now=present, read_epoch=epoch)
+                _, oi, metrics, present = self._step_epoch(
+                    step_fn, tables, bi, bf)
             # Start the egress fetches NOW, asynchronously: the copies
             # complete in the background while later plans step, so the
             # blocking np.asarray at the window's egress end finds the
@@ -2281,6 +2263,19 @@ class PipelineDispatcher(LifecycleComponent):
         except Exception as e:
             raise _StepFailed() from e
         return self._packed_view(oi, metrics, present, plan.seq)
+
+    def _step_epoch(self, step_fn, tables, bi, bf):
+        """ONE single step on the state manager's epoch through its
+        hand-off (:meth:`DeviceStateManager.step_packed`: read, dispatch
+        and commit under its lock), laid out on the mesh first where
+        there is one.  Returns the step's outputs; the carry it was
+        handed is gone where ``step_fn`` donates it, so nothing here
+        keeps it."""
+        out = self.state_manager.step_packed(
+            step_fn, tables, bi, bf, place=self._place_state)
+        if self._ring_donate and step_fn is self._packed_step:
+            self._m_steps_donated.inc()
+        return out
 
     def _packed_view(self, oi, metrics, present, seq: int):
         """A step's :class:`PackedView`, its one blocking fetch counted
@@ -2374,17 +2369,16 @@ class PipelineDispatcher(LifecycleComponent):
                 faults.device_fire("device.dispatch", values=bf,
                                    valid=bi[0] != 0)
             tables = self._tables_packed()
-            epoch = self.state_manager.current_packed
             with self._lock:
                 self._plans_outstanding += 1
             try:
-                new_ps, oi, metrics, present = step_fn(
-                    tables, epoch, bi, bf)
+                # each retry reads the epoch again through the hand-off,
+                # never a carry an earlier attempt was handed
+                new_ps, oi, metrics, present = self._step_epoch(
+                    step_fn, tables, bi, bf)
                 # surface async execution faults HERE, inside the
                 # containment, not at the egress fetch
                 jax.block_until_ready(new_ps)
-                self.state_manager.commit_packed(
-                    new_ps, present_now=present, read_epoch=epoch)
             except Exception:
                 with self._lock:
                     self._plans_outstanding -= 1
@@ -2433,12 +2427,11 @@ class PipelineDispatcher(LifecycleComponent):
             return None
         if self._cpu_step is None:
             try:
-                from sitewhere_tpu.pipeline.packed import (
-                    packed_pipeline_step,
-                )
+                from sitewhere_tpu.pipeline.packed import build_packed_step
 
                 cpu = jax.devices("cpu")[0]
-                jitted = jax.jit(packed_pipeline_step)
+                # not donating: its carry is a CPU copy of the device's
+                jitted = build_packed_step(donate=False)
 
                 def run(tables, ps, bi, bf, _cpu=cpu, _fn=jitted):
                     tables, ps, bi, bf = jax.device_put(

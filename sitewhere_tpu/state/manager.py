@@ -200,11 +200,10 @@ def _packed_codecs():
 
 def _packed_sweep():
     """Module-level jitted :func:`packed_presence_sweep` (lazy import, as
-    above).  NOT donated: a single step reads the packed epoch without a
-    lease (``dispatcher._step_packed``), so the manager never owns the
-    buffer alone and a donating sweep could delete it between a step's
-    read and its dispatch.  The sweep pays one carry copy instead, as the
-    single step does (ROADMAP S8 gives both the lease)."""
+    above).  Not donated, so it pays one copy of the carry.  Every
+    single step now takes the epoch under the manager's lock
+    (:meth:`DeviceStateManager.step_packed`), so a sweep dispatched
+    under the same lock could donate it too (ROADMAP S12)."""
     global _SWEEP
     if "_SWEEP" not in globals():
         from sitewhere_tpu.pipeline.packed import packed_presence_sweep
@@ -376,19 +375,49 @@ class DeviceStateManager(LifecycleComponent):
             # intervened and the presence merge can be skipped
             return packed, self._state
 
+    def step_packed(self, step, tables, bi, bf, place=None):
+        """Hand the packed epoch to ONE single step and adopt its output,
+        all under the lock: ``step(tables, packed, bi, bf)`` (the epoch
+        laid out by ``place`` first, on a mesh) returns ``(new_packed,
+        *outputs)``; the whole tuple is returned.
+
+        The step may donate the carry (``build_packed_step(donate=
+        True)``): nothing can read ``_packed`` between the read and the
+        commit (a sweep, a checkpoint's ``current``, a REST reader all
+        take this lock), so no presence merge is ever needed and none is
+        dispatched.  Dispatch is asynchronous, so the lock is held for
+        the host's dispatch alone.  The readers that leave the lock with
+        an epoch hold the unpacked twin (``current``), which no hand-off
+        donates; a program on the packed epoch is dispatched under the
+        lock (``current`` unpacks, the sweep and its warm-up run there).
+
+        A step that raises before its program is enqueued (an injected
+        device fault, a trace error) leaves the epoch as it was; a retry
+        reads it again through this method.  An execution fault surfaced
+        after the enqueue has consumed a donated carry, as a failed
+        chain's has (``lease_packed``)."""
+        with self._lock:
+            packed = self.current_packed
+            if place is not None:
+                packed = place(packed)
+            out = step(tables, packed, bi, bf)
+            self._packed = out[0]
+            self._state = None
+            return out
+
     def commit_packed(self, new_packed, present_now,
                       read_epoch=None, lease_token=None) -> None:
-        """Adopt a packed step's output state (the packed-loop analog of
-        :meth:`commit`): re-apply ``presence_missing`` flags a concurrent
-        sweep set on the current epoch for devices this step did not merge
-        (``present_now`` = the step's — or the whole chain's OR'd —
-        winner map).
+        """Adopt a step chain's output state (the packed-loop analog of
+        :meth:`commit`; a single step commits inside
+        :meth:`step_packed`): re-apply ``presence_missing`` flags a
+        concurrent sweep set on the current epoch for devices the chain
+        did not merge (``present_now`` = the chain's OR'd winner map).
 
-        Pass ``read_epoch`` (the PackedState the step consumed): when the
+        Pass ``read_epoch`` (the PackedState the chain consumed): when the
         current epoch is still that object, nothing intervened and the
-        merge — an extra per-step dispatch — is skipped entirely.  A
-        donated chain passes ``lease_token`` from :meth:`lease_packed`
-        instead (the consumed epoch's buffers no longer exist to compare).
+        merge — an extra dispatch — is skipped entirely.  A donated chain
+        passes ``lease_token`` from :meth:`lease_packed` instead (the
+        consumed epoch's buffers no longer exist to compare).
         """
         with self._lock:
             unchanged = (
@@ -506,12 +535,14 @@ class DeviceStateManager(LifecycleComponent):
         outputs: nothing is adopted, so nothing changes.  Called at
         start so the first sweep in service compiles nothing."""
         with self._lock:
+            # dispatched under the lock: a single step may donate the
+            # epoch the moment the lock is free
             packed = self.current_packed
-        # [1]: the swept carry is dropped at once, so the warm-up never
-        # holds more than the epoch and one copy of it on the device
-        newly = _packed_sweep()(packed, jnp.int32(0), jnp.int32(0))[1]
-        jax.block_until_ready(
-            _merge_presence(packed.rows, packed.rows, newly))
+            # [1]: the swept carry is dropped at once, so the warm-up
+            # never holds more than the epoch and one copy of it
+            newly = _packed_sweep()(packed, jnp.int32(0), jnp.int32(0))[1]
+            merged = _merge_presence(packed.rows, packed.rows, newly)
+        jax.block_until_ready(merged)
 
     # -- queries (reference: DeviceStateImpl RPCs) --------------------------
 

@@ -198,6 +198,9 @@ class FakeStateManager:
     def lease_packed(self):
         return None, None
 
+    def step_packed(self, step, tables, bi, bf, place=None):
+        return step(tables, self.current_packed, bi, bf)
+
 
 class SlowStore:
     """Event-store stand-in whose append costs ``delay_s`` host time."""

@@ -23,10 +23,10 @@ from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.pipeline import pipeline_step, update_device_state
 from sitewhere_tpu.pipeline.packed import (
     build_packed_chain,
+    build_packed_step,
     pack_batch_host,
     pack_state,
     pack_tables,
-    packed_pipeline_step,
     unpack_batch,
     unpack_state,
 )
@@ -253,11 +253,11 @@ def _assert_same_state(got: DeviceState, want: DeviceState):
             err_msg=f)
 
 
-def _run_packed_step(cap, M, batches, mesh):
+def _run_packed_step(cap, M, batches, mesh, donate=False):
     registry, rules, zones = _tables(cap, cap)
     tables = pack_tables(registry, rules, zones)
     ps = pack_state(_seeded_state(cap, M))
-    step = jax.jit(packed_pipeline_step)
+    step = build_packed_step(donate)
     presents = []
     for cols in batches:
         bi, bf = pack_batch_host(cols, len(cols["valid"]))
@@ -266,12 +266,12 @@ def _run_packed_step(cap, M, batches, mesh):
     return unpack_state(ps), presents
 
 
-def _run_packed_chain(cap, M, batches, mesh):
+def _run_packed_chain(cap, M, batches, mesh, donate=False):
     registry, rules, zones = _tables(cap, cap)
     tables = pack_tables(registry, rules, zones)
     ps = pack_state(_seeded_state(cap, M))
     packed = [pack_batch_host(c, len(c["valid"])) for c in batches]
-    chain = build_packed_chain(len(batches), donate=False)
+    chain = build_packed_chain(len(batches), donate=donate)
     ps, _ois, _mets, present = chain(
         tables, ps, *[b[0] for b in packed], *[b[1] for b in packed])
     return unpack_state(ps), np.asarray(present)
@@ -293,7 +293,7 @@ def _route(cols, cap, n_shards):
     return out
 
 
-def _run_sharded_step(cap, M, batches, mesh):
+def _run_sharded_step(cap, M, batches, mesh, donate=False):
     from sitewhere_tpu.pipeline.sharded import (
         build_sharded_packed_step,
         place_packed_batch,
@@ -304,7 +304,7 @@ def _run_sharded_step(cap, M, batches, mesh):
     registry, rules, zones = _tables(cap, cap)
     tables = place_packed_tables(mesh, pack_tables(registry, rules, zones))
     ps = place_packed_state(mesh, pack_state(_seeded_state(cap, M)))
-    step = build_sharded_packed_step(mesh)
+    step = build_sharded_packed_step(mesh, donate=donate)
     presents = []
     for cols in batches:
         bi, bf = pack_batch_host(cols, len(cols["valid"]))
@@ -323,8 +323,9 @@ def mesh4():
 
 
 @pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("path", ["packed_step", "packed_chain_k8",
-                                  "sharded_packed_step_4"])
+@pytest.mark.parametrize("path", ["packed_step", "packed_step_donated",
+                                  "packed_chain_k8", "sharded_packed_step_4",
+                                  "sharded_packed_step_4_donated"])
 def test_packed_paths_match_the_unpacked_step(path, shape, mesh4):
     cap, M, width = SHAPES[shape]
     batches = []
@@ -340,8 +341,10 @@ def test_packed_paths_match_the_unpacked_step(path, shape, mesh4):
     want_state, want_outs = _unpacked_run(cap, M, batches)
     run = {"packed_step": _run_packed_step,
            "packed_chain_k8": _run_packed_chain,
-           "sharded_packed_step_4": _run_sharded_step}[path]
-    got_state, present = run(cap, M, batches, mesh4)
+           "sharded_packed_step_4": _run_sharded_step}[
+               path.removesuffix("_donated")]
+    got_state, present = run(cap, M, batches, mesh4,
+                             donate=path.endswith("_donated"))
     _assert_same_state(got_state, want_state)
     assert int(np.asarray(want_state.nonfinite_count).sum()) > int(
         np.asarray(_seeded_state(cap, M).nonfinite_count).sum())
@@ -360,14 +363,13 @@ def test_packed_paths_match_the_unpacked_step(path, shape, mesh4):
 def test_nothing_in_the_step_is_sized_by_the_registry(program):
     """Lowered at one width and two capacities, compiled for the backend
     here: every value with at least ``capacity`` elements is the carry
-    (updated in place: one copy where it is not donated, none where it
-    is), the registry table, or a ``[capacity]`` vector."""
+    (updated in place and never copied: both programs donate it, PR 36),
+    the registry table, or a ``[capacity]`` vector."""
     import aot_check
 
     for capacity in (1 << 14, 1 << 18):
         hlo = aot_check.compiled_hlo(program, capacity, width=32)
-        aot_check.assert_step_costs_the_batch(
-            hlo, capacity, donated=program.endswith("donated"))
+        aot_check.assert_step_costs_the_batch(hlo, capacity, donated=True)
 
 
 def test_the_guard_sees_a_registry_sized_intermediate():

@@ -83,6 +83,7 @@ EXPECTED_RULES = {
     ("donation", "tp_use_after_jit_donate.py"): "DN001",
     ("donation", "tp_use_after_chain.py"): "DN001",
     ("donation", "tp_retry_with_donated.py"): "DN001",
+    ("donation", "tp_epoch_after_single_step.py"): "DN001",
     ("donation", "tp_use_after_lease.py"): "DN002",
     ("donation", "tp_use_after_commit.py"): "DN003",
     ("donation", "tp_use_after_abort.py"): "DN003",

@@ -6,7 +6,7 @@ against it ahead of time.  With ``jax.default_backend`` patched to
 return ``"tpu"`` while tracing, every backend switch in the tree takes
 its chip side, so a change that breaks the TPU lowering of
 
-- the packed single step,
+- the packed single step (donated, as served on the chip),
 - the K-step donated chain (``build_packed_chain``),
 - the mesh-fused chain on the 2x2 mesh (``build_sharded_packed_chain``),
 - the Pallas geofence kernel with ``interpret=False``
@@ -177,12 +177,12 @@ def compiled_hlo(program: str, capacity: int, width: int,
 
     from sitewhere_tpu.pipeline.packed import (
         build_packed_chain,
-        packed_pipeline_step,
+        build_packed_step,
     )
 
     tables, state, bi, bf = _packed_avals(capacity, width, mtype_slots)
     if program == "packed_step":
-        lowered = jax.jit(packed_pipeline_step).lower(tables, state, bi, bf)
+        lowered = build_packed_step(donate=True).lower(tables, state, bi, bf)
     else:
         k = ring_depth
         lowered = build_packed_chain(k, donate=True).lower(
@@ -233,7 +233,7 @@ def aot_check(capacity: int = 4096, width: int = 1024, ring_depth: int = 8,
     from sitewhere_tpu.parallel.mesh import MODEL_AXIS, SHARD_AXIS
     from sitewhere_tpu.pipeline.packed import (
         build_packed_chain,
-        packed_pipeline_step,
+        build_packed_step,
     )
     from sitewhere_tpu.pipeline.sharded import (
         _PACKED_BATCH_SPEC,
@@ -257,7 +257,7 @@ def aot_check(capacity: int = 4096, width: int = 1024, ring_depth: int = 8,
             t0 = time.perf_counter()
             rows.append(_report(
                 "packed_step",
-                jax.jit(packed_pipeline_step).lower(*args1), t0))
+                build_packed_step(donate=True).lower(*args1), t0))
         if "packed_chain" in programs:
             t0 = time.perf_counter()
             rows.append(_report(
