@@ -33,6 +33,7 @@ from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
 from sitewhere_tpu.ingest.sources import Receiver, logger
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.runtime.overload import OverloadShed
 
 PROTOCOL_HEADER = b"AMQP\x00\x00\x09\x01"
@@ -208,12 +209,7 @@ class AmqpReceiver(Receiver):
     def stop(self) -> None:
         self._alive = False
         self._stop_evt.set()
-        sock = self._sock
-        if sock is not None:
-            try:
-                sock.close()
-            except OSError:
-                pass
+        close_socket(self._sock)
         self._stop_supervisor()
         super().stop()
 

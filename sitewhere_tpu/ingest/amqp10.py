@@ -43,6 +43,7 @@ import uuid as _uuid
 from typing import Dict, List, Optional, Tuple
 
 from sitewhere_tpu.ingest.sources import Receiver, logger
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.runtime.overload import OverloadShed
 
 AMQP_HEADER = b"AMQP\x00\x01\x00\x00"
@@ -432,10 +433,7 @@ class EventHubReceiver(Receiver):
         self._alive = False
         self._stop_evt.set()
         for sock in list(self._socks.values()):
-            try:
-                sock.close()
-            except OSError:
-                pass
+            close_socket(sock)
         self._stop_supervisor()
         if self._ckpt_dirty:
             try:

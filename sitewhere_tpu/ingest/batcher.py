@@ -379,7 +379,7 @@ class BatchPlan:
 
     __slots__ = ("_batch", "n_events", "width", "full_width", "created_at",
                  "max_wait_s", "host_cols", "packed_i", "packed_f", "staged",
-                 "seq", "reason", "dispatch_s")
+                 "seq", "reason", "dispatch_s", "released")
 
     def __init__(
         self,
@@ -427,6 +427,9 @@ class BatchPlan:
         self.seq = seq
         self.reason = reason
         self.dispatch_s = dispatch_s
+        # Set by the dispatcher when egress takes the plan off its
+        # outstanding count (the commit gate's accounting).
+        self.released = False
 
     @property
     def batch(self) -> Optional[EventBatch]:

@@ -25,6 +25,7 @@ from collections import OrderedDict
 from typing import List, Optional, Tuple
 
 from sitewhere_tpu.ingest.sources import Receiver
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.runtime.overload import OverloadShed
 
 logger = logging.getLogger("sitewhere_tpu.ingest.coap")
@@ -218,9 +219,8 @@ class CoapServerReceiver(Receiver):
 
     def stop(self) -> None:
         self._alive = False
-        if self._sock is not None:
-            self._sock.close()
-            self._sock = None
+        sock, self._sock = self._sock, None
+        close_socket(sock)
         self._stop_supervisor()
         super().stop()
 

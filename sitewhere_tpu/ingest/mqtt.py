@@ -20,6 +20,8 @@ import threading
 import time
 from typing import Callable, Optional, Tuple
 
+from sitewhere_tpu.netio import close_socket
+
 # Packet types (<<4 in the fixed header).
 CONNECT, CONNACK = 1, 2
 PUBLISH, PUBACK = 3, 4
@@ -196,10 +198,7 @@ class MqttClient:
                 self._sock.sendall(bytes([DISCONNECT << 4, 0]))
             except OSError:
                 pass
-            try:
-                self._sock.close()
-            except OSError:
-                pass
+            close_socket(self._sock)   # wakes the pump's blocked recv
             self._sock = None
         if self._pump is not None:
             self._pump.join(timeout=2.0)

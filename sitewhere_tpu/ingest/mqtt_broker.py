@@ -56,6 +56,7 @@ from sitewhere_tpu.ingest.mqtt import (
     write_publish,
 )
 from sitewhere_tpu.ingest.sources import Receiver, logger
+from sitewhere_tpu.netio import close_socket
 
 
 def topic_matches(filt: str, topic: str) -> bool:
@@ -100,10 +101,7 @@ class _Session:
         return self.packet_id
 
     def close(self) -> None:
-        try:
-            self.sock.close()
-        except OSError:
-            pass
+        close_socket(self.sock)
 
 
 class MqttBroker:
@@ -150,12 +148,8 @@ class MqttBroker:
 
     def stop(self) -> None:
         self._alive = False
-        if self._srv is not None:
-            try:
-                self._srv.close()
-            except OSError:
-                pass
-            self._srv = None
+        srv, self._srv = self._srv, None
+        close_socket(srv)
         with self._lock:
             sessions = list(self._sessions.values())
             self._sessions.clear()
@@ -174,8 +168,11 @@ class MqttBroker:
 
     def _accept_loop(self) -> None:
         while self._alive:
+            srv = self._srv
+            if srv is None:
+                return   # stop() took the listener between two accepts
             try:
-                conn, addr = self._srv.accept()
+                conn, addr = srv.accept()
             except OSError:
                 return
             threading.Thread(

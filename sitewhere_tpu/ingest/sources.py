@@ -51,6 +51,7 @@ from typing import Callable, Dict, List, Optional
 logger = logging.getLogger("sitewhere_tpu.ingest")
 
 from sitewhere_tpu.ingest.decoders import DecodedRequest, DecodeError, RequestKind
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.runtime.overload import OverloadShed
@@ -708,18 +709,8 @@ class TcpReceiver(Receiver):
             self.port = sock.getsockname()[1]
 
     def _close_listener(self) -> None:
-        # shutdown BEFORE close: close() alone does not wake a thread
-        # blocked in accept() on Linux — the loop would hang forever
         sock, self._sock = self._sock, None
-        if sock is not None:
-            try:
-                sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                sock.close()
-            except OSError:
-                pass
+        close_socket(sock)
 
     def start(self) -> None:
         self._bind()
@@ -806,14 +797,7 @@ class TcpReceiver(Receiver):
         with self._conn_lock:
             conns = list(self._conns.items())
         for conn, thread in conns:
-            try:
-                conn.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                conn.close()
-            except OSError:
-                pass
+            close_socket(conn)
             try:
                 thread.join(timeout=2)
                 if thread.is_alive():
@@ -876,9 +860,8 @@ class UdpReceiver(Receiver):
 
     def stop(self) -> None:
         self._alive = False
-        if self._sock is not None:
-            self._sock.close()
-            self._sock = None
+        sock, self._sock = self._sock, None
+        close_socket(sock)
         self._stop_supervisor()
         super().stop()
 

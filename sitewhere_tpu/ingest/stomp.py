@@ -27,6 +27,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from sitewhere_tpu.ingest.sources import Receiver, logger
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.runtime.overload import OverloadShed
 from sitewhere_tpu.runtime.resilience import Backoff, RetryPolicy
 
@@ -203,12 +204,7 @@ class StompReceiver(Receiver):
     def stop(self) -> None:
         self._alive = False
         self._stop_evt.set()
-        sock = self._sock
-        if sock is not None:
-            try:
-                sock.close()
-            except OSError:
-                pass
+        close_socket(self._sock)
         self._stop_supervisor()
         super().stop()
 

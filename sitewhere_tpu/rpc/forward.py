@@ -616,9 +616,11 @@ class HostForwarder(LifecycleComponent):
 
         t = threading.Thread(target=run,
                              name=f"{self.name}-send-{owner}", daemon=True)
+        # started under the lock: flush(wait=True) joins every thread it
+        # finds in _senders, and joining one not yet started raises
         with self._lock:
             self._senders.add(t)
-        t.start()
+            t.start()
         return t
 
     def _owner_pending_locked(self, owner: int) -> bool:

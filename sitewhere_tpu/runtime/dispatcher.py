@@ -348,6 +348,10 @@ class PipelineDispatcher(LifecycleComponent):
         # plan sitting between emission and _run_plan (outside both
         # batcher.pending and _inflight) can never be committed past.
         self._plans_outstanding = 0
+        # Of those, the plans whose egress raised: they stay outstanding
+        # (the gate fails closed until a restart replays them), and no
+        # wait can end them, so flush() stops waiting for them.
+        self._plans_failed = 0
         self._lock = threading.Lock()
         # Serializes read-state → step → commit → egress across the loop
         # thread, source threads, and the presence thread: two concurrent
@@ -1361,6 +1365,8 @@ class PipelineDispatcher(LifecycleComponent):
         ``batcher.pending`` nor ``_inflight`` — only the plans-outstanding
         gate sees it — so flush waits for gate quiescence (bounded:
         concurrent sources can keep refilling under sustained traffic).
+        A plan whose egress failed never completes: quiescence leaves it
+        outstanding, and the commit below stays closed on it.
         """
         self._run_plans(self._take(self.batcher.flush))
         self._flush_ring()
@@ -1368,7 +1374,7 @@ class PipelineDispatcher(LifecycleComponent):
         deadline = time.monotonic() + timeout_s
         while time.monotonic() < deadline:
             with self._lock:
-                quiesced = (self._plans_outstanding == 0
+                quiesced = (self._plans_outstanding == self._plans_failed
                             and self.batcher.pending == 0)
             # _egress_busy outlives the outstanding decrement (the
             # worker's metrics/trace tail runs before its finally clears
@@ -2526,6 +2532,8 @@ class PipelineDispatcher(LifecycleComponent):
         batch that died) no matter which thread ran it."""
         self._m_stage["inflight_wait"].observe(
             time.perf_counter() - item[4])
+        # a bisected plan egresses once for each clean subset
+        item[0].released = False
         try:
             try:
                 self._egress(*item[:4])
@@ -2554,6 +2562,12 @@ class PipelineDispatcher(LifecycleComponent):
                         "re-dispatching seq=%d single-step", plan.seq)
                     self._dispatch_plan(plan, 1, stall=False)
                     return
+                with self._lock:
+                    # a raise after the release (the trace, metrics and
+                    # flight-record tail) leaves no plan outstanding
+                    # for flush to skip
+                    if not plan.released:
+                        self._plans_failed += 1
                 raise
         finally:
             # watchdog retire happens whether egress succeeded, failed,
@@ -2738,6 +2752,7 @@ class PipelineDispatcher(LifecycleComponent):
         with self._lock:
             self.latencies_s.append(lat)
             self._plans_outstanding -= 1
+            plan.released = True
         # Close the trace: for tail candidates this IS the retention
         # decision (errored/slow traces flip to sampled, so the async
         # outbound/command spans still land in the ring).  The e2e

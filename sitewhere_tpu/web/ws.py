@@ -22,6 +22,8 @@ import struct
 import threading
 from typing import Optional, Tuple
 
+from sitewhere_tpu.netio import close_socket
+
 _GUID = "258EAFA5-E914-47DA-95CA-C5AB0DC85B11"
 
 OP_CONT, OP_TEXT, OP_BINARY, OP_CLOSE, OP_PING, OP_PONG = 0, 1, 2, 8, 9, 10
@@ -215,7 +217,7 @@ class _BufferedSock:
         self._sock.sendall(data)
 
     def close(self) -> None:
-        self._sock.close()
+        close_socket(self._sock)   # wakes a thread blocked in recv
 
 
 class ClientWebSocket:

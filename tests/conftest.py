@@ -27,6 +27,8 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 
+import time_limit  # noqa: E402
+
 
 @pytest.fixture(scope="session")
 def devices():
@@ -43,6 +45,8 @@ def mesh8(devices):
 
 
 def pytest_configure(config):
+    # every test's setup, call and teardown runs under a time limit
+    config.pluginmanager.register(time_limit, "time_limit")
     config.addinivalue_line(
         "markers", "slow: long-running soak / multi-process integration tests")
     config.addinivalue_line(

@@ -44,6 +44,9 @@ from sitewhere_tpu.ingest.amqp import (
     parse_shortstr,
     shortstr,
 )
+from sitewhere_tpu.netio import close_socket
+
+from time_limit import wait_until
 
 
 class MiniAmqpBroker:
@@ -79,10 +82,7 @@ class MiniAmqpBroker:
 
     def close(self):
         self._alive = False
-        try:
-            self._srv.close()
-        except OSError:
-            pass
+        close_socket(self._srv)   # wakes the blocked accept
 
     # -- server side ---------------------------------------------------------
 
@@ -125,7 +125,10 @@ class MiniAmqpBroker:
                     self.acks.append(tag)
                     continue
                 raise AmqpError(f"mini-broker: unexpected {cm}")
-            pending.extend(reader.feed(conn.recv(65536)))
+            data = conn.recv(65536)
+            if not data:
+                raise AmqpError("mini-broker: client gone")
+            pending.extend(reader.feed(data))
 
     def _session(self, conn):
         conn.settimeout(10)
@@ -226,15 +229,6 @@ class MiniAmqpBroker:
         return out
 
 
-def _wait(predicate, timeout=5.0):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if predicate():
-            return True
-        time.sleep(0.01)
-    return False
-
-
 def test_consume_and_ack_after_sink_accepts():
     broker = MiniAmqpBroker()
     got = []
@@ -242,12 +236,12 @@ def test_consume_and_ack_after_sink_accepts():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(b'{"deviceToken":"d1"}')
         broker.push(b'{"deviceToken":"d2"}')
-        assert _wait(lambda: len(got) == 2)
+        assert wait_until(lambda: len(got) == 2)
         assert got == [b'{"deviceToken":"d1"}', b'{"deviceToken":"d2"}']
-        assert _wait(lambda: broker.acks == [1, 2])
+        assert wait_until(lambda: broker.acks == [1, 2])
         assert broker.declares == ["q1"]
         assert broker.auth[0] == "PLAIN"
         assert broker.auth[1] == b"\x00guest\x00guest"
@@ -264,10 +258,10 @@ def test_multi_frame_body_reassembled():
     rx.start()
     try:
         payload = b"x" * 100 + b"tail"
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(payload)
-        assert _wait(lambda: got == [payload])
-        assert _wait(lambda: broker.acks == [1])
+        assert wait_until(lambda: got == [payload])
+        assert wait_until(lambda: broker.acks == [1])
     finally:
         rx.stop()
         broker.close()
@@ -287,10 +281,10 @@ def test_rejected_payload_nacked_with_requeue():
     rx.sink = bad_sink
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(b"poison")
-        assert _wait(lambda: rx.emit_errors >= 1)
-        assert _wait(lambda: len(broker.nacks) >= 1)
+        assert wait_until(lambda: rx.emit_errors >= 1)
+        assert wait_until(lambda: len(broker.nacks) >= 1)
         assert broker.nacks[0] == (1, 0x02)  # requeue bit set
         time.sleep(0.1)
         assert broker.acks == []  # never acked a failed payload
@@ -319,19 +313,18 @@ def test_prefetch_window_survives_sink_failures():
     rx.sink = flaky_sink
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         for i in range(4):  # > prefetch consecutive failures
             broker.push(b"fail-%d" % i)
-        assert _wait(lambda: rx.emit_errors >= 4)
-        assert _wait(lambda: len(broker.nacks) >= 4)
+        assert wait_until(lambda: rx.emit_errors >= 4)
+        assert wait_until(lambda: len(broker.nacks) >= 4)
         fail[0] = False
         broker.push(b"good")
         # the sink recovered: the requeued deliveries AND the new one all
         # land (at-least-once), and everything delivered gets acked
-        assert _wait(lambda: sorted(got) == sorted(
-            [b"fail-0", b"fail-1", b"fail-2", b"fail-3", b"good"]),
-            timeout=10.0)
-        assert _wait(lambda: len(broker.acks) == 5)
+        assert wait_until(lambda: sorted(got) == sorted(
+            [b"fail-0", b"fail-1", b"fail-2", b"fail-3", b"good"]))
+        assert wait_until(lambda: len(broker.acks) == 5)
         assert rx._nack_streak == 0  # streak resets on success
     finally:
         rx.stop()
@@ -351,9 +344,9 @@ def test_persistent_sink_failure_backs_off_not_spins():
     rx.sink = dead_sink
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(b"poison")
-        assert _wait(lambda: rx.emit_errors >= 1)
+        assert wait_until(lambda: rx.emit_errors >= 1)
         time.sleep(1.0)
         # with backoff 50+100+200+400+800ms ≈ 5 attempts fit in ~1.5s;
         # without it the redeliver loop would spin hundreds of times
@@ -376,11 +369,11 @@ def test_delivery_coalesced_with_consume_ok_not_dropped():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: got == [b"early-bird", b"second"])
-        assert _wait(lambda: broker.acks == [1, 2])
+        assert wait_until(lambda: got == [b"early-bird", b"second"])
+        assert wait_until(lambda: broker.acks == [1, 2])
         # and the session keeps working for normal deliveries after
         broker.push(b"third")
-        assert _wait(lambda: b"third" in got)
+        assert wait_until(lambda: b"third" in got)
     finally:
         rx.stop()
         broker.close()
@@ -394,9 +387,9 @@ def test_reconnects_after_dropped_session():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions >= 2)
+        assert wait_until(lambda: broker.sessions >= 2)
         broker.push(b"after-reconnect")
-        assert _wait(lambda: got == [b"after-reconnect"])
+        assert wait_until(lambda: got == [b"after-reconnect"])
     finally:
         rx.stop()
         broker.close()
@@ -422,11 +415,11 @@ def test_receiver_feeds_instance_pipeline(tmp_path):
     try:
         seed_device(inst)
         source.start()
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(
             b'{"deviceToken":"dev-1","type":"Measurement",'
             b'"request":{"name":"temp","value":21.5,"eventDate":1000}}')
-        assert _wait(lambda: broker.acks == [1])
+        assert wait_until(lambda: broker.acks == [1])
         inst.dispatcher.flush()
         inst.event_store.flush()
         assert inst.event_store.total_events == 1
@@ -447,11 +440,11 @@ def test_heartbeat_negotiated_and_dead_connection_detected():
     rx.sink = lambda p: None
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions >= 1)
+        assert wait_until(lambda: broker.sessions >= 1)
         # the mini-broker never sends heartbeats, so the receiver's
         # 2x-interval cutoff fires and it reconnects — session count
         # keeps climbing without any traffic
-        assert _wait(lambda: broker.sessions >= 2, timeout=10.0)
+        assert wait_until(lambda: broker.sessions >= 2)
     finally:
         rx.stop()
         broker.close()
@@ -468,16 +461,16 @@ def test_socket_drop_mid_stream_reconnects_and_resumes():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: broker.sessions == 1)
+        assert wait_until(lambda: broker.sessions == 1)
         broker.push(b"one")
-        assert _wait(lambda: got == [b"one"])
+        assert wait_until(lambda: got == [b"one"])
         # kill the live session socket only (the accept loop stays up):
         sock = rx._sock
         assert sock is not None
         sock.close()
-        assert _wait(lambda: broker.sessions >= 2, timeout=10.0)
+        assert wait_until(lambda: broker.sessions >= 2)
         broker.push(b"two")
-        assert _wait(lambda: b"two" in got, timeout=10.0)
+        assert wait_until(lambda: b"two" in got)
     finally:
         rx.stop()
         broker.close()

@@ -11,7 +11,6 @@ import json
 import socket
 import struct
 import threading
-import time
 
 import pytest
 
@@ -46,6 +45,9 @@ from sitewhere_tpu.ingest.amqp10 import (
     parse_message,
     performative,
 )
+from sitewhere_tpu.netio import close_socket
+
+from time_limit import wait_until
 
 
 def test_codec_round_trips():
@@ -108,10 +110,7 @@ class MiniEventHub:
 
     def close(self):
         self._stop = True
-        try:
-            self.sock.close()
-        except OSError:
-            pass
+        close_socket(self.sock)   # wakes the blocked accept
 
     # -- protocol ------------------------------------------------------------
 
@@ -147,6 +146,7 @@ class MiniEventHub:
                     pass
 
     def _session(self, conn):
+        conn.settimeout(10)
         self.sessions += 1
         reader = FrameReader()
         pending = []
@@ -253,15 +253,6 @@ class MiniEventHub:
                     return  # simulate a dropped session
 
 
-def _wait(predicate, timeout=5.0):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if predicate():
-            return True
-        time.sleep(0.01)
-    return False
-
-
 def make_receiver(broker, tmp_path=None, **kw):
     kw.setdefault("sasl", "anonymous")
     kw.setdefault("credit", 8)
@@ -279,8 +270,8 @@ def test_consume_settle_and_checkpoint(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: seen == [b"one", b"two", b"three"])
-        assert _wait(lambda: broker.dispositions == [0, 1, 2])
+        assert wait_until(lambda: seen == [b"one", b"two", b"three"])
+        assert wait_until(lambda: broker.dispositions == [0, 1, 2])
         # offsets checkpointed per partition
         ckpt = json.load(open(r._ckpt_path()))
         assert ckpt == {"0": "1002"}
@@ -298,7 +289,7 @@ def test_sasl_plain_credentials_verified(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: seen == [b"hi"])
+        assert wait_until(lambda: seen == [b"hi"])
     finally:
         r.stop()
         broker.close()
@@ -312,7 +303,7 @@ def test_multi_frame_transfer_reassembled(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: seen == [b"a-long-payload-split-across-frames"])
+        assert wait_until(lambda: seen == [b"a-long-payload-split-across-frames"])
     finally:
         r.stop()
         broker.close()
@@ -326,7 +317,7 @@ def test_credit_topped_up_past_initial_window(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: len(seen) == n)
+        assert wait_until(lambda: len(seen) == n)
         assert seen == [b"m%d" % i for i in range(n)]
         assert len(broker.flow_credits) > 1  # replenished at half-window
     finally:
@@ -342,7 +333,7 @@ def test_reconnect_resumes_from_checkpoint(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: broker.sessions >= 2 and len(seen) >= 4)
+        assert wait_until(lambda: broker.sessions >= 2 and len(seen) >= 4)
         # second attach carried the Event-Hub selector filter past m1
         assert len(broker.attach_sources) >= 2
         filt = broker.attach_sources[1].value[7]
@@ -388,7 +379,7 @@ def test_receiver_feeds_instance_pipeline(tmp_path):
         inst.device_management.create_device_assignment(device="eh-1")
         for line in lines:
             broker.push(line)
-        assert _wait(
+        assert wait_until(
             lambda: inst.dispatcher.metrics_snapshot()["accepted"] == 3)
         inst.dispatcher.flush()
         inst.event_store.flush()
@@ -416,7 +407,7 @@ def test_sink_failure_leaves_unsettled_and_recycles(tmp_path):
     try:
         # the failed delivery is NOT settled, so the recycled session
         # redelivers it (at-least-once) and it succeeds the second time
-        assert _wait(lambda: seen == [b"bad", b"good"])
+        assert wait_until(lambda: seen == [b"bad", b"good"])
         assert r.emit_errors == 1
         assert broker.sessions >= 2
     finally:
@@ -434,7 +425,7 @@ def test_server_pipelining_amqp_header_after_sasl(tmp_path):
     r.sink = seen.append
     r.start()
     try:
-        assert _wait(lambda: seen == [b"pipelined"])
+        assert wait_until(lambda: seen == [b"pipelined"])
         assert broker.sessions == 1  # no failed connect/reconnect spin
     finally:
         r.stop()

@@ -20,6 +20,7 @@ All faults are seeded/counted — each run is bit-identical.
 
 import json
 import socket
+import sys
 import time
 
 import numpy as np
@@ -32,6 +33,8 @@ from sitewhere_tpu.runtime.resilience import (
     RetryPolicy,
 )
 
+from time_limit import wait_until
+
 pytestmark = pytest.mark.chaos
 
 
@@ -42,15 +45,6 @@ def _clean_faults():
     yield
     faults.clear()
     faults.device_clear()
-
-
-def _wait(cond, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if cond():
-            return True
-        time.sleep(0.005)
-    return cond()
 
 
 # ---------------------------------------------------------------------------
@@ -132,10 +126,10 @@ class TestReceiverRecovery:
             # the first datagram's emit crashes the receive loop
             faults.inject("ingest.emit", times=1)
             tx.sendto(b"poison", addr)
-            assert _wait(lambda: rx.supervisor.restarts == 1)
+            assert wait_until(lambda: rx.supervisor.restarts == 1)
             assert rx.supervisor.restart_delays == pytest.approx([0.01])
             # restarted loop (same bound socket) keeps receiving
-            assert _wait(lambda: rx.supervisor.alive)
+            assert wait_until(lambda: rx.supervisor.alive)
             assert not rx.supervisor.escalated
 
             def feed():
@@ -144,7 +138,7 @@ class TestReceiverRecovery:
                 tx.sendto(b"after-restart", addr)
                 return got
 
-            assert _wait(feed)
+            assert wait_until(feed)
             assert got[-1] == b"after-restart"
             tx.close()
         finally:
@@ -169,13 +163,13 @@ class TestReceiverRecovery:
             tx.settimeout(5)
             assert tx.recv(1) == b""
             tx.close()
-            assert _wait(lambda: rx.connection_errors == 1)
+            assert wait_until(lambda: rx.connection_errors == 1)
             assert rx.supervisor.restarts == 0
             assert not got
             # the client's redelivery path: reconnect and resend
             tx = socket.create_connection(("127.0.0.1", rx.port), timeout=5)
             tx.sendall(b"after-reconnect\n")
-            assert _wait(lambda: got)
+            assert wait_until(lambda: got)
             assert got[-1] == b"after-reconnect"
             tx.close()
         finally:
@@ -197,7 +191,7 @@ class TestReceiverRecovery:
         try:
             tx = socket.create_connection(("127.0.0.1", rx.port), timeout=5)
             tx.sendall(b"anything\n")
-            assert _wait(lambda: rx.connection_errors == 1)
+            assert wait_until(lambda: rx.connection_errors == 1)
             assert rx.supervisor.restarts == 0
             tx.close()
         finally:
@@ -219,7 +213,7 @@ class TestReceiverRecovery:
             # the accept loop's socket dies under it (shutdown wakes a
             # BLOCKED accept — close alone would not, on Linux)
             rx._sock.shutdown(socket.SHUT_RDWR)
-            assert _wait(lambda: rx.supervisor.restarts >= 1)
+            assert wait_until(lambda: rx.supervisor.restarts >= 1)
             assert not rx.supervisor.escalated
 
             def feed():
@@ -231,9 +225,9 @@ class TestReceiverRecovery:
                     return False
                 tx.sendall(b"after-restart\n")
                 tx.close()
-                return _wait(lambda: got, timeout=1.0)
+                return wait_until(lambda: got, timeout=1.0)
 
-            assert _wait(feed)
+            assert wait_until(feed)
             assert got[-1] == b"after-restart"
             assert rx.port == port
         finally:
@@ -257,20 +251,20 @@ class TestReceiverRecovery:
         rx.sink = got.append
         rx.start()
         try:
-            assert _wait(lambda: broker.subscribes)
+            assert wait_until(lambda: broker.subscribes)
             # supervised loop (ROADMAP open item, STOMP slice)
             assert rx.supervisor is not None and rx.supervisor.alive
             assert rx.acks_on_emit  # client-individual gates ACK on emit
             faults.inject("ingest.emit", times=1)
             broker.push("m-1", b"ev-1")
-            assert _wait(lambda: rx.emit_errors == 1)
+            assert wait_until(lambda: rx.emit_errors == 1)
             assert broker.acks == []           # crashed intake: no ACK
             assert got == []
             assert rx.supervisor.restarts == 0  # crash was message-local
             # broker-side at-least-once: redelivery lands and acks
             broker.push("m-1", b"ev-1")
-            assert _wait(lambda: got == [b"ev-1"])
-            assert _wait(lambda: broker.acks == ["m-1"])
+            assert wait_until(lambda: got == [b"ev-1"])
+            assert wait_until(lambda: broker.acks == ["m-1"])
         finally:
             rx.stop()
             broker.close()
@@ -293,7 +287,7 @@ class TestReceiverRecovery:
             faults.inject("ingest.emit", times=1)
             dev.publish("sitewhere/input/dev-chaos", b"ev-1", qos=1)
             assert not dev.drain_publishes(timeout=5.0)  # no PUBACK came
-            assert _wait(lambda: rx.broker.tap_failures == 1)
+            assert wait_until(lambda: rx.broker.tap_failures == 1)
             assert got == []  # the crashed attempt delivered nothing
             dev.disconnect()
 
@@ -556,8 +550,8 @@ class TestDispatcherChaos:
             faults.inject("dispatcher.egress", times=1)
             inst.dispatcher.ingest_wire_lines(payload)
             # the offloaded egress took the plan and died on it
-            assert _wait(lambda: faults.fired("dispatcher.egress") == 1)
-            assert _wait(lambda: inst.dispatcher._egress_super.restarts >= 1)
+            assert wait_until(lambda: faults.fired("dispatcher.egress") == 1)
+            assert wait_until(lambda: inst.dispatcher._egress_super.restarts >= 1)
             assert not inst.dispatcher._egress_super.escalated
             # journaled, but the dead plan keeps the commit gate closed
             assert inst.ingest_journal.end_offset == 1
@@ -566,12 +560,13 @@ class TestDispatcherChaos:
             inst.event_store.flush()
             assert inst.event_store.total_events == 0
 
-            # the restarted worker still serves its siblings (the dead
-            # plan keeps the outstanding gate >0, so bound the flush)
+            # the restarted worker still serves its siblings (the flush
+            # ends once the sibling landed: the dead plan is all that
+            # stays outstanding)
             payload2 = _measurement_line("d-0", 8.0, 1_753_800_001).encode()
             inst.dispatcher.ingest_wire_lines(payload2)
-            assert _wait(lambda: inst.dispatcher.totals["accepted"] == 1)
-            inst.dispatcher.flush(timeout_s=0.5)
+            assert wait_until(lambda: inst.dispatcher.totals["accepted"] == 1)
+            inst.dispatcher.flush()
             inst.event_store.flush()
             assert inst.event_store.total_events == 1
             # ...but the offset STILL must not move past the dead plan
@@ -583,6 +578,7 @@ class TestDispatcherChaos:
             # semantics as a Kafka consumer rewound to its offset).
             with inst.dispatcher._lock:
                 inst.dispatcher._plans_outstanding = 0
+                inst.dispatcher._plans_failed = 0
             replayed = inst.dispatcher.replay_journal()
             assert replayed == 2
             inst.event_store.flush()
@@ -624,9 +620,9 @@ class TestDispatcherChaos:
             faults.inject("dispatcher.egress", times=1)
             inst.dispatcher.ingest_wire_lines(payload(0))
             inst.dispatcher.ingest_wire_lines(payload(1))  # chain of 2
-            assert _wait(lambda: faults.fired("dispatcher.egress") == 1)
+            assert wait_until(lambda: faults.fired("dispatcher.egress") == 1)
             assert inst.dispatcher.metrics_snapshot()["ring_chains"] == 1
-            inst.dispatcher.flush(timeout_s=0.5)
+            inst.dispatcher.flush()
             # slot 1 (the sibling step) landed; slot 0 stays outstanding
             inst.event_store.flush()
             assert inst.event_store.total_events == width
@@ -656,6 +652,7 @@ class TestDispatcherChaos:
             # (the replayed full windows ride the ring again)
             with inst.dispatcher._lock:
                 inst.dispatcher._plans_outstanding = 0
+                inst.dispatcher._plans_failed = 0
             replayed = inst.dispatcher.replay_journal()
             assert replayed == 2 * width
             inst.event_store.flush()
@@ -681,7 +678,7 @@ class TestDispatcherChaos:
                 pass  # the ingest thread itself took the plan
             # either the ingest path or the deadline-tick loop thread
             # takes the plan; whichever runs it dies at the step fault
-            assert _wait(lambda: faults.fired("dispatcher.step") == 1)
+            assert wait_until(lambda: faults.fired("dispatcher.step") == 1)
             # journaled, but the dead plan keeps the commit gate closed:
             # the offset must never move past an unprocessed record
             assert inst.ingest_journal.end_offset == 1
@@ -694,6 +691,7 @@ class TestDispatcherChaos:
             # committed offset and the row lands exactly once
             with inst.dispatcher._lock:
                 inst.dispatcher._plans_outstanding = 0
+                inst.dispatcher._plans_failed = 0
             replayed = inst.dispatcher.replay_journal()
             assert replayed == 1
             inst.event_store.flush()
@@ -728,7 +726,7 @@ class TestDispatcherChaos:
                 inst.dispatcher.ingest_wire_lines(payload)
             except ChipBurp:
                 pass  # the ingest thread took the plan itself
-            assert _wait(lambda: faults.fired("dispatcher.step") == 1)
+            assert wait_until(lambda: faults.fired("dispatcher.step") == 1)
             assert inst.ingest_journal.end_offset == 1
             inst.dispatcher.flush(timeout_s=0.05)
             # fail-closed: journaled but neither stored nor committed
@@ -740,6 +738,7 @@ class TestDispatcherChaos:
             # replay on the SAME instance — no restart, no state rebuild
             with inst.dispatcher._lock:
                 inst.dispatcher._plans_outstanding = 0
+                inst.dispatcher._plans_failed = 0
             assert inst.dispatcher.replay_journal() == 1
             inst.event_store.flush()
             assert inst.event_store.total_events == 1
@@ -867,19 +866,19 @@ class TestRemainingReceiverChaos:
         rx.sink = got.append
         rx.start()
         try:
-            assert _wait(lambda: broker.sessions == 1)
+            assert wait_until(lambda: broker.sessions == 1)
             # supervised loop (ROADMAP open item, AMQP slice)
             assert rx.supervisor is not None and rx.supervisor.alive
             faults.inject("ingest.emit", times=1)
             broker.push(b"ev-1")
-            assert _wait(lambda: rx.emit_errors == 1)
+            assert wait_until(lambda: rx.emit_errors == 1)
             # broker-native redelivery semantics: nack + requeue bit,
             # never an ack for the crashed attempt
-            assert _wait(lambda: broker.nacks == [(1, 0x02)])
+            assert wait_until(lambda: broker.nacks == [(1, 0x02)])
             # broker-side at-least-once: the requeued delivery comes
             # back and lands — zero loss across the intake crash
-            assert _wait(lambda: got == [b"ev-1"])
-            assert _wait(lambda: broker.acks == [2])
+            assert wait_until(lambda: got == [b"ev-1"])
+            assert wait_until(lambda: broker.acks == [2])
             assert rx.supervisor.restarts == 0  # crash was delivery-local
         finally:
             rx.stop()
@@ -946,8 +945,7 @@ class TestRemainingReceiverChaos:
             # slice); the crash is handled in-loop: the delivery stays
             # UNSETTLED + un-checkpointed and the link recycles, so the
             # broker redelivers — at-least-once, zero supervisor burn
-            assert _wait(lambda: sorted(got) == [b"ev-1", b"ev-2"],
-                         timeout=10.0)
+            assert wait_until(lambda: sorted(got) == [b"ev-1", b"ev-2"])
             assert rx.emit_errors == 1
             assert broker.sessions >= 2   # recycle = the redelivery cue
             assert rx.supervisors and all(s.restarts == 0
@@ -1182,7 +1180,7 @@ class TestKillMidRingRecovery:
         faults.inject("dispatcher.egress", times=2)
         a.dispatcher.ingest_wire_lines(payload(0))
         a.dispatcher.ingest_wire_lines(payload(1))
-        assert _wait(lambda: faults.fired("dispatcher.egress") == 2)
+        assert wait_until(lambda: faults.fired("dispatcher.egress") == 2)
         assert faults.crosspoint_hits() >= 1, \
             "crash.mid_ring crosspoint not on the chain-dispatch path"
         faults.disarm_crosspoint()
@@ -1224,6 +1222,23 @@ class TestKillMidRingRecovery:
             b.terminate()
 
 
+def _load_tool(name):
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+crashrec = _load_tool("crashrec_bench")
+# the command the harness runs its golden and killed children with
+CRASHREC_CHILD = [sys.executable, crashrec.__file__]
+
+
 class TestCrashRecBench:
     """tools/crashrec_bench.py: the kill-point harness itself."""
 
@@ -1241,30 +1256,35 @@ class TestCrashRecBench:
                                           "crashrec_bench.py"), *args],
             capture_output=True, text=True, timeout=timeout, env=env)
 
-    def test_smoke_three_fixed_kill_points(self, tmp_path):
-        """Tier-1: SIGKILL at mid-ring, mid-egress, mid-background-seal,
-        mid-compaction-swap, pre-manifest and mid-forward-send on a
-        small journal; every kill must recover with zero
-        committed-event loss, a consistent segment catalog,
-        golden-equal analytics, and exported recovery gauges (the
-        mid-forward case instead proves the 2-host spool-tail replay)."""
-        res = self._run("--smoke", "--json",
-                        str(tmp_path / "crashrec.json"))
-        assert res.returncode == 0, res.stdout + res.stderr
-        doc = json.loads((tmp_path / "crashrec.json").read_text())
-        assert doc["ok"] and doc["summary"]["killed"] == 6
-        points = {k["point"] for k in doc["kills"]}
-        assert {"crash.mid_seal", "crash.mid_compact",
-                "crash.mid_forward"} <= points
-        for kill in doc["kills"]:
-            assert kill["killed"] and not kill["failures"]
-            if kill["point"] == "crash.mid_forward":
-                # fleet-shaped case: the spool tail replayed to the
-                # owner's journal and drained to zero
-                assert kill["spool_pending_after"] == 0
-                assert kill["owner_journal_rows"] >= kill["spooled_rows"]
-            else:
-                assert kill["restore_s"] is not None
+    @pytest.fixture(scope="class")
+    def golden(self, tmp_path_factory):
+        """The uninterrupted run every kill case is held to: one per
+        class, in the first case's setup."""
+        root = str(tmp_path_factory.mktemp("crashrec"))
+        return root, crashrec.golden_run(root, CRASHREC_CHILD)[0]
+
+    @pytest.mark.parametrize("case", range(len(crashrec.SMOKE_KILLS)),
+                             ids=[p for p, _ in crashrec.SMOKE_KILLS])
+    def test_smoke_kill_point(self, golden, case):
+        """Tier-1, the tool's ``--smoke`` one kill point at a time: SIGKILL
+        at mid-ring, mid-egress, mid-background-seal, mid-compaction-swap,
+        pre-manifest and mid-forward-send on a small journal; every kill
+        must recover with zero committed-event loss, a consistent segment
+        catalog, golden-equal analytics, and exported recovery gauges
+        (the mid-forward case instead proves the 2-host spool-tail
+        replay)."""
+        root, golden_matches = golden
+        point, hits = crashrec.SMOKE_KILLS[case]
+        kill = crashrec.kill_case(root, case, point, hits, golden_matches,
+                                  CRASHREC_CHILD)
+        assert kill["killed"] and not kill["failures"], kill
+        if point == "crash.mid_forward":
+            # fleet-shaped case: the spool tail replayed to the
+            # owner's journal and drained to zero
+            assert kill["spool_pending_after"] == 0
+            assert kill["owner_journal_rows"] >= kill["spooled_rows"]
+        else:
+            assert kill["restore_s"] is not None
 
     @pytest.mark.slow
     def test_randomized_sweep(self, tmp_path):

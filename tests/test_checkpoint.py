@@ -17,6 +17,8 @@ import pytest
 from sitewhere_tpu.instance import Instance
 from sitewhere_tpu.runtime.config import Config
 
+from time_limit import wait_until
+
 
 def _cfg(tmp_path, **over):
     doc = {
@@ -160,11 +162,7 @@ def test_periodic_checkpointer_runs(tmp_path):
     a = Instance(cfg)
     a.start()
     try:
-        deadline = time.monotonic() + 5.0
-        while a.checkpointer.last_saved_at is None \
-                and time.monotonic() < deadline:
-            time.sleep(0.05)
-        assert a.checkpointer.last_saved_at is not None
+        assert wait_until(lambda: a.checkpointer.last_saved_at is not None)
         assert a.checkpointer.generation >= 0
     finally:
         a.stop()

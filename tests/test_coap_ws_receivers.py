@@ -9,13 +9,24 @@ pulling payloads from a remote endpoint).
 import json
 import socket
 import threading
-import time
 
 import pytest
 
 from sitewhere_tpu.ingest import coap
-from sitewhere_tpu.ingest.sources import InboundEventSource, WebSocketReceiver
+from sitewhere_tpu.ingest.sources import (
+    InboundEventSource,
+    WebSocketReceiver,
+    close_socket,
+)
 from sitewhere_tpu.ingest.decoders import JsonDecoder
+
+from time_limit import wait_until
+
+
+def _stored(inst):
+    """Flush the pipeline; the events stored so far."""
+    inst.dispatcher.flush()
+    return inst.event_store.total_events
 
 
 # --------------------------------------------------------------------------
@@ -106,9 +117,7 @@ def test_non_post_emits_without_reply(coap_server):
     req = coap.encode_post("/events", b'{"v":2}', message_id=43,
                            confirmable=False)
     _udp_exchange(recv.port, req, expect_reply=False)
-    deadline = time.monotonic() + 3
-    while not got and time.monotonic() < deadline:
-        time.sleep(0.02)
+    assert wait_until(lambda: got)
     assert got == [b'{"v":2}']
 
 
@@ -167,11 +176,7 @@ def test_coap_source_end_to_end_pipeline(tmp_path):
         reply = _udp_exchange(
             recv.port, coap.encode_post("/events", payload, message_id=1))
         assert reply.code == coap.CHANGED_204
-        deadline = time.monotonic() + 5
-        while inst.event_store.total_events < 1 \
-                and time.monotonic() < deadline:
-            inst.dispatcher.flush()
-            time.sleep(0.05)
+        assert wait_until(lambda: _stored(inst) >= 1)
         assert inst.event_store.total_events == 1
     finally:
         inst.stop()
@@ -224,7 +229,7 @@ class _TinyWsServer:
 
     def stop(self):
         self._alive = False
-        self.sock.close()
+        close_socket(self.sock)   # wakes the blocked accept
 
 
 def test_ws_receiver_pulls_payloads_and_reconnects():
@@ -236,12 +241,10 @@ def test_ws_receiver_pulls_payloads_and_reconnects():
     recv.sink = got.append
     recv.start()
     try:
-        deadline = time.monotonic() + 5
         # server closes after each session; the receiver reconnects and
         # pulls the payloads again — expect at least two sessions' worth
-        while (len(got) < 4 or server.sessions < 2) \
-                and time.monotonic() < deadline:
-            time.sleep(0.05)
+        assert wait_until(
+            lambda: len(got) >= 4 and server.sessions >= 2)
         assert server.sessions >= 2
         assert got[:2] == payloads
         assert recv.connects >= 2
@@ -279,12 +282,7 @@ def test_ws_receiver_source_end_to_end(tmp_path):
     dm.create_device_assignment(device="w-1")
     inst.start()
     try:
-        deadline = time.monotonic() + 5
-        while inst.event_store.total_events < 1 \
-                and time.monotonic() < deadline:
-            inst.dispatcher.flush()
-            time.sleep(0.05)
-        assert inst.event_store.total_events >= 1
+        assert wait_until(lambda: _stored(inst) >= 1)
     finally:
         inst.stop()
         inst.terminate()

@@ -22,6 +22,8 @@ from sitewhere_tpu.services.event_store import (
     split_event_id,
 )
 
+from time_limit import wait_until
+
 
 def make_cols(n, *, device=None, area=None, etype=int(EventType.MEASUREMENT), ts0=1000):
     cols = {}
@@ -145,10 +147,8 @@ def test_interval_flusher_thread(tmp_path):
     store.start()
     try:
         store.append_columns(make_cols(3))
-        deadline = time.monotonic() + 2
-        while time.monotonic() < deadline and not store._chunks:
-            time.sleep(0.01)
-        assert store._chunks and store._chunks[0].n == 3
+        assert wait_until(lambda: store._chunks)
+        assert store._chunks[0].n == 3
     finally:
         store.stop()
 
@@ -652,10 +652,8 @@ def test_started_store_seals_on_flusher_thread(tmp_path):
     store.start()
     try:
         store.append_columns(make_cols(40))
-        deadline = time.monotonic() + 5.0
-        while time.monotonic() < deadline and not store._chunks:
-            time.sleep(0.01)
-        assert store._chunks and store._chunks[0].n == 40
+        assert wait_until(lambda: store._chunks)
+        assert store._chunks[0].n == 40
         assert store.total_events == 40
     finally:
         store.stop()

@@ -25,16 +25,9 @@ from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.dispatcher import PipelineDispatcher
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
 
+from time_limit import WAIT_S, wait_until
+
 WIDTH = 8
-
-
-def _wait(cond, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if cond():
-            return True
-        time.sleep(0.002)
-    return cond()
 
 
 # ---------------------------------------------------------------------------
@@ -380,18 +373,62 @@ class TestEgressOffload:
         try:
             faults.inject("dispatcher.egress", times=1)
             ingest_window(disp)           # this plan's egress dies
-            assert _wait(lambda: faults.fired("dispatcher.egress") == 1)
+            assert wait_until(lambda: faults.fired("dispatcher.egress") == 1)
             ingest_window(disp)           # sibling must still egress
-            disp.flush(timeout_s=1.0)
+            disp.flush()                  # ends once the sibling landed
             assert store.rows == WIDTH    # only the sibling landed
             assert disp.egress_failures == 1
-            assert _wait(lambda: disp._egress_super.restarts >= 1)
+            assert wait_until(lambda: disp._egress_super.restarts >= 1)
             assert not disp._egress_super.escalated
             with disp._lock:
                 # the dead plan is still outstanding: gate failed closed
-                assert disp._plans_outstanding == 1
+                assert disp._plans_outstanding == disp._plans_failed == 1
         finally:
             faults.clear()
+            disp.stop()
+
+    def test_a_raise_after_the_release_leaves_flush_waiting_for_live_plans(
+            self):
+        """A plan whose egress raised after it left the outstanding count
+        (here, in its flight record) is no plan for flush to skip: a later
+        flush still waits for a plan another thread is stepping."""
+
+        class Recorder:
+            def record(self, **rec):
+                pass
+
+            def anomaly(self, kind, detail=""):
+                pass
+
+        disp, store, _ = make_dispatcher(step_s=0.5)
+        disp.flightrec = Recorder()
+        raised = []
+
+        def flight_record(plan, out, replay_depth, commit, **kw):
+            if commit == "ok" and not raised:
+                raised.append(plan.seq)
+                raise RuntimeError("flight record failed")
+
+        disp._flight_record = flight_record
+        disp.start()
+        try:
+            ingest_window(disp)
+            disp.flush()
+            assert raised and disp.egress_failures == 1
+            assert store.rows == WIDTH
+            with disp._lock:
+                assert disp._plans_outstanding == disp._plans_failed == 0
+            # the live plan: outstanding from its take, then stepping
+            # (0.5 s) on another thread, in neither the batcher nor the
+            # egress window
+            t = threading.Thread(target=ingest_window, args=(disp,))
+            t.start()
+            assert wait_until(lambda: disp._plans_outstanding == 1)
+            disp.flush()
+            assert store.rows == 2 * WIDTH
+            t.join(timeout=WAIT_S)
+            assert not t.is_alive()
+        finally:
             disp.stop()
 
 
@@ -503,14 +540,14 @@ class TestDeviceResidentRing:
             faults.inject("dispatcher.egress", times=1)
             ingest_window_at(disp, 0)
             ingest_window_at(disp, 8)   # chain of 2 dispatches here
-            assert _wait(lambda: faults.fired("dispatcher.egress") == 1)
-            disp.flush(timeout_s=1.0)
+            assert wait_until(lambda: faults.fired("dispatcher.egress") == 1)
+            disp.flush()                        # ends once slot 1 landed
             assert store.rows == WIDTH          # only the sibling landed
             assert disp.egress_failures == 1
-            assert _wait(lambda: disp._egress_super.restarts >= 1)
+            assert wait_until(lambda: disp._egress_super.restarts >= 1)
             assert not disp._egress_super.escalated
             with disp._lock:
-                assert disp._plans_outstanding == 1
+                assert disp._plans_outstanding == disp._plans_failed == 1
         finally:
             faults.clear()
             disp.stop()

@@ -7,7 +7,6 @@ fused step → persistence/state/registration/replay/derived alerts.
 """
 
 import json
-import time
 
 import numpy as np
 import pytest
@@ -16,6 +15,8 @@ from sitewhere_tpu.ingest.decoders import DecodedRequest, RequestKind
 from sitewhere_tpu.instance import Instance, InstanceTemplate
 from sitewhere_tpu.runtime.config import Config
 from sitewhere_tpu.schema import ComparisonOp, EventType
+
+from time_limit import wait_until
 
 
 def make_config(tmp_path, **pipeline):
@@ -209,11 +210,8 @@ class TestDispatchLoop:
         seed_device(inst)
         inst.dispatcher.ingest(measurement("dev-1", 5.0))
         # background loop must emit within a few deadlines without flush
-        deadline = time.monotonic() + 2.0
-        while time.monotonic() < deadline:
-            if inst.dispatcher.metrics_snapshot()["accepted"] >= 1:
-                break
-            time.sleep(0.01)
+        assert wait_until(
+            lambda: inst.dispatcher.metrics_snapshot()["accepted"] >= 1)
         assert inst.dispatcher.metrics_snapshot()["accepted"] == 1
         inst.stop()
         inst.terminate()

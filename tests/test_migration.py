@@ -9,7 +9,6 @@ remap + record handoff + spool requeue (``rpc/migration.py``,
 
 import json
 import socket
-import time
 
 import numpy as np
 import pytest
@@ -18,6 +17,8 @@ from sitewhere_tpu.instance import Instance
 from sitewhere_tpu.rpc import owning_process
 from sitewhere_tpu.runtime.config import Config
 from sitewhere_tpu.services.common import SearchCriteria
+
+from time_limit import wait_until
 
 
 def free_port():
@@ -148,16 +149,14 @@ def test_grow_membership_hands_off_records(tmp_path):
             "request": {"name": "t", "value": 7.0,
                         "eventDate": 6000}}).encode()
         insts[0].forwarder.ingest_payload(line)
-        deadline = time.time() + 15
-        while time.time() < deadline:
+
+        def landed():
             insts[0].forwarder.flush(wait=True)
             third.dispatcher.flush()
-            if third.device_state.get_device_state(probe)[
-                    "last_event_ts_s"] == 6000:
-                break
-            time.sleep(0.1)
-        assert third.device_state.get_device_state(probe)[
-            "last_event_ts_s"] == 6000
+            return third.device_state.get_device_state(probe)[
+                "last_event_ts_s"] == 6000
+
+        assert wait_until(landed)
         insts.append(third)
     finally:
         for inst in insts:
@@ -218,13 +217,12 @@ def test_kill_host_replace_with_new_loses_nothing(tmp_path):
                     inst.apply_membership_change(peers_new)
             fwd.ingest_payload(batch(i))
             fwd.flush()
-        deadline = time.time() + 30
-        while time.time() < deadline:
+
+        def drained():
             fwd.flush(wait=True)
-            if fwd.metrics()["pending"] == 0:
-                break
-            time.sleep(0.2)
-        assert fwd.metrics()["pending"] == 0
+            return fwd.metrics()["pending"] == 0
+
+        assert wait_until(drained)
         assert fwd.dead_lettered == 0
 
         # no event loss: every host-2-owned row sent AFTER its death is

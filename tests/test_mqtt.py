@@ -9,7 +9,6 @@ import socket
 import socketserver
 import struct
 import threading
-import time
 
 from sitewhere_tpu.ingest.mqtt import (
     CONNACK,
@@ -25,18 +24,23 @@ from sitewhere_tpu.ingest.mqtt import (
     write_publish,
 )
 
+from time_limit import wait_until
+
 
 class MiniBroker:
     def __init__(self):
         self.subscribers = []  # (sock, topic_filter)
         self.published = []    # (topic, payload, qos)
         self.pubacks = []      # packet ids acked by clients
+        self.conns = []        # every client connection, for close()
         self.lock = threading.Lock()
         broker = self
 
         class Handler(socketserver.BaseRequestHandler):
             def handle(self):
                 sock = self.request
+                with broker.lock:
+                    broker.conns.append(sock)
                 try:
                     while True:
                         ptype, flags, body = read_packet(sock)
@@ -78,16 +82,16 @@ class MiniBroker:
 
     def close(self):
         self.server.shutdown()
+        # server_close() joins the handlers: wake any still reading a
+        # client that never disconnected
+        with self.lock:
+            conns = list(self.conns)
+        for sock in conns:
+            try:
+                sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
         self.server.server_close()
-
-
-def wait_for(pred, timeout=5.0):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if pred():
-            return True
-        time.sleep(0.01)
-    return False
 
 
 def test_connect_subscribe_publish_roundtrip():
@@ -105,9 +109,11 @@ def test_connect_subscribe_publish_roundtrip():
         pub.publish("sitewhere/input", b"hello-1", qos=1)
         pub.publish("other/topic", b"not-for-us", qos=0)
 
-        assert wait_for(lambda: len(got) == 2)
+        assert wait_until(lambda: len(got) == 2)
         assert got == [("sitewhere/input", b"hello-0"),
                        ("sitewhere/input", b"hello-1")]
+        # the broker may take the third publish after the second is out
+        assert wait_until(lambda: len(broker.published) == 3)
         assert broker.published[-1][0] == "other/topic"
         pub.disconnect()
         sub.disconnect()
@@ -136,7 +142,7 @@ def test_mqtt_receiver_through_source():
             "deviceToken": "mq-dev", "type": "Measurement",
             "request": {"name": "rpm", "value": 1200.0},
         }).encode())
-        assert wait_for(lambda: len(events) == 1)
+        assert wait_until(lambda: len(events) == 1)
         assert events[0].device_token == "mq-dev"
         assert events[0].value == 1200.0
         pub.disconnect()
@@ -159,8 +165,8 @@ def test_qos1_puback_sent_by_client():
         with broker.lock:
             ssock = broker.subscribers[0][0]
         write_publish(ssock, "t", b"payload", qos=1, packet_id=77)
-        assert wait_for(lambda: got == [b"payload"])
-        assert wait_for(lambda: 77 in broker.pubacks)
+        assert wait_until(lambda: got == [b"payload"])
+        assert wait_until(lambda: 77 in broker.pubacks)
         sub.disconnect()
     finally:
         broker.close()

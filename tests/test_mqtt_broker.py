@@ -21,14 +21,7 @@ from sitewhere_tpu.ingest.mqtt_broker import (
     topic_matches,
 )
 
-
-def _wait(predicate, timeout=5.0):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if predicate():
-            return True
-        time.sleep(0.01)
-    return False
+from time_limit import wait_until
 
 
 class TestTopicMatching:
@@ -63,10 +56,10 @@ def test_device_publishes_into_hosted_broker():
         dev.connect()
         dev.publish("sitewhere/input/dev-1", b'{"deviceToken":"dev-1"}')
         dev.publish("sitewhere/other/dev-1", b"ignored")  # filter miss
-        assert _wait(lambda: rx.broker.published == 2)
+        assert wait_until(lambda: rx.broker.published == 2)
         assert got == [b'{"deviceToken":"dev-1"}']
         dev.disconnect()
-        assert _wait(lambda: rx.broker.session_count == 0)
+        assert wait_until(lambda: rx.broker.session_count == 0)
     finally:
         rx.stop()
 
@@ -85,7 +78,7 @@ def test_qos1_publish_gets_puback():
         m.write_publish(sock, "sitewhere/input/x", b"p1", qos=1,
                         packet_id=77)
         # the client pump consumes the PUBACK; assert delivery instead
-        assert _wait(lambda: got == [b"p1"])
+        assert wait_until(lambda: got == [b"p1"])
         dev.disconnect()
     finally:
         rx.stop()
@@ -108,7 +101,7 @@ def test_fanout_between_subscribed_clients():
         dev.publish("fleet/dev-2/telemetry", b"t0", qos=0)
         dev.publish("fleet/dev-2/telemetry", b"t1", qos=1)
         dev.publish("fleet/dev-2/status", b"nope", qos=0)
-        assert _wait(lambda: len(seen) == 2)
+        assert wait_until(lambda: len(seen) == 2)
         assert seen == [("fleet/dev-2/telemetry", b"t0"),
                         ("fleet/dev-2/telemetry", b"t1")]
         assert broker.delivered == 2
@@ -124,15 +117,15 @@ def test_client_id_takeover_replaces_old_session():
     try:
         first = MqttClient("127.0.0.1", broker.port, client_id="same-id")
         first.connect()
-        assert _wait(lambda: broker.session_count == 1)
+        assert wait_until(lambda: broker.session_count == 1)
         second = MqttClient("127.0.0.1", broker.port, client_id="same-id")
         second.connect()
         # wait for the second CONNECT to be processed FIRST — session_count
         # is 1 both before and after the takeover, so waiting on it alone
         # races the broker's accept loop
-        assert _wait(lambda: broker.connects == 2)
+        assert wait_until(lambda: broker.connects == 2)
         # old socket is closed by the broker (MQTT-3.1.4-2)
-        assert _wait(lambda: broker.session_count == 1)
+        assert wait_until(lambda: broker.session_count == 1)
         second.publish("t", b"alive")
         second.disconnect()
         first.disconnect()
@@ -152,7 +145,7 @@ def test_unsubscribe_stops_delivery():
         pub = MqttClient("127.0.0.1", broker.port, client_id="p")
         pub.connect()
         pub.publish("a/b", b"one")
-        assert _wait(lambda: seen == [b"one"])
+        assert wait_until(lambda: seen == [b"one"])
         # UNSUBSCRIBE over the raw socket (the client has no helper)
         from sitewhere_tpu.ingest import mqtt as m
         body = struct.pack(">H", 9) + m._utf8("a/b")
@@ -203,7 +196,7 @@ def test_keepalive_timeout_reaps_dead_session():
         assert (ptype, ack[1]) == (m.CONNACK, 0)
         assert broker.session_count == 1
         # no pings: the broker must reap within ~1.5x keepalive
-        assert _wait(lambda: broker.session_count == 0, timeout=5.0)
+        assert wait_until(lambda: broker.session_count == 0)
         sock.close()
     finally:
         broker.stop()
@@ -236,11 +229,11 @@ def test_broker_receiver_feeds_instance_pipeline(tmp_path):
             b'{"deviceToken":"dev-1","type":"Measurement",'
             b'"request":{"name":"temp","value":21.5,"eventDate":1000}}',
             qos=1)
-        assert _wait(lambda: rx.received_count == 1)
+        assert wait_until(lambda: rx.received_count == 1)
         # received_count ticks BEFORE the sink runs (Receiver._emit);
         # wait for admission too, or the flush below can race the
         # broker-session thread's ingest and observe an empty store
-        assert _wait(
+        assert wait_until(
             lambda: inst.dispatcher.metrics_snapshot()["accepted"] >= 1)
         inst.dispatcher.flush()
         inst.event_store.flush()
@@ -284,7 +277,7 @@ def test_burst_publish_then_disconnect_loses_nothing():
                 c.publish("fleet/burst/events",
                           b"m%d-%d" % (round_no, i), qos=1)
             c.disconnect()  # immediately — no settling sleep
-        assert _wait(lambda: len(seen) == 100)
+        assert wait_until(lambda: len(seen) == 100)
         assert seen == [b"m%d-%d" % (r, i)
                         for r in range(5) for i in range(20)]
     finally:
@@ -366,7 +359,7 @@ def test_command_delivery_through_hosted_broker(tmp_path):
                 command_id=handle,
                 event_type=int(EventType.COMMAND_RESPONSE)).total == 1
 
-        assert _wait(correlated, timeout=10)
+        assert wait_until(correlated)
         assert inst.commands.delivered == 1
     finally:
         if dev is not None:
@@ -438,10 +431,8 @@ def test_shed_pause_applied_on_overload(monkeypatch):
                             client_id="dev-shed", keepalive=60)
         client.connect()
         client.publish("sitewhere/input/x", b"{}", qos=0)
-        deadline = time.monotonic() + 5
-        while not pauses and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert pauses and pauses[0] == pytest.approx(30.0)
+        assert wait_until(lambda: pauses)
+        assert pauses[0] == pytest.approx(30.0)
         assert broker.sheds == 1
         client.disconnect()
     finally:

@@ -36,6 +36,8 @@ from sitewhere_tpu.runtime.metrics import (
 )
 from sitewhere_tpu.runtime.tracing import Tracer
 
+from time_limit import WAIT_S
+
 
 # ---------------------------------------------------------------------------
 # registry under concurrent writers
@@ -85,9 +87,10 @@ class TestSnapshotConcurrency:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT_S)
         stop.set()
-        rt.join()
+        rt.join(WAIT_S)
+        assert not any(t.is_alive() for t in threads + [rt])
 
         assert errors == []
         snap = reg.snapshot()

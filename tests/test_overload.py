@@ -33,14 +33,7 @@ from sitewhere_tpu.runtime.overload import (
     classify_event_type,
 )
 
-
-def _wait(cond, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if cond():
-            return True
-        time.sleep(0.005)
-    return cond()
+from time_limit import wait_until
 
 
 class FakeClock:
@@ -351,7 +344,7 @@ class TestDegradationLadder:
         release = []
 
         def slow(cols, mask):
-            _wait(lambda: release, timeout=5.0)
+            wait_until(lambda: release)
 
         mgr = OutboundConnectorsManager([CallbackConnector("slow", slow)])
         mgr.start()
@@ -381,7 +374,10 @@ def _instance_config(tmp_path, overload=None, **pipeline):
                      "mtype_slots": 4, "deadline_ms": 5.0, "n_shards": 1,
                      **pipeline},
         "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
-        "overload": {"enabled": True, **(overload or {})},
+        # a state the test forces holds until the test moves it: the
+        # loop's tick de-escalates only after an hour of calm
+        "overload": {"enabled": True, "cooldown_s": 3600.0,
+                     **(overload or {})},
     }, apply_env=False)
 
 
@@ -650,7 +646,7 @@ class TestProtocolBackpressure:
                         _measurement("d-0", 1.0).encode(), qos=1)
             # the PUBACK is WITHHELD (the device's redelivery cue)...
             assert not dev.drain_publishes(timeout=1.0)
-            assert _wait(lambda: rx.broker.sheds == 1)
+            assert wait_until(lambda: rx.broker.sheds == 1)
             # ...but the session survives: shedding is flow control
             assert rx.broker.session_count == 1
             assert rx.broker.tap_failures == 0   # shed ≠ fault
@@ -688,16 +684,16 @@ class TestProtocolBackpressure:
         rx.sink = sink
         rx.start()
         try:
-            assert _wait(lambda: broker.subscribes)
+            assert wait_until(lambda: broker.subscribes)
             broker.push("m-1", b"ev-1")
-            assert _wait(lambda: rx.sheds == 1)
+            assert wait_until(lambda: rx.sheds == 1)
             time.sleep(0.05)
             assert broker.acks == []       # unacked → broker redelivers
             assert rx.emit_errors == 0     # shed is not a fault
             shedding[0] = False
             broker.push("m-1", b"ev-1")    # broker-side redelivery
-            assert _wait(lambda: got == [b"ev-1"])
-            assert _wait(lambda: broker.acks == ["m-1"])
+            assert wait_until(lambda: got == [b"ev-1"])
+            assert wait_until(lambda: broker.acks == ["m-1"])
         finally:
             rx.stop()
             broker.close()
@@ -726,18 +722,18 @@ class TestProtocolBackpressure:
         rx.sink = sink
         rx.start()
         try:
-            assert _wait(lambda: broker.sessions == 1)
+            assert wait_until(lambda: broker.sessions == 1)
             broker.push(b"telemetry-1")
-            assert _wait(lambda: rx.sheds >= 1)
+            assert wait_until(lambda: rx.sheds >= 1)
             # nacked with the requeue bit — broker-native redelivery
-            assert _wait(lambda: len(broker.nacks) >= 1)
+            assert wait_until(lambda: len(broker.nacks) >= 1)
             assert broker.nacks[0][1] == 0x02
             assert rx.emit_errors == 0     # shed is not a fault
             assert rx.nacked == 0          # ...and not a sink failure
             shedding[0] = False            # overload clears
             # the requeued redelivery lands and acks
-            assert _wait(lambda: b"telemetry-1" in got)
-            assert _wait(lambda: len(broker.acks) >= 1)
+            assert wait_until(lambda: b"telemetry-1" in got)
+            assert wait_until(lambda: len(broker.acks) >= 1)
         finally:
             rx.stop()
             broker.close()
@@ -755,7 +751,7 @@ class TestProtocolBackpressure:
         try:
             client = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
             client.sendto(b"telemetry", ("127.0.0.1", rx.port))
-            assert _wait(lambda: rx.sheds == 1)
+            assert wait_until(lambda: rx.sheds == 1)
             assert rx.supervisor.restarts == 0   # not treated as a crash
             client.close()
         finally:

@@ -27,6 +27,8 @@ from sitewhere_tpu.runtime.resilience import (
     dead_letter,
 )
 
+from time_limit import wait_until
+
 
 # ---------------------------------------------------------------------------
 # RetryPolicy / Backoff
@@ -266,15 +268,6 @@ class TestCircuitBreaker:
 # Supervisor (satellite: permanent failure must escalate, not spin)
 # ---------------------------------------------------------------------------
 
-def _wait(cond, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if cond():
-            return True
-        time.sleep(0.005)
-    return cond()
-
-
 class TestSupervisor:
     def test_transient_failures_restart_with_backoff(self):
         reg = MetricsRegistry()
@@ -318,7 +311,7 @@ class TestSupervisor:
                          on_escalate=escalations.append, metrics=reg)
         with caplog.at_level("ERROR", logger="sitewhere_tpu.resilience"):
             sup.start()
-            assert _wait(lambda: not sup.alive)
+            assert wait_until(lambda: not sup.alive)
         assert sup.escalated
         assert calls["n"] == sup.max_restarts + 1  # initial run + restarts
         assert sup.restarts == sup.max_restarts
@@ -341,7 +334,7 @@ class TestSupervisor:
 
         sup = Supervisor("oneshot", once, metrics=MetricsRegistry())
         sup.start()
-        assert _wait(lambda: not sup.alive)
+        assert wait_until(lambda: not sup.alive)
         assert calls["n"] == 1
         assert sup.restarts == 0
 
@@ -351,7 +344,7 @@ class TestSupervisor:
             policy=RetryPolicy(initial_s=60.0), max_restarts=8,
             metrics=MetricsRegistry())
         sup.start()
-        assert _wait(lambda: sup.restarts >= 1 or sup.last_error)
+        assert wait_until(lambda: sup.restarts >= 1 or sup.last_error)
         t0 = time.monotonic()
         sup.stop()
         assert time.monotonic() - t0 < 10.0

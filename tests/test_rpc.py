@@ -32,6 +32,8 @@ from sitewhere_tpu.rpc import (
 )
 from sitewhere_tpu.security.jwt import TokenManagement
 
+from time_limit import WAIT_S, wait_until
+
 
 # ---------------------------------------------------------------------------
 # wire framing
@@ -149,7 +151,8 @@ class TestServerChannel:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT_S)
+        assert not any(t.is_alive() for t in threads)
         assert results == {i: i for i in range(16)}
         chan.close()
 
@@ -473,6 +476,12 @@ class TestDomainServices:
 # keyed cross-host forwarding (two Instances = two "hosts")
 # ---------------------------------------------------------------------------
 
+def _forwarded(fwd):
+    """Ship what is pending; the rows forwarded so far."""
+    fwd.flush(wait=True)
+    return fwd.forwarded_rows
+
+
 class TestForwarding:
     def test_owning_process_stable(self):
         assert owning_process("dev-1", 4) == owning_process("dev-1", 4)
@@ -513,6 +522,41 @@ class TestForwarding:
         by_owner = split_lines(payload, 2)
         locals_ = by_owner.get(-1, [])
         assert len(locals_) == 2   # bad line + tokenless line
+
+    def test_flush_wait_never_joins_an_unstarted_sender(self, monkeypatch):
+        """A sender enters ``_senders`` and starts under one hold of the
+        lock, so ``flush(wait=True)`` racing ``_send_async`` never meets
+        "cannot join thread before it is started".  The race is held
+        open: the sender's ``start`` waits for the flush to be done, or
+        half a second once the flush is shut out by the lock."""
+        import types
+
+        from sitewhere_tpu.rpc import forward
+
+        fwd = HostForwarder(None, 0, {0: None, 1: RpcDemux(["127.0.0.1:1"])})
+        starting, flushed = threading.Event(), threading.Event()
+
+        class HeldThread(threading.Thread):
+            def start(self):
+                starting.set()
+                flushed.wait(0.5)
+                super().start()
+
+        shim = types.ModuleType("threading")
+        shim.__dict__.update(vars(threading))
+        shim.Thread = HeldThread
+        monkeypatch.setattr(forward, "threading", shim)
+        caller = threading.Thread(target=fwd._send_async, args=(1,))
+        caller.start()
+        try:
+            assert starting.wait(WAIT_S)
+            fwd.flush(wait=True)
+        finally:
+            flushed.set()
+            caller.join(WAIT_S)
+        assert not caller.is_alive()
+        with fwd._lock:
+            assert not fwd._senders and not fwd._active_owners
 
     @pytest.fixture()
     def two_hosts(self, tmp_path):
@@ -566,9 +610,7 @@ class TestForwarding:
             accepted = fwd.ingest_payload(b"\n".join(lines))
             assert accepted == 2          # local rows only
             fwd.flush()
-            deadline = time.time() + 10
-            while time.time() < deadline and fwd.forwarded_rows < 2:
-                time.sleep(0.05)
+            assert wait_until(lambda: fwd.forwarded_rows >= 2)
             assert fwd.forwarded_rows == 2
         finally:
             fwd.stop()
@@ -614,9 +656,7 @@ class TestForwarding:
                 b' "request": {"name": "t", "value": 1,'
                 b' "eventDate": 1000}}' % tok1.encode())
             fwd.flush()
-            deadline = time.time() + 10
-            while time.time() < deadline and fwd.forwarded_rows < 1:
-                time.sleep(0.05)
+            assert wait_until(lambda: fwd.forwarded_rows >= 1)
             assert fwd.forwarded_rows == 1
         finally:
             fwd.stop()
@@ -696,12 +736,8 @@ class TestForwarding:
                                     "eventDate": 1000},
                     }).encode()
                     s.sendall(struct.pack(">I", len(payload)) + payload)
-            deadline = time.time() + 15
-            while time.time() < deadline:
-                if insts[0].forwarder.forwarded_rows >= 2:
-                    break
-                insts[0].forwarder.flush(wait=True)
-                time.sleep(0.05)
+            assert wait_until(
+                lambda: _forwarded(insts[0].forwarder) >= 2)
             assert insts[0].forwarder.forwarded_rows == 2
             for inst in insts:
                 inst.dispatcher.flush()
@@ -819,11 +855,10 @@ class TestForwarding:
                                  dead_letters=inst.dead_letters,
                                  deadline_ms=5.0, data_dir=spool_dir)
             fwd2.start()
-            deadline = time.time() + 10
-            while time.time() < deadline and fwd2.forwarded_rows < 1:
-                time.sleep(0.05)
+            # the spool commits its tail after the row is counted sent
+            assert wait_until(lambda: fwd2.forwarded_rows >= 1
+                              and fwd2.metrics()["pending"] == 0)
             assert fwd2.forwarded_rows == 1
-            assert fwd2.metrics()["pending"] == 0
             peer.dispatcher.flush()
             peer.event_store.flush()
             d = int(peer.identity.device.lookup(tok))
@@ -905,11 +940,8 @@ class TestForwarding:
             assert local._peer_demuxes[1].endpoints == [srv_b.endpoint]
 
             local.forwarder.ingest_payload(line)
-            deadline = time.time() + 10
-            while (time.time() < deadline
-                   and local.forwarder.forwarded_rows < 2):
-                local.forwarder.flush(wait=True)
-                time.sleep(0.05)
+            assert wait_until(
+                lambda: _forwarded(local.forwarder) >= 2)
             assert local.forwarder.forwarded_rows == 2
             assert local.forwarder.dead_lettered == 0
 
@@ -958,12 +990,7 @@ class TestForwarding:
             # wait out the in-flight sender: mid-poll the reader position
             # sits past the record until the failure seeks back, so
             # pending only settles once no sender is running
-            deadline = time.time() + 10
-            while time.time() < deadline:
-                with fwd._lock:
-                    if not fwd._senders:
-                        break
-                time.sleep(0.05)
+            assert wait_until(lambda: not fwd._senders)
             assert fwd.metrics()["pending"] == 1   # retained, not lost
         finally:
             fwd.stop()
@@ -1263,9 +1290,7 @@ class TestDeadlinePropagation:
             with faults.net_injected(srv.endpoint, drop=1.0, one_way=True):
                 with pytest.raises(ChannelUnavailable):
                     chan.call("work.do", {}, timeout_s=0.4)
-            deadline = time.time() + 5
-            while time.time() < deadline and not ran:
-                time.sleep(0.01)
+            assert wait_until(lambda: ran)
             assert ran == [1]
         finally:
             chan.close()
@@ -1569,17 +1594,11 @@ class TestHealthGatedForwarding:
         fwd.start()
         try:
             inst.overload.force(OverloadState.SHEDDING, reason="test")
-            deadline = time.time() + 10
-            while time.time() < deadline and fwd.health.overload_state(1) \
-                    != int(OverloadState.SHEDDING):
-                time.sleep(0.02)
-            assert fwd.health.overload_state(1) == int(OverloadState.SHEDDING)
+            assert wait_until(lambda: fwd.health.overload_state(1)
+                              == int(OverloadState.SHEDDING))
             assert fwd.health.state(1) == PeerState.ALIVE
             inst.overload.force(OverloadState.NORMAL, reason="test-done")
-            deadline = time.time() + 10
-            while time.time() < deadline and fwd.health.overload_state(1):
-                time.sleep(0.02)
-            assert fwd.health.overload_state(1) == 0
+            assert wait_until(lambda: fwd.health.overload_state(1) == 0)
         finally:
             fwd.stop()
             demux.close()

@@ -9,7 +9,6 @@ list, built at start and attached through ``Instance.add_source``.
 import json
 import socket
 import struct
-import time
 
 import pytest
 
@@ -18,14 +17,7 @@ from sitewhere_tpu.instance import Instance
 from sitewhere_tpu.runtime.config import Config
 from sitewhere_tpu.services.common import ValidationError
 
-
-def _wait(pred, timeout=5.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if pred():
-            return True
-        time.sleep(0.01)
-    return False
+from time_limit import wait_until
 
 
 def test_factory_rejects_bad_declarations():
@@ -90,7 +82,7 @@ def test_instance_boots_config_sources_and_ingests(tmp_path):
         }).encode()
         with socket.create_connection(("127.0.0.1", rx.port), timeout=5) as s:
             s.sendall(struct.pack(">I", len(payload)) + payload)
-        assert _wait(lambda: src.decoded_count >= 1)
+        assert wait_until(lambda: src.decoded_count >= 1)
 
         # decoded_count can tick before the row lands in the batcher
         # (the source thread is mid-forward), so a single flush may run
@@ -99,7 +91,7 @@ def test_instance_boots_config_sources_and_ingests(tmp_path):
             inst.dispatcher.flush()
             return inst.event_store.total_events == 1
 
-        assert _wait(settled)
+        assert wait_until(settled)
     finally:
         inst.stop()
         inst.terminate()
@@ -182,21 +174,21 @@ def test_raw_wire_source_takes_columnar_lane(tmp_path):
         payload = "\n".join(lines).encode()
         with socket.create_connection(("127.0.0.1", rx.port), timeout=5) as s:
             s.sendall(struct.pack(">I", len(payload)) + payload)
-        assert _wait(lambda: src.decoded_count >= 16)
+        assert wait_until(lambda: src.decoded_count >= 16)
 
         def settled():
             inst.dispatcher.flush()
             return (inst.event_store.total_events == 16
                     and "d-new" in inst.identity.device)
 
-        assert _wait(settled)
+        assert wait_until(settled)
 
         # an undecodable payload dead-letters whole; the receiver lives
         before = inst.dispatcher.dead_letters.end_offset
         with socket.create_connection(("127.0.0.1", rx.port), timeout=5) as s:
             bad = b'{"not": "wire'
             s.sendall(struct.pack(">I", len(bad)) + bad)
-        assert _wait(
+        assert wait_until(
             lambda: inst.dispatcher.dead_letters.end_offset > before)
         assert src.failed_count == 1  # raw-lane failures tick the source
         with socket.create_connection(("127.0.0.1", rx.port), timeout=5) as s:
@@ -207,7 +199,7 @@ def test_raw_wire_source_takes_columnar_lane(tmp_path):
             inst.dispatcher.flush()
             return inst.event_store.total_events == 17
 
-        assert _wait(one_more)
+        assert wait_until(one_more)
     finally:
         inst.stop()
         inst.terminate()
@@ -252,6 +244,9 @@ def test_raw_wire_source_owner_splits_in_multihost(tmp_path):
                          "mtype_slots": 4, "deadline_ms": 5.0,
                          "n_shards": 1},
             "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
+            # the rows' path, not admission: a loaded machine's pressure
+            # must not shed them on the way
+            "overload": {"enabled": False},
             "rpc": {"server": {"enabled": True, "host": "127.0.0.1",
                                "port": ports[p]},
                     "process_id": p, "peers": peers,
@@ -296,7 +291,7 @@ def test_raw_wire_source_owner_splits_in_multihost(tmp_path):
             return (len(insts[0].event_store.query(device_id=d0)) == 2
                     and len(insts[1].event_store.query(device_id=d1)) == 2)
 
-        assert _wait(both_landed, timeout=15)
+        assert wait_until(both_landed)
         assert src.decoded_count == 2  # the locally-accepted rows
     finally:
         for inst in insts:

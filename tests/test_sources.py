@@ -4,7 +4,6 @@ import json
 import socket
 import struct
 import threading
-import time
 import urllib.request
 
 import pytest
@@ -19,14 +18,7 @@ from sitewhere_tpu.ingest.sources import (
     newline_frames,
 )
 
-
-def wait_for(predicate, timeout=5.0):
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if predicate():
-            return True
-        time.sleep(0.01)
-    return False
+from time_limit import wait_until
 
 
 def meas_payload(token="dev-1", value=1.0, alt=None):
@@ -59,7 +51,7 @@ def test_tcp_receiver_length_prefixed():
                 s.sendall(struct.pack(">I", len(payload)) + payload)
             bad = b"this is not json"
             s.sendall(struct.pack(">I", len(bad)) + bad)
-        assert wait_for(lambda: len(events) == 2 and len(failures) == 1)
+        assert wait_until(lambda: len(events) == 2 and len(failures) == 1)
         assert [e.value for e in events] == [1.0, 2.0]
         assert events[0].kind == RequestKind.MEASUREMENT
     finally:
@@ -75,7 +67,7 @@ def test_tcp_receiver_newline_framing():
         port = src.receivers[0].port
         with socket.create_connection(("127.0.0.1", port)) as s:
             s.sendall(meas_payload(value=5.0) + b"\n" + meas_payload(value=6.0) + b"\n")
-        assert wait_for(lambda: len(events) == 2)
+        assert wait_until(lambda: len(events) == 2)
         assert {e.value for e in events} == {5.0, 6.0}
     finally:
         src.stop()
@@ -88,7 +80,7 @@ def test_udp_receiver():
         port = src.receivers[0].port
         s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
         s.sendto(meas_payload(value=9.0), ("127.0.0.1", port))
-        assert wait_for(lambda: len(events) == 1)
+        assert wait_until(lambda: len(events) == 1)
         assert events[0].value == 9.0
     finally:
         src.stop()
@@ -106,7 +98,7 @@ def test_http_receiver_and_registration_routing():
             r = urllib.request.urlopen(urllib.request.Request(
                 url, data=body, method="POST"))
             assert r.status == 202
-        assert wait_for(lambda: len(events) == 1 and len(regs) == 1)
+        assert wait_until(lambda: len(events) == 1 and len(regs) == 1)
         assert regs[0].device_type_token == "pi"
         # wrong path -> 404, no event
         with pytest.raises(urllib.error.HTTPError):
@@ -125,8 +117,8 @@ def test_source_dedups_across_receivers():
         s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
         for _ in range(3):
             s.sendto(meas_payload(alt="same-msg"), ("127.0.0.1", port))
-        assert wait_for(lambda: src.receivers[0].received_count == 3)
-        assert wait_for(lambda: len(events) == 1)
+        assert wait_until(lambda: src.receivers[0].received_count == 3)
+        assert wait_until(lambda: len(events) == 1)
         assert src.duplicate_count == 2
     finally:
         src.stop()
@@ -162,7 +154,7 @@ def test_host_plane_request_does_not_kill_receiver():
         s.sendto(json.dumps({"deviceToken": "d", "type": "StreamData",
                              "request": {}}).encode(), ("127.0.0.1", port))
         s.sendto(meas_payload(value=3.0), ("127.0.0.1", port))
-        assert wait_for(lambda: len(events) == 1)  # receiver survived
+        assert wait_until(lambda: len(events) == 1)  # receiver survived
         assert src.dropped_host_requests == 1
         assert src.failed_count == 1
     finally:
@@ -181,7 +173,7 @@ def test_broken_sink_does_not_kill_receiver():
         s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
         s.sendto(meas_payload(value=1.0), ("127.0.0.1", port))
         s.sendto(meas_payload(value=2.0), ("127.0.0.1", port))
-        assert wait_for(lambda: src.failed_count == 2)  # both logged, thread alive
+        assert wait_until(lambda: src.failed_count == 2)  # both logged, thread alive
         assert src.receivers[0].received_count == 2
     finally:
         src.stop()

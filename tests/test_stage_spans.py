@@ -148,39 +148,55 @@ def test_stage_timers_add_up_to_the_plans_latency(tmp_path, ring_depth):
         for i in range(warm):
             d.ingest_wire_lines(_payload(i))
         d.flush()
-        before = totals()
-        for i in range(warm, warm + n):
-            d.ingest_wire_lines(_payload(i))
-        d.flush()
-        parts = {k: v - before[k] for k, v in totals().items()}
-        lat = list(d.latencies_s)[warm:]
-        assert len(lat) == n
-        assert reg.timer("pipeline.stage_egress_s").count == warm + n
+        # a thread descheduled between two spans by the machine's other
+        # load adds to the unattributed share and never takes from it:
+        # every round holds the timers to at most 10% over the latency (a
+        # span counted twice, or spans that overlap, is no noise), and up
+        # to three rounds of n plans get to come within 10% under it
+        for first in range(warm, warm + 3 * n, n):
+            before = totals()
+            for i in range(first, first + n):
+                d.ingest_wire_lines(_payload(i))
+            d.flush()
+            parts = {k: v - before[k] for k, v in totals().items()}
+            lat = list(d.latencies_s)[first:]
+            assert len(lat) == n
+            if ring_depth:
+                # every slot of a chain waits out the whole chain's
+                # dispatch ...
+                parts["ring_dispatch"] *= ring_depth
+                # ... and ring_wait covers what blocked its last plan
+                del parts["dispatch_wait"]
+                assert parts.pop("dispatch") == 0.0
+            else:
+                assert parts.pop("ring_wait") == parts.pop(
+                    "ring_dispatch") == 0
+            attributed, whole = sum(parts.values()), sum(lat)
+            print(f"\nplans {n}  latency {whole * 1e3:.2f} ms  attributed "
+                  f"{attributed * 1e3:.2f} ms  unattributed "
+                  f"{(whole - attributed) / whole:+.2%}  parts "
+                  + ", ".join(f"{k} {v * 1e3:.2f}"
+                              for k, v in parts.items()))
+            assert attributed <= whole * 1.10
+            if attributed >= whole * 0.90:
+                break
+        else:
+            pytest.fail("three rounds each left over 10% of the plans' "
+                        "latency unattributed")
+        plans = first + n
+        assert reg.timer("pipeline.stage_egress_s").count == plans
         if ring_depth:
             assert reg.counter("pipeline.ring_chains").value == (
-                (warm + n) // ring_depth)
-            # every slot of a chain waits out the whole chain's dispatch
-            parts["ring_dispatch"] *= ring_depth
-            # ... and ring_wait covers what blocked the chain's last plan
-            del parts["dispatch_wait"]
-            assert parts.pop("dispatch") == 0.0
-        else:
-            assert parts.pop("ring_wait") == parts.pop("ring_dispatch") == 0
-        attributed, whole = sum(parts.values()), sum(lat)
-        print(f"\nplans {n}  latency {whole * 1e3:.2f} ms  attributed "
-              f"{attributed * 1e3:.2f} ms  unattributed "
-              f"{(whole - attributed) / whole:+.2%}  parts "
-              + ", ".join(f"{k} {v * 1e3:.2f}" for k, v in parts.items()))
-        assert attributed == pytest.approx(whole, rel=0.10)
+                plans // ring_depth)
 
         wait = reg.timer("pipeline.device_wait_s")
         assert wait.count == reg.counter("pipeline.host_syncs").value > 0
         assert wait.total <= reg.timer("pipeline.stage_egress_s").total
-        assert reg.timer("pipeline.stage_inflight_wait_s").count == warm + n
+        assert reg.timer("pipeline.stage_inflight_wait_s").count == plans
         assert reg.timer("pipeline.stage_dispatch_wait_s").count >= (
-            0 if ring_depth else warm + n)
+            0 if ring_depth else plans)
         # per payload, the ingest journal only
-        assert reg.timer("ingest.journal_append_s").count == warm + n
+        assert reg.timer("ingest.journal_append_s").count == plans
         assert inst.dead_letters._append_span is contextlib.nullcontext
     finally:
         inst.stop()

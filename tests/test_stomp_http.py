@@ -30,7 +30,10 @@ from sitewhere_tpu.ingest.stomp import (
     StompReceiver,
     encode_frame,
 )
+from sitewhere_tpu.netio import close_socket
 from sitewhere_tpu.outbound.connectors import HttpConnector
+
+from time_limit import wait_until
 
 
 # ---------------------------------------------------------------------------
@@ -106,10 +109,7 @@ class MiniBroker:
 
     def close(self):
         self._alive = False
-        try:
-            self._srv.close()
-        except OSError:
-            pass
+        close_socket(self._srv)   # wakes the blocked accept
 
     def _loop(self):
         while self._alive:
@@ -177,15 +177,6 @@ class MiniBroker:
             conn.close()
 
 
-def _wait(pred, timeout=5.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if pred():
-            return True
-        time.sleep(0.01)
-    return False
-
-
 def test_stomp_receiver_subscribes_delivers_and_acks():
     broker = MiniBroker()
     got = []
@@ -194,15 +185,15 @@ def test_stomp_receiver_subscribes_delivers_and_acks():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: broker.subscribes)
+        assert wait_until(lambda: broker.subscribes)
         assert broker.subscribes[0]["destination"] == "/queue/q"
         assert broker.subscribes[0]["ack"] == "client-individual"
         broker.push("m-1", b'{"device":"d-1"}')
         broker.push("m-2", b'{"device":"d-2"}')
-        assert _wait(lambda: len(got) == 2)
+        assert wait_until(lambda: len(got) == 2)
         assert got == [b'{"device":"d-1"}', b'{"device":"d-2"}']
         # per-message acks arrive only after the sink accepted the payload
-        assert _wait(lambda: broker.acks == ["m-1", "m-2"])
+        assert wait_until(lambda: broker.acks == ["m-1", "m-2"])
     finally:
         rx.stop()
         broker.close()
@@ -216,10 +207,10 @@ def test_stomp_receiver_reconnects_after_dropped_session():
     rx.sink = got.append
     rx.start()
     try:
-        assert _wait(lambda: broker.subscribes)  # second session made it
+        assert wait_until(lambda: broker.subscribes)  # second session made it
         assert broker.sessions >= 2
         broker.push("m-9", b"payload")
-        assert _wait(lambda: got == [b"payload"])
+        assert wait_until(lambda: got == [b"payload"])
     finally:
         rx.stop()
         broker.close()
@@ -365,11 +356,11 @@ def test_stomp_poison_message_left_unacked_and_receiver_survives():
     rx.sink = sink
     rx.start()
     try:
-        assert _wait(lambda: broker.subscribes)
+        assert wait_until(lambda: broker.subscribes)
         broker.push("m-1", b"poison")
         broker.push("m-2", b"fine")
-        assert _wait(lambda: got == [b"fine"])
-        assert _wait(lambda: broker.acks == ["m-2"])  # poison NOT acked
+        assert wait_until(lambda: got == [b"fine"])
+        assert wait_until(lambda: broker.acks == ["m-2"])  # poison NOT acked
         assert rx.emit_errors == 1
     finally:
         rx.stop()
@@ -388,15 +379,15 @@ def test_stomp_heartbeats_negotiated_and_sent():
     rx.sink = lambda p: None
     rx.start()
     try:
-        assert _wait(lambda: broker.subscribes)
+        assert wait_until(lambda: broker.subscribes)
         # client LF heart-beats arrive (boundary-insensitive: a chunk of
         # nothing but LFs, however many coalesced)
-        assert _wait(lambda: any(
+        assert wait_until(lambda: any(
             d and d.strip(b"\n") == b"" for d in raw_frames), timeout=2.0)
         # silent broker -> heart-beat cutoff -> reconnect attempt: the
         # broker sees a SECOND session (would never happen if the
         # dead-connection detection in _session were removed)
-        assert _wait(lambda: broker.sessions >= 2, timeout=5.0)
+        assert wait_until(lambda: broker.sessions >= 2)
     finally:
         rx.stop()
         broker.close()
@@ -441,9 +432,7 @@ def test_index_push_interval_flush_and_final_flush_on_stop():
             bulk_rows=1000, bulk_interval_s=0.1)
         c.start()
         c.process_batch(_cols(2), np.ones(2, np.bool_))
-        deadline = time.time() + 5
-        while not srv.requests and time.time() < deadline:
-            time.sleep(0.02)
+        assert wait_until(lambda: srv.requests)
         assert len(srv.requests) == 1  # interval flush
         c.process_batch(_cols(1), np.ones(1, np.bool_))
         c.stop()  # final best-effort flush

@@ -398,7 +398,10 @@ def _instance_config(tmp_path, tenants=None, overload=None):
         "pipeline": {"width": 64, "registry_capacity": 128,
                      "mtype_slots": 4, "deadline_ms": 5.0, "n_shards": 1},
         "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
-        "overload": {"enabled": True, **(overload or {})},
+        # a state the test forces holds until the test moves it: the
+        # loop's tick de-escalates only after an hour of calm
+        "overload": {"enabled": True, "cooldown_s": 3600.0,
+                     **(overload or {})},
         "tenants": tenants or {},
     }, apply_env=False)
 

@@ -3,7 +3,6 @@ upload — round-2 verdict items #8, #9, #10."""
 
 import json
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -11,6 +10,8 @@ import pytest
 from sitewhere_tpu.instance import Instance
 from sitewhere_tpu.runtime.config import Config
 from sitewhere_tpu.runtime.tracing import Tracer
+
+from time_limit import wait_until
 
 
 def _cfg(tmp_path, **over):
@@ -234,10 +235,12 @@ def test_scripted_decoder_feeds_source_end_to_end(inst):
     s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     s.sendto(b"csv-dev,33.0", ("127.0.0.1", recv.port))
     s.close()
-    deadline = time.monotonic() + 5
-    while inst.event_store.total_events < 1 and time.monotonic() < deadline:
+
+    def stored():
         inst.dispatcher.flush()
-        time.sleep(0.05)
+        return inst.event_store.total_events >= 1
+
+    assert wait_until(stored)
     assert inst.event_store.total_events == 1
 
 

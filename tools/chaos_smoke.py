@@ -119,6 +119,7 @@ def main() -> int:
         # process; the journal (committed offset) is the durable truth
         with inst.dispatcher._lock:
             inst.dispatcher._plans_outstanding = 0
+            inst.dispatcher._plans_failed = 0
             inst.dispatcher._inflight.clear()
         inst.dispatcher.replay_journal()
         inst.dispatcher.flush()

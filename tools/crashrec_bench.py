@@ -623,6 +623,37 @@ def run_kill_case(root, case, point, hits, golden_matches, child_cmd):
     return failures, report
 
 
+def golden_run(root, child_cmd):
+    """The uninterrupted reference run in ``root``: its analytics match
+    set and its durably journaled events."""
+    golden_dir = os.path.join(root, "golden")
+    golden_matches_path = os.path.join(root, "matches-golden.jsonl")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("SW_CRASHPOINT", None)
+    proc = subprocess.run(
+        child_cmd + ["--child", golden_dir,
+                     "--matches", golden_matches_path],
+        env=env, capture_output=True, timeout=300)
+    if proc.returncode != 0:
+        raise RuntimeError("golden run did not complete: "
+                           + proc.stderr.decode(errors="replace")[-2000:])
+    return read_matches(golden_matches_path), expected_events(golden_dir)
+
+
+def kill_case(root, case, point, hits, golden_matches, child_cmd):
+    """Kill a child at the ``hits``-th crossing of ``point``, recover it
+    and check the contract; the case's report, its ``failures`` empty
+    when it recovered clean."""
+    if point == "crash.mid_forward":
+        # fleet-shaped case: its own 2-host child + verifier
+        failures, report = run_forward_kill_case(root, case, hits, child_cmd)
+    else:
+        failures, report = run_kill_case(
+            root, case, point, hits, golden_matches, child_cmd)
+    report.update({"point": point, "hit": hits, "failures": failures})
+    return report
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--child", metavar="DATA_DIR")
@@ -658,36 +689,19 @@ def main(argv=None) -> int:
     results = {"seed": seed, "kills": [], "ok": True}
     all_failures = []
     try:
-        # golden reference: the uninterrupted run
-        golden_dir = os.path.join(root, "golden")
-        golden_matches_path = os.path.join(root, "matches-golden.jsonl")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        env.pop("SW_CRASHPOINT", None)
-        proc = subprocess.run(
-            child_cmd + ["--child", golden_dir,
-                         "--matches", golden_matches_path],
-            env=env, capture_output=True, timeout=300)
-        if proc.returncode != 0:
-            print(proc.stderr.decode(errors="replace")[-2000:],
-                  file=sys.stderr)
-            print("FAIL: golden run did not complete", file=sys.stderr)
+        try:
+            golden_matches, golden_events = golden_run(root, child_cmd)
+        except RuntimeError as e:
+            print(f"FAIL: {e}", file=sys.stderr)
             return 1
-        golden_matches = read_matches(golden_matches_path)
-        golden_events = expected_events(golden_dir)
         print(f"crashrec: seed={seed} golden: "
               f"{len(golden_events)} events, "
               f"{len(golden_matches)} matches")
 
         for case, (point, hits) in enumerate(kills):
-            if point == "crash.mid_forward":
-                # fleet-shaped case: its own 2-host child + verifier
-                failures, report = run_forward_kill_case(
-                    root, case, hits, child_cmd)
-            else:
-                failures, report = run_kill_case(
-                    root, case, point, hits, golden_matches, child_cmd)
-            report.update({"point": point, "hit": hits,
-                           "failures": failures})
+            report = kill_case(root, case, point, hits, golden_matches,
+                               child_cmd)
+            failures = report["failures"]
             results["kills"].append(report)
             all_failures.extend(f"{point}:{hits}: {f}" for f in failures)
             status = "ok" if not failures else "FAIL"

@@ -40,6 +40,7 @@ Exit status 0 = every phase held its contract.
 """
 
 import argparse
+import dataclasses
 import json
 import math
 import os
@@ -77,6 +78,7 @@ TS0 = 1_754_500_000
 def _make_instance(data_dir, **overrides):
     from sitewhere_tpu.instance import Instance
     from sitewhere_tpu.runtime.config import Config
+    from sitewhere_tpu.runtime.overload import Watermarks
 
     pipeline = {"width": WIDTH, "registry_capacity": 256,
                 "mtype_slots": 4, "deadline_ms": 5.0, "n_shards": 1,
@@ -85,9 +87,13 @@ def _make_instance(data_dir, **overrides):
     cfg = Config({
         "instance": {"id": "devfault-bench", "data_dir": data_dir},
         "pipeline": pipeline,
-        # only the bench releases the forced DEGRADED (via the breaker
-        # restore) — the ladder's own cooldown must not race it
-        "overload": {"cooldown_s": 3600.0},
+        # only the bench moves the ladder: the breaker forces DEGRADED
+        # and its restore releases it.  Neither the ladder's own
+        # cooldown nor a loaded machine's pressure signals may race it
+        # (on a busy host they escalated a phase to SHEDDING, which shed
+        # the bench's own rows)
+        "overload": {"cooldown_s": 3600.0, "watermarks": {
+            f.name: [1e9, 2e9, 3e9] for f in dataclasses.fields(Watermarks)}},
         "presence": {"scan_interval_s": 3600.0, "missing_after_s": 1800},
     }, apply_env=False)
     return Instance(cfg)

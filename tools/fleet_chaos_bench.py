@@ -37,6 +37,7 @@ FLEETCHAOS_rNN.json evidence captures.
 """
 
 import argparse
+import dataclasses
 import json
 import math
 import os
@@ -71,6 +72,7 @@ def _free_port():
 
 def _config(data_dir, ports, pid, heartbeat_s):
     from sitewhere_tpu.runtime.config import Config
+    from sitewhere_tpu.runtime.overload import Watermarks
 
     return Config({
         "instance": {"id": f"fleet-{pid}", "data_dir": data_dir},
@@ -80,9 +82,12 @@ def _config(data_dir, ports, pid, heartbeat_s):
         "checkpoint": {"interval_s": 0},
         "analytics": {"enabled": False},
         "slo": {"enabled": False},
-        # forced overload states must hold for the scripted phase: a
-        # short cooldown would let the controller self-recover mid-test
-        "overload": {"enabled": True, "cooldown_s": 600.0},
+        # only the script moves the ladder: a forced state must hold for
+        # its phase (a short cooldown would let the controller
+        # self-recover mid-test), and a loaded machine's own pressure
+        # signals must not escalate a host past the state it was given
+        "overload": {"enabled": True, "cooldown_s": 600.0, "watermarks": {
+            f.name: [1e9, 2e9, 3e9] for f in dataclasses.fields(Watermarks)}},
         "rpc": {
             "server": {"enabled": True, "host": "127.0.0.1",
                        "port": ports[pid]},
@@ -169,6 +174,20 @@ class _Driver(threading.Thread):
         self.join(timeout=10)
 
 
+def _until(cond, timeout_s, poke=lambda: None):
+    """Poll ``cond`` until it holds, calling ``poke`` between polls;
+    its last value once ``timeout_s`` has passed.  (``tests/time_limit``
+    has the tests' copy, without ``poke``: it imports pytest, which a
+    tool run from the command line does not need.)"""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        if cond():
+            return True
+        poke()
+        time.sleep(0.1)
+    return cond()
+
+
 def _accepted(insts):
     return [int(i.dispatcher.metrics_snapshot()["accepted"]) for i in insts]
 
@@ -221,13 +240,8 @@ def main(argv=None) -> int:
         for p, toks in tokens_by_owner.items():
             fwd.ingest_payload(_payload(toks[:4], 0), source_id="warmup")
         fwd.flush(wait=True)
-        warm_deadline = time.monotonic() + 120
-        while time.monotonic() < warm_deadline:
-            if all(a >= 4 for a in _accepted(insts)):
-                break
-            fwd.flush()
-            time.sleep(0.1)
-        if not all(a >= 4 for a in _accepted(insts)):
+        if not _until(lambda: all(a >= 4 for a in _accepted(insts)), 120,
+                      poke=fwd.flush):
             failures.append("warm-up rows never landed on every host")
         report["warmup_accepted"] = _accepted(insts)
 
@@ -272,9 +286,10 @@ def main(argv=None) -> int:
             "shed",
             setup=lambda: insts[sick].overload.force(
                 OverloadState.SHEDDING, reason="fleet-chaos"))
-        # host 0's table must have learned the state (heartbeat or
-        # piggyback — both race the phase window, so check at the end)
-        if fwd.health.overload_state(sick) != int(OverloadState.SHEDDING):
+        # host 0's table must learn the state (heartbeat or piggyback —
+        # either may land after the phase window, so wait for it)
+        if not _until(lambda: fwd.health.overload_state(sick)
+                      == int(OverloadState.SHEDDING), drain_timeout_s):
             failures.append(
                 "health table never learned the SHEDDING state "
                 f"(saw {fwd.health.overload_state(sick)})")
@@ -297,6 +312,10 @@ def main(argv=None) -> int:
         partition = run_phase(
             "partition",
             setup=lambda: faults.net_inject(sick_ep, drop=1.0))
+        # the detector moves on missed heartbeats and failed sends, which
+        # a loaded host may still be counting when the window closes
+        _until(lambda: fwd.health.state(sick).name != "ALIVE",
+               drain_timeout_s)
         state_after = fwd.health.state(sick).name
         report["state_after_partition"] = state_after
         if state_after == "ALIVE":
@@ -311,10 +330,8 @@ def main(argv=None) -> int:
 
         driver.stop()
         # the spool must drain to ZERO once the peer is healthy again
-        deadline = time.monotonic() + drain_timeout_s
-        while time.monotonic() < deadline and fwd.pending_rows() > 0:
-            fwd.flush()
-            time.sleep(0.1)
+        _until(lambda: fwd.pending_rows() == 0, drain_timeout_s,
+               poke=fwd.flush)
         pending_final = fwd.pending_rows()
         report["pending_after_recovery"] = pending_final
         if pending_final != 0:
