@@ -19,7 +19,10 @@ events keyed by device token land in per-partition record batches.  Here:
   rows) or when the oldest pending event exceeds the deadline — bounding
   added latency the way the Mongo buffer bounds flush delay
   (``DeviceEventBuffer.java:40-46``, ≤250 ms there; default 5 ms here for
-  the <10 ms p99 budget);
+  the <10 ms p99 budget) — or, for a live wire payload that finds the
+  pipeline empty (nothing pending, no plan outstanding), at once
+  (:meth:`Batcher.emit_idle`): the deadline is paid only while there is
+  something to coalesce behind;
 - rows that don't fit carry over to the next batch (no drops);
 - unknown devices round-robin across shards and dead-letter on-device.
 """
@@ -85,7 +88,7 @@ def plan_rungs(width: int) -> tuple:
     """The widths a single-shard batcher emits plans at, ascending, at
     most four and always ending in ``width``: ``width / 64``, ``/ 16``,
     ``/ 4`` and ``width`` itself, none under :data:`_MIN_RUNG`.  A
-    deadline or flush emission takes the smallest rung that holds its
+    deadline, idle or flush emission takes the smallest rung that holds its
     rows, so a 1,024-row payload steps a 1,024-column program and not
     the ``pipeline.width`` one (PERF.md §6, PR 33).  A fixed function of
     the configured width: the dispatcher compiles one step a rung at
@@ -400,9 +403,9 @@ class BatchPlan:
         # Emission bookkeeping for the device-resident dispatch ring:
         # ``seq`` is the batcher's monotonic emission number (commit/
         # egress attribution of a chained step), ``reason`` the emit
-        # trigger ("fill" | "deadline" | "flush").  Only full-width fill
-        # emissions ride the ring; deadline/flush partials are latency-
-        # sensitive and take the single-step path.
+        # trigger ("fill" | "deadline" | "idle" | "flush").  Only
+        # full-width fill emissions ride the ring; the partials are
+        # latency-sensitive and take the single-step path.
         seq: int = -1,
         reason: str = "fill",
         # Host dispatch time this plan paid (single-step: the jitted
@@ -468,6 +471,11 @@ class AdaptiveBatchController:
       the stream is backlogged; GROW the window toward ``max_s`` (fuller
       batches, fewer partial-width dispatches).
 
+    The window binds only rows that have something to coalesce behind: a
+    live wire payload that finds the pipeline empty leaves at once
+    (reason "idle", :meth:`Batcher.emit_idle`) and waits for no window,
+    so an idle emission, like a flush, tells the controller nothing.
+
     Deterministic: no internal clock — driven entirely by the batcher's
     emits, so a fake-clock test replays decisions exactly.  Decisions are
     exported through the metrics registry (``ingest.adaptive_window_s``
@@ -513,9 +521,10 @@ class AdaptiveBatchController:
     def on_emit(self, n_events: int, width: int, pending: int,
                 reason: str) -> None:
         """Observe one emission (``reason``: "fill" | "deadline" |
-        "flush") and adjust the window.  Flush emits are shutdown/drain
-        artifacts and never adapt."""
-        if reason == "flush":
+        "idle" | "flush") and adjust the window.  Flush emits are
+        shutdown/drain artifacts and idle emits waited for no window:
+        neither adapts."""
+        if reason in ("flush", "idle"):
             return
         if reason == "fill" or pending >= width:
             new = min(self.window_s * self.grow, self.max_s)
@@ -603,6 +612,7 @@ class Batcher:
         if metrics is not None:
             self._m_batches = metrics.counter("ingest.batches_emitted")
             self._m_rows = metrics.counter("ingest.rows_emitted")
+            self._m_rows_idle = metrics.counter("ingest.rows_emitted_idle")
             self._m_fill = metrics.gauge("ingest.batch_fill")
             self._m_wait = metrics.histogram("ingest.batch_wait_s")
             self._m_copied = metrics.counter("pipeline.bytes_copied.batch")
@@ -920,6 +930,18 @@ class Batcher:
         if self.clock() - self._oldest >= self.deadline_s:
             return self._emit(reason="deadline")
         return None
+
+    def emit_idle(self) -> Optional[BatchPlan]:
+        """Emit what is pending now, at the narrow rung a deadline plan
+        would take: the caller (the dispatcher's live wire intake, under
+        its intake lock) has seen that these rows found the pipeline
+        empty, so a deadline wait would coalesce them with nothing."""
+        if self._oldest is None:
+            return None
+        plan = self._emit(reason="idle")
+        if self.metrics is not None:
+            self._m_rows_idle.inc(plan.n_events)
+        return plan
 
     def flush(self) -> Optional[BatchPlan]:
         """Emit whatever is pending (shutdown/drain)."""

@@ -690,12 +690,22 @@ class PipelineDispatcher(LifecycleComponent):
 
     # -- ingest entry points (wired as InboundEventSource.on_event) ---------
 
-    def _take(self, intake: Callable[[], object]) -> List[BatchPlan]:
+    def _take(self, intake: Callable[[], object],
+              live_wire: bool = False) -> List[BatchPlan]:
         """Run a batcher intake under the lock, counting every emitted plan
         as outstanding until its egress completes — the commit gate's
-        accounting (see ``_maybe_commit_offset``)."""
+        accounting (see ``_maybe_commit_offset``).
+
+        ``live_wire`` (a live wire payload's commit): rows that found the
+        pipeline empty — nothing pending before them, no plan outstanding
+        — and filled no segment leave now (``Batcher.emit_idle``) instead
+        of waiting for the loop's deadline poll, which would coalesce
+        them with nothing.  Anything outstanding closes that gate, and
+        the rows wait for the deadline as every other intake's do."""
         with self._m_stage["batch"].time() as span:
             with self._lock:
+                idle = (live_wire and self._plans_outstanding == 0
+                        and self.batcher.pending == 0)
                 out = intake()
                 if out is None:
                     plans: List[BatchPlan] = []
@@ -703,6 +713,10 @@ class PipelineDispatcher(LifecycleComponent):
                     plans = [p for p in out if p is not None]
                 else:
                     plans = [out]
+                if idle and not plans:
+                    plan = self.batcher.emit_idle()
+                    if plan is not None:
+                        plans = [plan]
                 self._plans_outstanding += len(plans)
             if not plans:
                 span.discard()   # the timer is per EMITTED plan
@@ -1084,7 +1098,8 @@ class PipelineDispatcher(LifecycleComponent):
                 })
         if not columns:
             return 0   # every event row was shed; host-plane lines routed
-        return self._ingest_resolved_columns(columns, ref, tenant)
+        return self._ingest_resolved_columns(columns, ref, tenant,
+                                             live_wire=True)
 
     def _ingest_reserved(self, payload: bytes, res, source_id: str,
                          tenant: str = "default") -> int:
@@ -1116,7 +1131,7 @@ class PipelineDispatcher(LifecycleComponent):
         res.set_const(tenant_id=self.resolve_tenant(tenant),
                       payload_ref=ref)
         self._count_wire_rows(n, tenant)
-        self._run_plans(self._take(res.commit))
+        self._run_plans(self._take(res.commit, live_wire=True))
         return n
 
     def _count_wire_rows(self, n: int, tenant: str) -> None:
@@ -1125,13 +1140,15 @@ class PipelineDispatcher(LifecycleComponent):
             self._m_wire_rows_tenant.inc(n)
 
     def _ingest_resolved_columns(self, columns, ref: int,
-                                 tenant: str = "default") -> int:
+                                 tenant: str = "default",
+                                 live_wire: bool = False) -> int:
         """Resolve one decoded column dict and queue its rows (shared by
         live wire intake and columnar journal replay — replay's
         equivalence argument depends on both using THIS code: rows get
         ``ref`` as payload_ref and land in ``tenant``, which the live
         intake takes from its caller and the replay from the journal
-        record)."""
+        record).  Only the live intake passes ``live_wire`` (see
+        ``_take``): a replay keeps coalescing under the deadline."""
         from sitewhere_tpu.ingest.columnar import n_rows, resolve_columns
 
         n = n_rows(columns)
@@ -1149,7 +1166,8 @@ class PipelineDispatcher(LifecycleComponent):
             n, self.resolve_tenant(tenant), np.int32)
         self._count_wire_rows(n, tenant)
         self._run_plans(self._take(
-            lambda: self.batcher.add_arrays(_copy=False, **cols)))
+            lambda: self.batcher.add_arrays(_copy=False, **cols),
+            live_wire=live_wire))
         return n
 
     def ingest_registration(self, req: DecodedRequest, payload: bytes = b"") -> None:
@@ -1603,7 +1621,7 @@ class PipelineDispatcher(LifecycleComponent):
     def _ring_eligible(self, plan: BatchPlan, replay_depth: int) -> bool:
         """May this plan wait in the ring for a chained dispatch?  Only
         depth-0 full-width fill emissions:
-        deadline/flush partials are latency-sensitive and re-injected
+        deadline/idle/flush partials are latency-sensitive and re-injected
         plans (derived alerts, replay) must not recurse through the
         ring.  Mesh plans chain through the sharded packed chain — the
         fused mode — under the same eligibility rules.  The explicit

@@ -380,6 +380,35 @@ def test_adaptive_exports_decisions_to_metrics():
     assert snap["gauges"]["ingest.adaptive_window_s"] == ctl.window_s
 
 
+def test_adaptive_ignores_idle_emits():
+    """An idle emission waited for no window, so it says nothing about
+    coalescing: the emit a deadline plan of the same fill would shrink
+    on leaves the window where it was."""
+    ctl = make_adaptive(deadline_ms=5.0)
+    ctl.on_emit(1, 16, 0, "idle")
+    ctl.on_emit(16, 16, 32, "idle")
+    assert ctl.window_s == 0.005 and ctl.shrinks == ctl.grows == 0
+    ctl.on_emit(1, 16, 0, "deadline")
+    assert ctl.shrinks == 1
+
+
+def test_emit_idle_emits_what_is_pending_and_counts_its_rows():
+    from sitewhere_tpu.runtime.metrics import MetricsRegistry
+
+    m = MetricsRegistry()
+    ctl = make_adaptive(deadline_ms=5.0)
+    b = ladder_batcher(metrics=m, controller=ctl)
+    assert b.emit_idle() is None          # nothing pending, no plan
+    b.add_arrays(device_id=np.arange(1000, dtype=np.int32))
+    plan = b.emit_idle()
+    assert (plan.reason, plan.n_events, plan.width) == ("idle", 1000, 2048)
+    assert b.pending == 0 and b.emit_idle() is None
+    counters = m.snapshot()["counters"]
+    assert counters["ingest.rows_emitted_idle"] == 1000
+    assert counters["ingest.rows_emitted"] == 1000
+    assert ctl.window_s == 0.005
+
+
 def test_add_arrays_single_shard_copies_caller_arrays():
     """ingest_arrays advertises vectorized/ring-buffer feeders; a caller
     refilling its buffers while rows sit queued must not corrupt queued
@@ -400,8 +429,15 @@ def test_add_arrays_single_shard_copies_caller_arrays():
     assert got_val == [1.0, 2.0, 3.0]
 
 
+def _emit_partial(b, reason):
+    """What is pending, emitted the way ``reason`` names: the loop's
+    deadline poll, a drain's flush, or the wire intake's idle emission."""
+    return {"deadline": b.poll, "flush": b.flush,
+            "idle": b.emit_idle}[reason]()
+
+
 @pytest.mark.parametrize("n_shards", [1, 4])
-@pytest.mark.parametrize("reason", ["fill", "deadline", "flush"])
+@pytest.mark.parametrize("reason", ["fill", "deadline", "flush", "idle"])
 def test_plan_views_agree(reason, n_shards):
     """A plan has one form: whatever made the batcher emit it, it
     carries the packed buffers the dispatcher stages, and they unpack to
@@ -423,11 +459,9 @@ def test_plan_views_agree(reason, n_shards):
         value=np.linspace(0.0, 1.0, n).astype(np.float32),
         lat=np.full(n, 3.5, np.float32),
         update_state=(np.arange(n) % 2 == 0))
-    if reason == "deadline":
+    if reason != "fill":
         clock.t = 1.0
-        plans = [b.poll()]
-    elif reason == "flush":
-        plans = [b.flush()]
+        plans = [_emit_partial(b, reason)]
     (plan,) = plans
     assert plan.reason == reason and plan.n_events == n
     assert plan.packed_i is not None and plan.packed_f is not None
@@ -481,7 +515,7 @@ def _rung_cases():
             yield rung + 1, rungs[i + 1]     # one over
 
 
-@pytest.mark.parametrize("reason", ["deadline", "flush"])
+@pytest.mark.parametrize("reason", ["deadline", "flush", "idle"])
 @pytest.mark.parametrize("n, rung", sorted(set(_rung_cases())))
 def test_partial_emission_takes_the_smallest_rung_that_holds_it(
         reason, n, rung):
@@ -499,7 +533,7 @@ def test_partial_emission_takes_the_smallest_rung_that_holds_it(
     else:
         assert emitted == []
         clock.t = 1.0
-        plan = b.poll() if reason == "deadline" else b.flush()
+        plan = _emit_partial(b, reason)
         assert plan.reason == reason
     assert plan.n_events == n
     assert plan.width == rung and plan.full_width == LADDER_W
@@ -576,7 +610,7 @@ def test_emit_tail_reads_the_configured_width(n):
     assert m.snapshot()["gauges"]["ingest.batch_fill"] == n / LADDER_W
 
 
-@pytest.mark.parametrize("reason", ["deadline", "flush"])
+@pytest.mark.parametrize("reason", ["deadline", "flush", "idle"])
 @pytest.mark.parametrize("n", [1, 100, 129, 2049])
 def test_sharded_batcher_emits_at_one_width(reason, n):
     clock = FakeClock()
@@ -584,7 +618,7 @@ def test_sharded_batcher_emits_at_one_width(reason, n):
     assert b.rungs == (LADDER_W,)
     b.add_arrays(device_id=(np.arange(n, dtype=np.int32) * 31) % (1 << 16))
     clock.t = 1.0
-    plan = b.poll() if reason == "deadline" else b.flush()
+    plan = _emit_partial(b, reason)
     assert plan.n_events == n
     assert plan.width == plan.full_width == LADDER_W
     assert plan.packed_i.shape[1] == LADDER_W

@@ -220,13 +220,15 @@ class SlowStore:
 
 
 def make_dispatcher(step_s=0.0, egress_s=0.0, egress_offload=True,
-                    inflight_depth=1, **kw):
+                    inflight_depth=1, deadline_ms=60_000.0, store=None,
+                    **kw):
     metrics = MetricsRegistry()
     batcher = Batcher(
         width=WIDTH, n_shards=1, registry_capacity=64,
         resolve_device=lambda t: NULL_ID, resolve_mtype=lambda n: 0,
-        resolve_alert=lambda n: 0, deadline_ms=60_000.0)
-    store = SlowStore(egress_s)
+        resolve_alert=lambda n: 0, deadline_ms=deadline_ms,
+        metrics=metrics)
+    store = store if store is not None else SlowStore(egress_s)
     disp = PipelineDispatcher(
         batcher=batcher,
         registry_provider=lambda: None,
@@ -1080,3 +1082,122 @@ def test_step_failure_on_a_narrow_plan_bisects_and_dead_letters(tmp_path):
         faults.device_clear()
         inst.stop()
         inst.terminate()
+
+
+# ---------------------------------------------------------------------------
+# a wire payload that finds the pipeline empty leaves at once
+# ---------------------------------------------------------------------------
+
+def _wire_payload(n, ts_s=1_754_700_000):
+    import json as _json
+
+    return "\n".join(_json.dumps({
+        "deviceToken": f"d-{i}", "type": "Measurement",
+        "request": {"name": "temp", "value": float(i), "eventDate": ts_s},
+    }) for i in range(n)).encode()
+
+
+class GatedStore(SlowStore):
+    """An event store whose appends wait for ``gate``: the plan being
+    egressed stays outstanding until the test opens it."""
+
+    def __init__(self):
+        super().__init__()
+        self.gate = threading.Event()
+
+    def append_columns(self, cols, mask=None):
+        self.gate.wait(WAIT_S)
+        super().append_columns(cols, mask)
+
+
+def _idle_rows(metrics):
+    return metrics.counter("ingest.rows_emitted_idle").value
+
+
+def test_a_wire_payload_into_an_idle_pipeline_leaves_at_once():
+    """A 10 s deadline, and the payload is a plan before the call
+    returns: emitted, counted outstanding (its egress is held), no row
+    left pending."""
+    store = GatedStore()
+    disp, _, metrics = make_dispatcher(deadline_ms=10_000.0, store=store)
+    disp.start()
+    try:
+        assert disp.ingest_wire_lines(_wire_payload(3)) == 3
+        assert disp.batcher.pending == 0
+        assert disp._plans_outstanding == 1
+        assert disp.batcher.emitted_batches == 1
+        assert _idle_rows(metrics) == 3
+    finally:
+        store.gate.set()
+        disp.stop()
+    assert store.rows == 3 and store.batches == 1
+
+
+def test_a_payload_behind_an_outstanding_plan_waits_for_the_deadline():
+    store = GatedStore()
+    disp, _, metrics = make_dispatcher(deadline_ms=1_000.0, store=store)
+    disp.start()
+    try:
+        disp.ingest_wire_lines(_wire_payload(3))
+        assert disp._plans_outstanding == 1
+        disp.ingest_wire_lines(_wire_payload(2))
+        # the first plan is outstanding: the second payload coalesces
+        assert disp.batcher.pending == 2
+        assert _idle_rows(metrics) == 3
+        store.gate.set()
+        # the loop's deadline poll takes it, not the intake
+        wait_until(lambda: disp.batcher.pending == 0
+                   and disp._plans_outstanding == 0)
+        assert disp.batcher.emitted_batches == 2
+        assert _idle_rows(metrics) == 3
+        assert metrics.counter("ingest.rows_emitted").value == 5
+    finally:
+        store.gate.set()
+        disp.stop()
+    assert store.rows == 5 and store.batches == 2
+
+
+def test_a_payload_behind_pending_rows_waits():
+    disp, store, metrics = make_dispatcher(deadline_ms=10_000.0)
+    disp.start()
+    try:
+        disp.ingest_arrays(device_id=np.zeros(1, np.int32))
+        disp.ingest_wire_lines(_wire_payload(3))
+        assert disp.batcher.pending == 4
+        assert disp.batcher.emitted_batches == 0
+        assert _idle_rows(metrics) == 0
+        disp.flush()
+    finally:
+        disp.stop()
+    assert store.rows == 4 and store.batches == 1
+
+
+def test_journal_replay_emits_no_idle_plan(tmp_path):
+    """Replay shares the live intake's column path and keeps coalescing
+    under the deadline: its rows leave on the drain's flush."""
+    inst = _ladder_instance(tmp_path, 0)
+    try:
+        d = inst.dispatcher
+        assert d.ingest_wire_lines(_wire_payload(5)) == 5
+        assert d.batcher.pending == 0
+        d.flush()
+        assert _idle_rows(inst.metrics) == 5
+        assert d.replay_journal(from_offset=0) == 5
+        assert _idle_rows(inst.metrics) == 5
+        assert inst.metrics.counter("ingest.rows_emitted").value == 10
+        assert d.totals["accepted"] == 10
+    finally:
+        inst.stop()
+        inst.terminate()
+
+
+def test_ring_refuses_an_idle_plan():
+    from sitewhere_tpu.ingest.batcher import BatchPlan
+
+    disp, _, _ = make_ring_dispatcher(ring_depth=2)
+    disp.batcher.add_arrays(device_id=np.zeros(WIDTH - 1, np.int32))
+    plan = disp.batcher.emit_idle()
+    assert plan.reason == "idle" and plan.n_events == WIDTH - 1
+    assert not disp._ring_eligible(plan, replay_depth=0)
+    full = BatchPlan(n_events=WIDTH, width=WIDTH, reason="idle", seq=3)
+    assert not disp._ring_eligible(full, replay_depth=0)
