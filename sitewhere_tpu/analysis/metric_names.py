@@ -95,6 +95,8 @@ FAMILIES: Dict[str, Optional[Set[str]]] = {
         "tier_promotions", "tier_demotions",
         # histograms (background stage timers)
         "seal_s", "compact_s",
+        # timers: the writer-thread seal of the backpressure valve
+        "inline_seal_s",
         # gauges
         "segments", "segments_hot", "hot_bytes",
         "seal_queue_depth", "buffered_rows", "catalog_drift",

@@ -724,6 +724,9 @@ class Instance(LifecycleComponent):
             prune_journal=bool(self.config.get(
                 "journal.prune_after_checkpoint", False)),
         ))
+        # a stall record says whether a save overlapped it
+        self.dispatcher.stall_witness.save_probe = \
+            self.checkpointer.saving_within
         if self.analytics is not None:
             # live query/CEP state: open windows, rings, sessions,
             # pattern stages — carried with its exact applied offset

@@ -31,6 +31,7 @@ import numpy as np
 
 from sitewhere_tpu.outbound.connectors import OutboundConnector
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
+from sitewhere_tpu.runtime.process import name_os_thread
 from sitewhere_tpu.runtime.tracing import _NOOP_TRACE
 
 logger = logging.getLogger("sitewhere_tpu.outbound")
@@ -196,6 +197,7 @@ class _Worker:
                 self.q.all_tasks_done.wait(remaining)
 
     def _loop(self) -> None:
+        name_os_thread("sw-out-" + self.connector.connector_id)
         while not self._stop.is_set():
             item = self.q.get()
             try:

@@ -73,6 +73,7 @@ import numpy as np
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
 from sitewhere_tpu.runtime.metrics import MetricsRegistry
+from sitewhere_tpu.runtime.process import name_os_thread
 
 logger = logging.getLogger("sitewhere_tpu.checkpoint")
 
@@ -294,6 +295,14 @@ class Checkpointer(LifecycleComponent):
         if provider.name in _RESERVED_SECTIONS:
             raise ValueError(f"section name {provider.name!r} is reserved")
         self._providers[provider.name] = provider
+
+    def saving_within(self, seconds: float) -> bool:
+        """Did a save run at any time in the last ``seconds``: one
+        holds the save lock now, or the last one ended inside them (the
+        stall witness's ``save_probe``)."""
+        return self._save_lock.locked() or (
+            self.last_saved_at is not None
+            and time.time() - self.last_saved_at <= seconds)
 
     # -- manifest -----------------------------------------------------------
 
@@ -750,6 +759,7 @@ class Checkpointer(LifecycleComponent):
         super().stop()
 
     def _loop(self) -> None:
+        name_os_thread("sw-checkpoint")
         while not self._stop.wait(self.interval_s):
             try:
                 self.save()

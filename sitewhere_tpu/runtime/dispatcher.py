@@ -78,6 +78,7 @@ from __future__ import annotations
 import collections
 import collections.abc
 import functools
+import json
 import logging
 import os
 import threading
@@ -94,6 +95,11 @@ from sitewhere_tpu.ingest.decoders import DecodedRequest
 from sitewhere_tpu.ingest.journal import Journal, JournalReader
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
+from sitewhere_tpu.runtime.process import (
+    DUMP_STALL_S,
+    StallWitness,
+    name_os_thread,
+)
 from sitewhere_tpu.runtime.resilience import dead_letter
 from sitewhere_tpu.schema import EventType, as_numpy
 from sitewhere_tpu.store import segment as _segment_schema
@@ -509,6 +515,21 @@ class PipelineDispatcher(LifecycleComponent):
         # device_wait_s.
         # One observation per host sync (pipeline.host_syncs).
         self._m_device_wait = metrics.timer("pipeline.device_wait_s")
+        # The egress legs, each a child of the egress stage beside the
+        # request tracer's span of the same leg: the store append (its
+        # inline seal is ``store.inline_seal_s``), the outbound submit,
+        # the re-injection of derived alerts with its nested take.
+        self._m_egress_persist = metrics.timer("pipeline.egress_persist_s")
+        self._m_egress_outbound = metrics.timer("pipeline.egress_outbound_s")
+        self._m_egress_reinject = metrics.timer("pipeline.egress_reinject_s")
+        # Waits for _lock at _take, by the edge that waits: a live wire
+        # payload, and the egress worker re-injecting alerts.
+        self._m_lock_wait_wire = metrics.timer("pipeline.lock_wait_wire_s")
+        self._m_lock_wait_reinject = metrics.timer(
+            "pipeline.lock_wait_reinject_s")
+        # The commit gate's flush and journal commit, held under
+        # _step_lock and _lock: one observation a commit.
+        self._m_commit_gate = metrics.timer("pipeline.commit_gate_s")
         # "How often does the host touch the device" as a first-class
         # metric: one inc per BLOCKING device→host sync on the dispatch/
         # egress path (the packed views' lazy fetch, the ring's shared
@@ -552,6 +573,13 @@ class PipelineDispatcher(LifecycleComponent):
         # instance wiring.  None = recording off (tests composing bare
         # dispatchers).
         self.flightrec = flightrec
+        # The process layer (runtime/process.py): the loop thread's
+        # waits witness a process that stood still (a ``stall`` flight
+        # record), and full collections are spans while the dispatcher
+        # runs (``stall_witness.full_collections``, drained each wake).
+        # ``stall_witness.save_probe`` is the instance's wiring.
+        self.stall_witness = StallWitness(
+            metrics, report=None if flightrec is None else self._on_stall)
         # SLO burn-rate engine (runtime/metrics.py BurnRateEngine): the
         # loop thread ticks it alongside the overload controller.
         self.slo = slo
@@ -691,7 +719,7 @@ class PipelineDispatcher(LifecycleComponent):
     # -- ingest entry points (wired as InboundEventSource.on_event) ---------
 
     def _take(self, intake: Callable[[], object],
-              live_wire: bool = False) -> List[BatchPlan]:
+              live_wire: bool = False, edge=None) -> List[BatchPlan]:
         """Run a batcher intake under the lock, counting every emitted plan
         as outstanding until its egress completes — the commit gate's
         accounting (see ``_maybe_commit_offset``).
@@ -701,9 +729,20 @@ class PipelineDispatcher(LifecycleComponent):
         — and filled no segment leave now (``Batcher.emit_idle``) instead
         of waiting for the loop's deadline poll, which would coalesce
         them with nothing.  Anything outstanding closes that gate, and
-        the rows wait for the deadline as every other intake's do."""
+        the rows wait for the deadline as every other intake's do.
+
+        The wait for the lock is timed (``Timer.time()`` around the
+        acquire alone, closed as the first statement under the lock,
+        which stays a ``with`` region for the lock-order lint) into
+        ``pipeline.lock_wait_wire_s`` where ``live_wire``, else into
+        ``edge`` where one is given (the re-injection's)."""
+        if live_wire:
+            edge = self._m_lock_wait_wire
         with self._m_stage["batch"].time() as span:
+            wait = None if edge is None else edge.time().__enter__()
             with self._lock:
+                if wait is not None:
+                    wait.__exit__(None, None, None)
                 idle = (live_wire and self._plans_outstanding == 0
                         and self.batcher.pending == 0)
                 out = intake()
@@ -1200,6 +1239,7 @@ class PipelineDispatcher(LifecycleComponent):
                 metrics=self.metrics)
             self._egress_super.start()
         self._warm_programs()
+        self.stall_witness.full_collections.install()
         self._thread = threading.Thread(
             target=self._loop, name=f"{self.name}-loop", daemon=True
         )
@@ -1312,13 +1352,34 @@ class PipelineDispatcher(LifecycleComponent):
             self._egress_evt.set()
             self._egress_super.stop()
             self._egress_super = None
+        self.stall_witness.full_collections.remove()
         super().stop()
 
+    def _on_stall(self, record: dict) -> None:
+        """A witnessed stall (``StallWitness.report``): a ``stall``
+        record in the flight recorder's ring for every one; the ring
+        dumped (the ``stall`` anomaly, the record as its detail) only for
+        one of ``DUMP_STALL_S`` or more, long enough to cost sends."""
+        self.flightrec.record(kind="stall", **record)
+        if record["late_ms"] >= DUMP_STALL_S * 1e3:
+            self._dump_async("stall", json.dumps(record, sort_keys=True))
+
+    def _dump_async(self, reason: str,
+                    detail: Optional[str] = None) -> None:
+        """The flight recorder's anomaly ``reason``, dumped off the
+        calling thread (a snapshot is a file write of the whole ring)."""
+        threading.Thread(target=self.flightrec.anomaly,
+                         args=(reason, detail), daemon=True,
+                         name="flightrec-dump").start()
+
     def _loop(self) -> None:
+        name_os_thread("sw-loop")
         # poll at half the (possibly adaptive) deadline, floored at 2 ms:
         # an idle instance whose window shrank to the floor must not spin
-        # the loop thread at sub-millisecond cadence
-        while not self._stop.wait(max(self.batcher.deadline_s / 2, 0.002)):
+        # the loop thread at sub-millisecond cadence; every wait is
+        # witnessed, so a wake that comes late records a stall
+        wait = self.stall_witness.wait
+        while not wait(self._stop, max(self.batcher.deadline_s / 2, 0.002)):
             try:
                 from sitewhere_tpu import native as _native
 
@@ -1428,9 +1489,10 @@ class PipelineDispatcher(LifecycleComponent):
                     return
                 upto = self._max_egressed_ref + 1
                 if upto > reader.committed:
-                    if self.event_store is not None:
-                        self.event_store.flush()
-                    reader.commit(upto)
+                    with self._m_commit_gate.time():
+                        if self.event_store is not None:
+                            self.event_store.flush()
+                        reader.commit(upto)
 
     def replay_journal(self, decoder=None, max_records: int = 4096,
                        upto: Optional[int] = None,
@@ -2114,6 +2176,17 @@ class PipelineDispatcher(LifecycleComponent):
                 detail=f"{elapsed_s:.3f}s in flight "
                        f"(soft budget {self.watchdog.soft_s:.3f}s)")
 
+    def _on_slow_fetch(self, plan: BatchPlan, seconds: float) -> None:
+        """An egress whose fetch of the step's outputs took the soft
+        budget or more: the plan's ``slow-fetch`` record (its ``seq``,
+        rows, reason and ``fetch_ms``) in the flight recorder's ring,
+        and the ring, that record last, dumped (``device-slow-fetch``)
+        off the egress thread."""
+        self.flightrec.record(kind="slow-fetch",
+                              fetch_ms=round(seconds * 1e3, 3),
+                              **self._wd_record(plan))
+        self._dump_async("device-slow-fetch")
+
     def _on_watchdog_hard(self, payload, elapsed_s: float) -> None:
         self._m_fault["watchdog_hard_trips"].inc()
         # shard-scoped wedge attribution (mesh): the breaker bank's
@@ -2524,6 +2597,7 @@ class PipelineDispatcher(LifecycleComponent):
         restarts the loop with backoff, and the failed plan stays
         outstanding (the commit gate fails closed; journal replay
         recovers its rows after a restart: at-least-once)."""
+        name_os_thread("sw-egress")
         while True:
             item = None
             with self._step_lock:
@@ -2626,8 +2700,12 @@ class PipelineDispatcher(LifecycleComponent):
         """The egress stage's body (one ``pipeline.stage_egress_s``
         span): fetch the step's outputs — the one place the host blocks
         on the device, timed as ``pipeline.device_wait_s`` — then store,
-        fan out, re-inject.  Returns the plan's end-to-end latency."""
+        fan out, re-inject, each of those three legs a child span of its
+        own (``pipeline.egress_persist_s``, ``_outbound_s``,
+        ``_reinject_s``) around the request tracer's span of the leg.
+        Returns the plan's end-to-end latency."""
         host_cols = plan.host_cols
+        fetch_t0 = time.perf_counter()
         with trace.span("egress.fetch-outputs"):
             # the view counts and times its own lazy fetch (on_fetch /
             # wait_timer), which this access triggers; the accepted mask
@@ -2635,6 +2713,9 @@ class PipelineDispatcher(LifecycleComponent):
             m = as_numpy(out.metrics)
             accepted = out.accepted
             cols = self._columns(host_cols, out)
+        fetch_s = time.perf_counter() - fetch_t0
+        if fetch_s >= self.watchdog.soft_s and self.flightrec is not None:
+            self._on_slow_fetch(plan, fetch_s)
         for key in ("processed", "accepted", "unregistered", "unassigned",
                     "threshold_alerts", "zone_alerts"):
             count = int(getattr(m, key))
@@ -2688,8 +2769,9 @@ class PipelineDispatcher(LifecycleComponent):
             store_mask = accepted & ((refs == NULL_ID)
                                      | (refs >= self.store_dedup_floor))
         if self.event_store is not None and store_mask.any():
-            with trace.span("egress.persist").tag(
-                    "rows", int(store_mask.sum())):
+            with self._m_egress_persist.time(seq=plan.seq), \
+                    trace.span("egress.persist").tag(
+                        "rows", int(store_mask.sum())):
                 self.event_store.append_columns(cols, mask=store_mask)
             self._m_seal.set(time.monotonic() - ingest_t0)
         elif accepted.any() and (self.outbound is not None
@@ -2710,7 +2792,8 @@ class PipelineDispatcher(LifecycleComponent):
         # 2. enriched fan-out (outbound connectors + rule processor hosts)
         #    — the trace rides along so the async delivery span joins it
         if self.outbound is not None and accepted.any():
-            with trace.span("egress.outbound"):
+            with self._m_egress_outbound.time(seq=plan.seq), \
+                    trace.span("egress.outbound"):
                 self.outbound.submit(cols, accepted, trace=trace,
                                      ingest_t0=ingest_t0)
 
@@ -2757,7 +2840,8 @@ class PipelineDispatcher(LifecycleComponent):
         #    events, reference ZoneTestRuleProcessor fires alerts back
         #    through event management) — fetched only when rules fired
         if int(m.threshold_alerts) + int(m.zone_alerts) > 0:
-            with trace.span("egress.derived-alerts"):
+            with self._m_egress_reinject.time(seq=plan.seq), \
+                    trace.span("egress.derived-alerts"):
                 self._reinject_derived(plan, out, replay_depth)
 
         # Egress complete: record the plan's end-to-end latency (batcher
@@ -3002,8 +3086,8 @@ class PipelineDispatcher(LifecycleComponent):
         self.totals["derived_alerts"] += int(rows.size)
         cols = out.derived_cols(plan.host_cols, rows)
         self._run_plans(self._take(
-            lambda: self.batcher.add_arrays(_copy=False, **cols)),
-            replay_depth + 1)
+            lambda: self.batcher.add_arrays(_copy=False, **cols),
+            edge=self._m_lock_wait_reinject), replay_depth + 1)
 
     def inject_rule_alerts(self, cols: Dict[str, np.ndarray]) -> int:
         """Re-inject fired tenant-program alerts as first-class ALERT

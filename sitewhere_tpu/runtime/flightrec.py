@@ -45,12 +45,25 @@ from sitewhere_tpu.analysis.markers import hot_path  # noqa: E402
 
 _REASON_RE = re.compile(r"[^a-z0-9_-]")
 
+#: reasons whose dumps are pruned among themselves (each keeps its own
+#: newest ``max_snapshots``): a process stall is routine next to a crash,
+#: and a run of stall dumps must never prune a crash's evidence
+OWN_CAP_REASONS = frozenset({"stall"})
+
 
 def _safe_reason(reason: str) -> str:
     """Reason → filename fragment (anomaly reasons embed operator/config
     strings; they must never mint a path)."""
     out = _REASON_RE.sub("-", str(reason).lower())[:48]
     return out or "anomaly"
+
+
+def _pool(name: str) -> Optional[str]:
+    """The pruning pool of a snapshot file ``<seq>-<reason>.jsonl``: its
+    reason where that is in :data:`OWN_CAP_REASONS`, else None (the
+    shared pool)."""
+    reason = name.split("-", 1)[-1][:-len(".jsonl")]
+    return reason if reason in OWN_CAP_REASONS else None
 
 
 class FlightRecorder:
@@ -66,7 +79,8 @@ class FlightRecorder:
       because an unrelated overload transition dumped moments earlier.
       Explicit :meth:`snapshot` calls bypass it.
     - ``max_snapshots``: oldest snapshot files pruned beyond this
-      (``<= 0`` disables pruning — unlimited retention).
+      (``<= 0`` disables pruning — unlimited retention); a reason in
+      :data:`OWN_CAP_REASONS` is counted and pruned apart from the rest.
 
     Thread-safe; ``record`` is the only hot-path entry and does no I/O.
     """
@@ -179,15 +193,17 @@ class FlightRecorder:
         self._m_snapshots.inc()
         logger.warning("flight recorder dumped %d records to %s (%s)",
                        len(records), name, reason)
-        self._prune()
+        self._prune(_pool(name))
         return path
 
-    def _prune(self) -> None:
+    def _prune(self, pool: Optional[str]) -> None:
+        """Prune the snapshots of ``pool`` (:func:`_pool`) to the
+        newest ``max_snapshots``."""
         if self.max_snapshots <= 0:
             return   # <= 0 means unlimited retention, never "delete all"
         try:
             names = sorted(n for n in os.listdir(self.dir)
-                           if n.endswith(".jsonl"))
+                           if n.endswith(".jsonl") and _pool(n) == pool)
             for name in names[:-self.max_snapshots]:
                 os.unlink(os.path.join(self.dir, name))
         except OSError:

@@ -93,7 +93,9 @@ class Timer:
     A timer is also a span: :meth:`time` times a lexical region into the
     timer AND, while a ``jax.profiler`` session is open, leaves a
     host-plane event under the timer's ``name`` on the profiler's clock
-    (the same clock as the device trace).
+    (the same clock as the device trace); :meth:`mark` does the same for
+    a time measured elsewhere.  :class:`TimedSpan` is the one place a
+    profiler annotation is made.
     """
 
     def __init__(self, reservoir: int = 4096, name: str = "timer"):
@@ -117,6 +119,16 @@ class Timer:
         ``tags`` ride the profiler event (spans of one plan share
         ``seq``); the registry side takes the duration only."""
         return TimedSpan(self, tags)
+
+    def mark(self, seconds: float, **tags) -> None:
+        """Observe ``seconds`` measured elsewhere, and leave a marker
+        event of the timer's name that closes now, carrying ``tags``
+        (the duration itself as a tag: a profiler event cannot start in
+        the past).  For a time known only after the fact, e.g. how late
+        a wake came."""
+        with TimedSpan(self, tags) as span:
+            span.discard()
+        self.observe(seconds)
 
     def percentile(self, q: float) -> float:
         with self._lock:

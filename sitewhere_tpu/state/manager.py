@@ -15,7 +15,6 @@ resulting indices/rows copied back.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import threading
 from typing import Dict, List, Optional
@@ -270,13 +269,11 @@ class DeviceStateManager(LifecycleComponent):
     def bind_metrics(self, metrics) -> None:
         """The presence sweep's instruments (a ``Timer.time()`` region
         is a profiler span of the timer's name): ``presence.sweep_s`` is
-        the whole of :meth:`apply_presence_sweep`,
-        ``presence.sweep_device_s`` the sweep program from its dispatch
-        until its outputs are ready (before the mask's D2H);
-        ``presence.sweeps`` counts sweeps, ``presence.reported`` the rows
-        handed on for re-injection."""
+        the whole of :meth:`apply_presence_sweep`; ``presence.sweeps``
+        counts sweeps, ``presence.reported`` the rows handed on for
+        re-injection.  The sweep program's own time is the device
+        trace's, under the ``presence_sweep`` scope."""
         self._m_sweep = metrics.timer("presence.sweep_s")
-        self._m_sweep_device = metrics.timer("presence.sweep_device_s")
         self._m_sweeps = metrics.counter("presence.sweeps")
         self._m_reported = metrics.counter("presence.reported")
 
@@ -498,10 +495,8 @@ class DeviceStateManager(LifecycleComponent):
         The report is numpy from the mask on: no program depends on how
         many devices went silent, so none is compiled for a new count.
         """
-        with self._m_sweep.time(), contextlib.ExitStack() as device:
+        with self._m_sweep.time():
             with self._lock:
-                # the device span opens once the lock is held
-                device.enter_context(self._m_sweep_device.time())
                 if self._packed is not None:
                     self._packed, newly = _packed_sweep()(
                         self._packed, jnp.int32(now_s),
@@ -511,11 +506,9 @@ class DeviceStateManager(LifecycleComponent):
                     self._state, newly = presence_sweep(
                         self.current, jnp.int32(now_s),
                         jnp.int32(missing_after_s))
-            # outside the lock (a commit must not wait for the chip), and
-            # on the mask, which no later chain can donate
-            jax.block_until_ready(newly)
-            device.close()
             self._m_sweeps.inc()
+            # the mask's fetch waits for the chip outside the lock (a
+            # commit must not wait for it); no later chain can donate it
             (idx,) = np.nonzero(np.asarray(newly))
             if idx.size == 0:
                 return None

@@ -25,6 +25,7 @@ import numpy as np
 
 from sitewhere_tpu.ids import NULL_ID
 from sitewhere_tpu.runtime.lifecycle import LifecycleComponent
+from sitewhere_tpu.runtime.process import name_os_thread
 from sitewhere_tpu.schema import DeviceState, EventType
 
 logger = logging.getLogger("sitewhere_tpu.state.presence")
@@ -136,6 +137,7 @@ class PresenceManager(LifecycleComponent):
         return count
 
     def _loop(self) -> None:
+        name_os_thread("sw-presence")
         while not self._stop.wait(self.check_interval_s):
             try:
                 self.sweep_once()

@@ -34,6 +34,7 @@ journal replay re-derives the rows).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -44,6 +45,7 @@ import numpy as np
 
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.resilience import RetryPolicy, Supervisor, dead_letter
+from sitewhere_tpu.runtime.process import name_os_thread
 from sitewhere_tpu.store.segment import (
     INT_COLUMNS,
     Segment,
@@ -114,7 +116,8 @@ class SealerPool:
         self._stopping.clear()
         self.running = True
         self._supervisors = [
-            Supervisor(f"store-seal-{i}", self._worker_loop,
+            Supervisor(f"store-seal-{i}",
+                       functools.partial(self._worker_loop, i),
                        policy=self._policy, max_restarts=64,
                        min_uptime_s=5.0)
             for i in range(self.n_workers)
@@ -243,7 +246,8 @@ class SealerPool:
 
     # -- worker side ---------------------------------------------------------
 
-    def _worker_loop(self) -> None:
+    def _worker_loop(self, index: int) -> None:
+        name_os_thread(f"sw-seal-{index}")
         while not self._stopping.is_set():
             with self._cond:
                 while not self._queue and not self._stopping.is_set():

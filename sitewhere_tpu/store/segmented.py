@@ -191,6 +191,9 @@ class SegmentStore(EventStore):
         self.metrics.histogram("store.seal_s")
         self.metrics.histogram("store.compact_s")
         self._m_buffered = self.metrics.gauge("store.buffered_rows")
+        # the backpressure valve's seal on the writer's own thread
+        # (``append_columns``): a child of the egress persist leg
+        self._m_inline_seal = self.metrics.timer("store.inline_seal_s")
         self._update_gauges()
 
     # -- layout --------------------------------------------------------------
@@ -228,7 +231,8 @@ class SegmentStore(EventStore):
         if added:
             self._m_buffered.set(self._buffered_rows)
             if self.sealer.queue_depth() > 4 + self.sealer.n_workers:
-                self.sealer.pump_one()
+                with self._m_inline_seal.time():
+                    self.sealer.pump_one()
         return added
 
     def _route_and_fill(self, cols, mask) -> int:

@@ -109,6 +109,23 @@ class TestFlightRecorder:
         # newest survive, file sequence keeps counting
         assert names[-1].startswith("000005-")
 
+    def test_stall_dumps_are_pruned_apart_from_the_rest(self, tmp_path):
+        """A run of routine stall dumps never prunes a crash's evidence,
+        and the rest never prune the stall dumps."""
+        rec = FlightRecorder(data_dir=str(tmp_path), max_snapshots=2)
+        rec.record(seq=1, commit="ok")
+        crash = os.path.basename(rec.snapshot("egress-crash"))
+        for _ in range(5):
+            rec.snapshot("stall")
+        names = [s["name"] for s in rec.snapshots()]
+        assert crash in names
+        assert sum(n.endswith("-stall.jsonl") for n in names) == 2
+        for i in range(3):
+            rec.snapshot(f"overload-{i}")
+        names = [s["name"] for s in rec.snapshots()]
+        assert sum(n.endswith("-stall.jsonl") for n in names) == 2
+        assert len(names) == 4 and crash not in names
+
     def test_rate_limit_is_per_reason(self, tmp_path):
         """An egress crash must never lose its dump because an
         unrelated overload transition dumped moments earlier."""

@@ -213,6 +213,5 @@ def test_the_sweeps_instruments(manager):
     assert snap["counters"]["presence.sweeps"] == 2
     assert snap["counters"]["presence.reported"] == 2
     assert snap["timers"]["presence.sweep_s"]["count"] == 2
-    assert snap["timers"]["presence.sweep_device_s"]["count"] == 2
-    assert (snap["timers"]["presence.sweep_device_s"]["mean_ms"]
-            <= snap["timers"]["presence.sweep_s"]["mean_ms"])
+    # the sweep program's own time is the device trace's, not a host span
+    assert "presence.sweep_device_s" not in snap["timers"]

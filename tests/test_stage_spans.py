@@ -30,6 +30,18 @@ NEW_TIMERS = (
     "pipeline.stage_dispatch_wait_s",
     "ingest.journal_append_s",
     "checkpoint.save_s",
+    # the egress worker's legs, the intake lock's waiters and holder
+    "pipeline.egress_persist_s",
+    "pipeline.egress_outbound_s",
+    "pipeline.egress_reinject_s",
+    "pipeline.lock_wait_wire_s",
+    "pipeline.lock_wait_reinject_s",
+    "pipeline.commit_gate_s",
+    "store.inline_seal_s",
+    # the process layer: stalls of the loop's waits, full collections
+    "runtime.stall_s",
+    "runtime.stall_cpu_s",
+    "runtime.gc_full_s",
 )
 
 
