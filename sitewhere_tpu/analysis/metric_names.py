@@ -93,10 +93,13 @@ FAMILIES: Dict[str, Optional[Set[str]]] = {
         "rows_compacted", "segments_compacted",
         "scan_rows", "scan_hot_hits", "scan_pruned",
         "tier_promotions", "tier_demotions",
+        # committed seals, and those the native writer made
+        "seals", "seals_native",
         # histograms (background stage timers)
         "seal_s", "compact_s",
-        # timers: the writer-thread seal of the backpressure valve
-        "inline_seal_s",
+        # timers: the writer-thread seal of the backpressure valve, and
+        # every seal job on whichever thread ran it
+        "inline_seal_s", "seal_job_s",
         # gauges
         "segments", "segments_hot", "hot_bytes",
         "seal_queue_depth", "buffered_rows", "catalog_drift",

@@ -92,6 +92,15 @@ def load_swwire():
         _load_lock.release()
 
 
+def loaded_swwire():
+    """The _swwire module if a load has already finished, else None: for
+    a caller off the decode path (the event store's seal) that must
+    neither build the module, nor wait for a build in flight, nor count
+    a decode fallback.  ``Instance.start``'s warm-up and the decode tier
+    load it; until then such a caller takes its Python path."""
+    return _swwire
+
+
 def build_swwire():
     """Compile ``swwire.c`` NOW on the calling thread and load it — for
     a caller that must know the native tier is in place before its first

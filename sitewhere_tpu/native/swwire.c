@@ -20,6 +20,10 @@
  * Returns (tokens: list[str], names: list[str], values: bytes[f64],
  *          ts: bytes[f64], update_state: bytes[u8]) or None.
  *
+ * The module also holds the event store's seal (seal_segment, below):
+ * a segment's zone maps, Blooms and .npz file made in one call with the
+ * GIL released.
+ *
  * Reference justification: SURVEY.md §0 — "the native/performance tier
  * of the new framework is the TPU kernels themselves plus any C++
  * host-side ingest shim we choose to write — justified by capability
@@ -28,11 +32,17 @@
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
+#include <errno.h>
+#include <fcntl.h>
+#include <limits.h>
 #include <math.h>
 #include <pthread.h>
 #include <stdint.h>
 #include <stdlib.h>
 #include <string.h>
+#include <sys/uio.h>
+#include <time.h>
+#include <unistd.h>
 
 typedef struct {
     const char *p;
@@ -2218,6 +2228,512 @@ ts_bail:
     Py_RETURN_NONE;
 }
 
+/* ---- seal_segment: one sealed event segment, the GIL released -------
+ *
+ * The event store's seal (store/segment.py seal_segment_file) in one
+ * call: the zone maps, the Blooms and the whole .npz are made without the
+ * interpreter.  In Python the same seal is cheap alone but dear beside a
+ * busy process: np.savez makes ~330 file-object calls, each a turn at the
+ * GIL, so the seal workers fall behind and the store's valve seals on the
+ * writer's thread.  Here the GIL is held only to take the arguments.
+ *
+ * seal_segment(path, ints, flts, spec, shard, shard_count, replaces,
+ *              core, bounds, blooms, sync) -> None
+ *
+ *   ints, flts  [Ci, n] int32 and [Cf, n] float32 blocks: each row
+ *               contiguous, rows at any stride (views of a shard buffer).
+ *   spec        (names, ts_row, filter_rows, bloom_rows, version,
+ *               bloom_bits, h1, h2), built once by segment.py: the member
+ *               names in file order (the Ci + Cf columns, _meta_core,
+ *               _meta_bounds, _meta_shard, _meta_replaces, then one Bloom
+ *               member per bloom row), the int rows the metadata reads,
+ *               the metadata version and the Bloom's size and hashes.
+ *   replaces    int64 [k, 3] C-contiguous, or None (member left out).
+ *   core        int64 [4], written: [version, n, min_ts, max_ts].
+ *   bounds      int64 [F, 2], written: (min, max) per filter row, and
+ *               (0, -1) for an empty segment.
+ *   blooms      uint8 [B, 2**bloom_bits / 8], written: bit
+ *               ((uint64)(int64)v * h) >> (64 - bloom_bits) set for h1 and
+ *               h2, packed MSB-first (np.packbits).
+ *
+ * The file is a stored (uncompressed) zip of .npy v1.0 members, each
+ * member's bytes as numpy.lib.format writes them and each with its
+ * CRC-32, so np.load reads it as it reads np.savez's.  fsyncs when sync
+ * is true.  Raises OSError on an IO failure (the partial file is left,
+ * as np.savez leaves it).
+ */
+
+#if defined(__BYTE_ORDER__) && __BYTE_ORDER__ == __ORDER_BIG_ENDIAN__
+#define NPY_BO ">"
+#define SW_LITTLE_ENDIAN 0
+#else
+#define NPY_BO "<"
+#define SW_LITTLE_ENDIAN 1
+#endif
+#ifndef IOV_MAX
+#define IOV_MAX 1024
+#endif
+
+/* slicing-by-8 tables over crc_table: ~8x the bytewise rate, which
+ * matters at ~800 KB a segment */
+static uint32_t crc_tab8[8][256];
+
+static void crc_init8(void) {
+    if (!crc_table_ready) crc_init();
+    for (int i = 0; i < 256; i++) crc_tab8[0][i] = crc_table[i];
+    for (int i = 0; i < 256; i++)
+        for (int t = 1; t < 8; t++)
+            crc_tab8[t][i] = (crc_tab8[t - 1][i] >> 8)
+                             ^ crc_table[crc_tab8[t - 1][i] & 0xFF];
+}
+
+/* zlib.crc32(buf, prev) */
+static uint32_t crc32_fast(uint32_t prev, const unsigned char *p,
+                           size_t len) {
+    uint32_t c = prev ^ 0xFFFFFFFFu;
+#if SW_LITTLE_ENDIAN
+    while (len >= 8) {
+        uint32_t one, two;
+        memcpy(&one, p, 4);
+        memcpy(&two, p + 4, 4);
+        one ^= c;
+        c = crc_tab8[7][one & 0xFF] ^ crc_tab8[6][(one >> 8) & 0xFF]
+            ^ crc_tab8[5][(one >> 16) & 0xFF] ^ crc_tab8[4][one >> 24]
+            ^ crc_tab8[3][two & 0xFF] ^ crc_tab8[2][(two >> 8) & 0xFF]
+            ^ crc_tab8[1][(two >> 16) & 0xFF] ^ crc_tab8[0][two >> 24];
+        p += 8;
+        len -= 8;
+    }
+#endif
+    while (len--) c = crc_table[(c ^ *p++) & 0xFF] ^ (c >> 8);
+    return c ^ 0xFFFFFFFFu;
+}
+
+#define NPY_HDR_MAX 256
+#define ZIP_NAME_MAX 72
+#define ZIP_LOCAL 30
+#define ZIP_CENTRAL 46
+#define ZIP_END 22
+
+/* The .npy v1.0 header numpy.lib.format._write_array_header writes: the
+ * dict with sorted keys, 21 - len(repr(shape[0])) spaces of room to
+ * grow, then spaces and a newline to a 64-byte boundary.  d1 < 0 for a
+ * 1-D shape.  Returns its length (at most NPY_HDR_MAX). */
+static size_t npy_header(char *out, const char *descr, long long d0,
+                         long long d1) {
+    char dict[160];
+    int len = d1 < 0
+        ? snprintf(dict, sizeof dict, "{'descr': '%s', 'fortran_order': "
+                   "False, 'shape': (%lld,), }", descr, d0)
+        : snprintf(dict, sizeof dict, "{'descr': '%s', 'fortran_order': "
+                   "False, 'shape': (%lld, %lld), }", descr, d0, d1);
+    int grow = 21 - snprintf(NULL, 0, "%lld", d0);
+    if (grow < 0) grow = 0;
+    size_t hlen = (size_t)len + (size_t)grow + 1;
+    size_t padlen = 64 - ((10 + hlen) % 64);
+    size_t body = hlen + padlen;
+    memcpy(out, "\x93NUMPY\x01\x00", 8);
+    out[8] = (char)(body & 0xFF);
+    out[9] = (char)((body >> 8) & 0xFF);
+    memcpy(out + 10, dict, (size_t)len);
+    memset(out + 10 + len, ' ', (size_t)grow + padlen);
+    out[10 + body - 1] = '\n';
+    return 10 + body;
+}
+
+static inline void put16(unsigned char *p, uint32_t v) {
+    p[0] = (unsigned char)v;
+    p[1] = (unsigned char)(v >> 8);
+}
+
+static inline void put32(unsigned char *p, uint32_t v) {
+    p[0] = (unsigned char)v;
+    p[1] = (unsigned char)(v >> 8);
+    p[2] = (unsigned char)(v >> 16);
+    p[3] = (unsigned char)(v >> 24);
+}
+
+typedef struct {
+    char name[ZIP_NAME_MAX]; /* "<member>.npy", copied under the GIL */
+    size_t name_len;
+    const char *descr;
+    long long d0, d1;
+    const void *data;
+    size_t data_len;
+    /* local header + name + npy header, then what the central
+     * directory needs */
+    unsigned char head[ZIP_LOCAL + ZIP_NAME_MAX + NPY_HDR_MAX];
+    size_t head_len;
+    uint32_t crc, size, offset;
+} zmember;
+
+static void zip_local(zmember *m, uint32_t offset, uint16_t dtime,
+                      uint16_t ddate) {
+    unsigned char *h = m->head;
+    size_t name_at = ZIP_LOCAL;
+    memcpy(h + name_at, m->name, m->name_len);
+    unsigned char *npy = h + name_at + m->name_len;
+    size_t npy_len = npy_header((char *)npy, m->descr, m->d0, m->d1);
+    m->crc = crc32_fast(crc32_fast(0, npy, npy_len),
+                        (const unsigned char *)m->data, m->data_len);
+    m->size = (uint32_t)(npy_len + m->data_len);
+    m->offset = offset;
+    put32(h, 0x04034b50u);
+    put16(h + 4, 20);                 /* version needed: 2.0 */
+    put16(h + 6, 0);                  /* flags */
+    put16(h + 8, 0);                  /* stored */
+    put16(h + 10, dtime);
+    put16(h + 12, ddate);
+    put32(h + 14, m->crc);
+    put32(h + 18, m->size);           /* compressed */
+    put32(h + 22, m->size);           /* uncompressed */
+    put16(h + 26, (uint32_t)m->name_len);
+    put16(h + 28, 0);                 /* extra */
+    m->head_len = name_at + m->name_len + npy_len;
+}
+
+static size_t zip_central(unsigned char *c, const zmember *m,
+                          uint16_t dtime, uint16_t ddate) {
+    put32(c, 0x02014b50u);
+    put16(c + 4, (3u << 8) | 20);     /* made by: unix, 2.0 */
+    put16(c + 6, 20);
+    put16(c + 8, 0);
+    put16(c + 10, 0);
+    put16(c + 12, dtime);
+    put16(c + 14, ddate);
+    put32(c + 16, m->crc);
+    put32(c + 20, m->size);
+    put32(c + 24, m->size);
+    put16(c + 28, (uint32_t)m->name_len);
+    put16(c + 30, 0);                 /* extra */
+    put16(c + 32, 0);                 /* comment */
+    put16(c + 34, 0);                 /* disk */
+    put16(c + 36, 0);                 /* internal attributes */
+    put32(c + 38, 0600u << 16);       /* external: -rw------- */
+    put32(c + 42, m->offset);
+    memcpy(c + ZIP_CENTRAL, m->head + ZIP_LOCAL, m->name_len);
+    return ZIP_CENTRAL + m->name_len;
+}
+
+/* writev until every byte is down; -1 with errno on failure */
+static int writev_all(int fd, struct iovec *iov, int cnt) {
+    while (cnt > 0) {
+        while (cnt > 0 && iov->iov_len == 0) { iov++; cnt--; }
+        if (cnt == 0) break;
+        ssize_t w = writev(fd, iov, cnt > IOV_MAX ? IOV_MAX : cnt);
+        if (w < 0) {
+            if (errno == EINTR) continue;
+            return -1;
+        }
+        while (w > 0) {
+            if ((size_t)w >= iov->iov_len) {
+                w -= (ssize_t)iov->iov_len;
+                iov++;
+                cnt--;
+            } else {
+                iov->iov_base = (char *)iov->iov_base + w;
+                iov->iov_len -= (size_t)w;
+                w = 0;
+            }
+        }
+    }
+    return 0;
+}
+
+/* A [C, n] block of 4-byte items whose rows are contiguous. */
+static int seal_block(PyObject *obj, Py_buffer *v, char kind,
+                      const char *what) {
+    if (PyObject_GetBuffer(obj, v, PyBUF_RECORDS_RO) != 0) return -1;
+    const char *f = v->format ? v->format : "B";
+    size_t fl = strlen(f);
+    if (v->ndim != 2 || v->itemsize != 4 || fl == 0 || f[fl - 1] != kind
+        || (v->shape[1] > 1 && v->strides[1] != 4)) {
+        PyBuffer_Release(v);
+        PyErr_Format(PyExc_ValueError,
+                     "%s must be a 2-D block of 4-byte '%c' items with "
+                     "contiguous rows", what, kind);
+        return -1;
+    }
+    return 0;
+}
+
+/* A writable C-contiguous buffer of exactly `want` bytes. */
+static int seal_out(PyObject *obj, Py_buffer *v, Py_ssize_t want,
+                    const char *what) {
+    if (PyObject_GetBuffer(obj, v, PyBUF_WRITABLE | PyBUF_C_CONTIGUOUS)
+        != 0)
+        return -1;
+    if (v->len != want) {
+        PyBuffer_Release(v);
+        PyErr_Format(PyExc_ValueError, "%s must be %zd bytes", what, want);
+        return -1;
+    }
+    return 0;
+}
+
+#define SEAL_MAX_ROWS 64
+
+static PyObject *seal_segment(PyObject *self, PyObject *args) {
+    PyObject *path = NULL, *ints_o, *flts_o, *spec, *rep_o, *core_o,
+             *bounds_o, *blooms_o;
+    long long shard, shard_count;
+    int sync;
+    if (!PyArg_ParseTuple(args, "O&OOO!LLOOOOp", PyUnicode_FSConverter,
+                          &path, &ints_o, &flts_o, &PyTuple_Type, &spec,
+                          &shard, &shard_count, &rep_o, &core_o, &bounds_o,
+                          &blooms_o, &sync))
+        return NULL;
+    PyObject *names, *frows_o, *brows_o;
+    Py_ssize_t ts_row, version, bloom_bits;
+    unsigned long long h1, h2;
+    Py_buffer vi, vf, vr, vc, vb, vbl;
+    int have_i = 0, have_f = 0, have_r = 0, have_c = 0, have_b = 0,
+        have_bl = 0;
+    zmember *mem = NULL;
+    unsigned char *cd = NULL;
+    struct iovec *iov = NULL;
+    PyObject *out = NULL;
+    Py_ssize_t frow[SEAL_MAX_ROWS], brow[SEAL_MAX_ROWS];
+
+    if (!PyArg_ParseTuple(spec, "O!nO!O!nnKK", &PyTuple_Type, &names,
+                          &ts_row, &PyTuple_Type, &frows_o, &PyTuple_Type,
+                          &brows_o, &version, &bloom_bits, &h1, &h2))
+        goto done;
+    if (seal_block(ints_o, &vi, 'i', "ints") != 0) goto done;
+    have_i = 1;
+    if (seal_block(flts_o, &vf, 'f', "flts") != 0) goto done;
+    have_f = 1;
+    Py_ssize_t ci = vi.shape[0], cf = vf.shape[0], n = vi.shape[1];
+    Py_ssize_t nf = PyTuple_GET_SIZE(frows_o), nb = PyTuple_GET_SIZE(brows_o);
+    if (vf.shape[1] != n || nf > SEAL_MAX_ROWS || nb > SEAL_MAX_ROWS
+        || bloom_bits < 3 || bloom_bits > 32 || ts_row < 0 || ts_row >= ci
+        || n >= ((Py_ssize_t)1 << 31)) {
+        PyErr_SetString(PyExc_ValueError, "seal_segment: bad shapes or spec");
+        goto done;
+    }
+    for (Py_ssize_t i = 0; i < nf + nb; i++) {
+        PyObject *o = i < nf ? PyTuple_GET_ITEM(frows_o, i)
+                             : PyTuple_GET_ITEM(brows_o, i - nf);
+        Py_ssize_t r = PyLong_AsSsize_t(o);
+        if (r == -1 && PyErr_Occurred()) goto done;
+        if (r < 0 || r >= ci) {
+            PyErr_SetString(PyExc_ValueError, "seal_segment: row out of range");
+            goto done;
+        }
+        if (i < nf) frow[i] = r; else brow[i - nf] = r;
+    }
+    Py_ssize_t k = 0;
+    if (rep_o != Py_None) {
+        if (PyObject_GetBuffer(rep_o, &vr, PyBUF_C_CONTIGUOUS | PyBUF_FORMAT)
+            != 0)
+            goto done;
+        have_r = 1;
+        if (vr.ndim != 2 || vr.itemsize != 8 || vr.shape[1] != 3) {
+            PyErr_SetString(PyExc_ValueError,
+                            "replaces must be an int64 [k, 3] array");
+            goto done;
+        }
+        k = vr.shape[0];
+    }
+    Py_ssize_t bb = ((Py_ssize_t)1 << bloom_bits) / 8;
+    if (seal_out(core_o, &vc, 4 * 8, "core") != 0) goto done;
+    have_c = 1;
+    if (seal_out(bounds_o, &vb, nf * 2 * 8, "bounds") != 0) goto done;
+    have_b = 1;
+    if (seal_out(blooms_o, &vbl, nb * bb, "blooms") != 0) goto done;
+    have_bl = 1;
+
+    Py_ssize_t nmem = ci + cf + 3 + (k > 0) + nb;
+    if (PyTuple_GET_SIZE(names) != ci + cf + 4 + nb) {
+        PyErr_SetString(PyExc_ValueError, "seal_segment: names do not "
+                        "match the blocks and Blooms");
+        goto done;
+    }
+    mem = PyMem_Calloc((size_t)nmem, sizeof(zmember));
+    cd = PyMem_Malloc((size_t)nmem * (ZIP_CENTRAL + ZIP_NAME_MAX) + ZIP_END);
+    iov = PyMem_Malloc(((size_t)nmem * 2 + 1) * sizeof(struct iovec));
+    if (!mem || !cd || !iov) {
+        PyErr_NoMemory();
+        goto done;
+    }
+    int64_t *core = vc.buf, *bounds = vb.buf, shard_meta[2];
+    uint8_t *blooms = vbl.buf;
+    shard_meta[0] = shard;
+    shard_meta[1] = shard_count;
+    /* the members, in file order; names, shapes and where the bytes are */
+    {
+        Py_ssize_t m = 0;
+        for (Py_ssize_t j = 0; j < ci + cf + 4 + nb; j++) {
+            if (j == ci + cf + 3 && k == 0) continue; /* no _meta_replaces */
+            Py_ssize_t len;
+            const char *s = PyUnicode_AsUTF8AndSize(
+                PyTuple_GET_ITEM(names, j), &len);
+            if (!s) goto done;
+            if (len + 4 > ZIP_NAME_MAX) {
+                PyErr_SetString(PyExc_ValueError, "member name too long");
+                goto done;
+            }
+            zmember *z = &mem[m++];
+            memcpy(z->name, s, (size_t)len);
+            memcpy(z->name + len, ".npy", 4);
+            z->name_len = (size_t)len + 4;
+            z->d1 = -1;
+            if (j < ci) {
+                z->descr = NPY_BO "i4";
+                z->d0 = n;
+                z->data = (const char *)vi.buf + j * vi.strides[0];
+                z->data_len = (size_t)n * 4;
+            } else if (j < ci + cf) {
+                z->descr = NPY_BO "f4";
+                z->d0 = n;
+                z->data = (const char *)vf.buf + (j - ci) * vf.strides[0];
+                z->data_len = (size_t)n * 4;
+            } else if (j < ci + cf + 4) {
+                z->descr = NPY_BO "i8";
+                Py_ssize_t which = j - ci - cf;
+                if (which == 0) {
+                    z->d0 = 4; z->data = core; z->data_len = 32;
+                } else if (which == 1) {
+                    z->d0 = nf; z->d1 = 2; z->data = bounds;
+                    z->data_len = (size_t)nf * 16;
+                } else if (which == 2) {
+                    z->d0 = 2; z->data = shard_meta; z->data_len = 16;
+                } else {
+                    z->d0 = k; z->d1 = 3; z->data = vr.buf;
+                    z->data_len = (size_t)k * 24;
+                }
+            } else {
+                z->descr = "|u1";
+                z->d0 = bb;
+                z->data = blooms + (j - ci - cf - 4) * bb;
+                z->data_len = (size_t)bb;
+            }
+        }
+    }
+    /* stored zip32: refuse what its 4-byte sizes and offsets cannot hold */
+    {
+        unsigned long long total = ZIP_END;
+        for (Py_ssize_t m = 0; m < nmem; m++)
+            total += ZIP_LOCAL + ZIP_CENTRAL + 2 * ZIP_NAME_MAX
+                     + NPY_HDR_MAX + mem[m].data_len;
+        if (total > 0xFFFFFFFFull) {
+            PyErr_SetString(PyExc_ValueError,
+                            "segment too large for one zip32 file");
+            goto done;
+        }
+    }
+
+    const char *cpath = PyBytes_AS_STRING(path);
+    int err = 0;
+    /* The job's buffer is recycled only after its seal commits, and every
+     * block is held through a Py_buffer until return: reading them
+     * without the GIL is safe. */
+    Py_BEGIN_ALLOW_THREADS
+    const char *ib = vi.buf;
+    Py_ssize_t is0 = vi.strides[0];
+    core[0] = version;
+    core[1] = n;
+    core[2] = core[3] = 0;
+    if (n) {
+        const int32_t *ts = (const int32_t *)(ib + ts_row * is0);
+        int32_t lo = ts[0], hi = ts[0];
+        for (Py_ssize_t i = 1; i < n; i++) {
+            if (ts[i] < lo) lo = ts[i];
+            if (ts[i] > hi) hi = ts[i];
+        }
+        core[2] = lo;
+        core[3] = hi;
+    }
+    for (Py_ssize_t f = 0; f < nf; f++) {
+        bounds[2 * f] = 0;
+        bounds[2 * f + 1] = -1;
+        if (!n) continue;
+        const int32_t *r = (const int32_t *)(ib + frow[f] * is0);
+        int32_t lo = r[0], hi = r[0];
+        for (Py_ssize_t i = 1; i < n; i++) {
+            if (r[i] < lo) lo = r[i];
+            if (r[i] > hi) hi = r[i];
+        }
+        bounds[2 * f] = lo;
+        bounds[2 * f + 1] = hi;
+    }
+    memset(blooms, 0, (size_t)(nb * bb));
+    unsigned shift = (unsigned)(64 - bloom_bits);
+    for (Py_ssize_t b = 0; b < nb; b++) {
+        const int32_t *r = (const int32_t *)(ib + brow[b] * is0);
+        uint8_t *bits = blooms + b * bb;
+        for (Py_ssize_t i = 0; i < n; i++) {
+            uint64_t u = (uint64_t)(int64_t)r[i];
+            uint64_t a = (u * h1) >> shift, c = (u * h2) >> shift;
+            bits[a >> 3] |= (uint8_t)(0x80u >> (a & 7));
+            bits[c >> 3] |= (uint8_t)(0x80u >> (c & 7));
+        }
+    }
+    /* headers, CRCs and the central directory (zipfile's DOS clock) */
+    time_t now = time(NULL);
+    struct tm tm;
+    localtime_r(&now, &tm);
+    int year = tm.tm_year + 1900 < 1980 ? 1980 : tm.tm_year + 1900;
+    uint16_t dtime = (uint16_t)((tm.tm_hour << 11) | (tm.tm_min << 5)
+                                | (tm.tm_sec / 2));
+    uint16_t ddate = (uint16_t)(((year - 1980) << 9) | ((tm.tm_mon + 1) << 5)
+                                | tm.tm_mday);
+    uint32_t offset = 0;
+    size_t cd_len = 0;
+    int nio = 0;
+    for (Py_ssize_t m = 0; m < nmem; m++) {
+        zmember *z = &mem[m];
+        zip_local(z, offset, dtime, ddate);
+        offset += (uint32_t)(z->head_len + z->data_len);
+        cd_len += zip_central(cd + cd_len, z, dtime, ddate);
+        iov[nio].iov_base = z->head;
+        iov[nio++].iov_len = z->head_len;
+        iov[nio].iov_base = (void *)z->data;
+        iov[nio++].iov_len = z->data_len;
+    }
+    unsigned char *e = cd + cd_len;
+    put32(e, 0x06054b50u);
+    put16(e + 4, 0);
+    put16(e + 6, 0);
+    put16(e + 8, (uint32_t)nmem);
+    put16(e + 10, (uint32_t)nmem);
+    put32(e + 12, (uint32_t)cd_len);
+    put32(e + 16, offset);
+    put16(e + 20, 0);
+    iov[nio].iov_base = cd;
+    iov[nio++].iov_len = cd_len + ZIP_END;
+    int fd = open(cpath, O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0666);
+    if (fd < 0) {
+        err = errno;
+    } else {
+        if (writev_all(fd, iov, nio) != 0 || (sync && fsync(fd) != 0))
+            err = errno;
+        if (close(fd) != 0 && !err) err = errno;
+    }
+    Py_END_ALLOW_THREADS
+    if (err) {
+        errno = err;
+        PyObject *fn = PyUnicode_DecodeFSDefault(cpath);
+        PyErr_SetFromErrnoWithFilenameObject(PyExc_OSError, fn);
+        Py_XDECREF(fn);
+        goto done;
+    }
+    out = Py_None;
+    Py_INCREF(out);
+done:
+    if (have_i) PyBuffer_Release(&vi);
+    if (have_f) PyBuffer_Release(&vf);
+    if (have_r) PyBuffer_Release(&vr);
+    if (have_c) PyBuffer_Release(&vc);
+    if (have_b) PyBuffer_Release(&vb);
+    if (have_bl) PyBuffer_Release(&vbl);
+    PyMem_Free(mem);
+    PyMem_Free(cd);
+    PyMem_Free(iov);
+    Py_XDECREF(path);
+    return out;
+}
+
 static PyMethodDef methods[] = {
     {"decode_measurement_lines", decode_measurement_lines, METH_O,
      "Scan NDJSON measurement envelopes into column buffers; None = "
@@ -2246,6 +2762,10 @@ static PyMethodDef methods[] = {
     {"split_owner_lines", split_owner_lines, METH_VARARGS,
      "Rendezvous-hash owner per non-blank NDJSON line; -1 = "
      "local/malformed; None = bail, caller must use the Python splitter."},
+    {"seal_segment", seal_segment, METH_VARARGS,
+     "Write one sealed event segment (.npz of .npy members) and fill its "
+     "zone maps and Blooms, with the GIL released; OSError on IO "
+     "failure."},
     {NULL, NULL, 0, NULL},
 };
 
@@ -2256,6 +2776,7 @@ static struct PyModuleDef module = {
 
 PyMODINIT_FUNC PyInit__swwire(void) {
     if (PyType_Ready(&TokenTableType) < 0) return NULL;
+    crc_init8(); /* seal_segment reads the tables without the GIL */
     PyObject *m = PyModule_Create(&module);
     if (!m) return NULL;
     Py_INCREF(&TokenTableType);

@@ -40,10 +40,11 @@ import numpy as np
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.resilience import RetryPolicy, Supervisor
 from sitewhere_tpu.store.segment import (
-    COLUMN_NAMES,
+    FLOAT_COLUMNS,
+    INT_COLUMNS,
     Segment,
     SegmentPruned,
-    write_segment_file,
+    seal_segment_file,
 )
 
 logger = logging.getLogger("sitewhere_tpu.store.compaction")
@@ -156,10 +157,13 @@ class Compactor:
             parts = [c.materialize() for c in run]
         except SegmentPruned:
             return 0
-        merged = {
-            name: np.concatenate([p[name] for p in parts])
-            for name in COLUMN_NAMES
-        }
+        # merged straight into the packed pair the seal writes from
+        n = sum(int(c.n) for c in run)
+        ints = np.empty((len(INT_COLUMNS), n), np.int32)
+        flts = np.empty((len(FLOAT_COLUMNS), n), np.float32)
+        for block, names in ((ints, INT_COLUMNS), (flts, FLOAT_COLUMNS)):
+            for i, name in enumerate(names):
+                np.concatenate([p[name] for p in parts], out=block[i])
         # provenance: direct inputs, plus the transitive sources of any
         # input that was itself a compacted segment — boot-time
         # tombstone resolution and the id remap both need the ORIGINAL
@@ -176,18 +180,17 @@ class Compactor:
         with store._lock:
             seq = store._next_seq
             store._next_seq += 1
-        seg = Segment(seq, merged, shard=run[0].shard,
-                      shard_count=run[0].shard_count)
-        seg.replaces = tuple(replaces)
-        seg.order_key = min(c.order_key for c in run)
         path = store._segment_path(seq)
         t0 = time.perf_counter()
         # the merged file must be DURABLE before any input is unlinked:
         # the inputs may already be the durable trace of a committed
         # journal offset, and a deferred-fsync merged copy could vanish
         # in a power loss after the originals are gone
-        write_segment_file(path, merged, seg, sync=True,
-                           fsync_dir=store._fsync_dir)
+        seg, _ = seal_segment_file(
+            path, seq, ints, flts, shard=run[0].shard,
+            shard_count=run[0].shard_count, replaces=tuple(replaces),
+            sync=True, fsync_dir=store._fsync_dir)
+        seg.order_key = min(c.order_key for c in run)
         # chaos kill point: merged file on disk, inputs still listed +
         # on disk — boot must resolve the tombstones, not double rows
         faults.crosspoint("crash.mid_compact")

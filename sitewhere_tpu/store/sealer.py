@@ -46,12 +46,7 @@ import numpy as np
 from sitewhere_tpu.runtime import faults
 from sitewhere_tpu.runtime.resilience import RetryPolicy, Supervisor, dead_letter
 from sitewhere_tpu.runtime.process import name_os_thread
-from sitewhere_tpu.store.segment import (
-    INT_COLUMNS,
-    Segment,
-    unpack_cols,
-    write_segment_file,
-)
+from sitewhere_tpu.store.segment import INT_COLUMNS, seal_segment_file
 
 logger = logging.getLogger("sitewhere_tpu.store.sealer")
 
@@ -107,6 +102,13 @@ class SealerPool:
         self.running = False
         self.sealed_segments = 0
         self._policy = policy or RetryPolicy(initial_s=0.05, max_s=2.0)
+        # one committed seal each; ``seals_native`` those the native
+        # writer made (GIL released), the rest took np.savez
+        self._m_seals = store.metrics.counter("store.seals")
+        self._m_seals_native = store.metrics.counter("store.seals_native")
+        # a job's whole seal, on whichever thread runs it (a worker, or
+        # the writer through the valve)
+        self._m_job = store.metrics.timer("store.seal_job_s")
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -230,7 +232,8 @@ class SealerPool:
         propagates — a lost job would let a later sync flush report
         durable-success for rows that exist nowhere."""
         try:
-            self._process(job)
+            with self._m_job.time():
+                self._process(job)
         except BaseException:
             with self._cond:
                 if job in self._inflight:
@@ -268,11 +271,8 @@ class SealerPool:
         store = self._store
         if job.committed:
             return
-        cols = unpack_cols(job.ints, job.flts)
         t0 = time.perf_counter()
         try:
-            seg = Segment(job.seq, cols, shard=job.shard,
-                          shard_count=store.n_shards)
             path = store._segment_path(job.seq)
             faults.fire("event_store.seal")
             # chaos kill point: death mid-seal leaves a partial segment
@@ -280,13 +280,21 @@ class SealerPool:
             # the rows (they are below no committed offset — the commit
             # gate's sync flush had not passed this job)
             faults.crosspoint("crash.mid_seal")
-            write_segment_file(path, cols, seg, sync=False)
+            # the native writer reads job.ints/flts with the GIL
+            # released: safe, since the job's buffer is recycled only
+            # after _commit_sealed below
+            seg, native = seal_segment_file(
+                path, job.seq, job.ints, job.flts, shard=job.shard,
+                shard_count=store.n_shards, sync=False)
         except OSError as e:
             self._on_seal_failure(job, e)
             return
         store._commit_sealed(job, seg, path,
                              seal_s=time.perf_counter() - t0)
         self.sealed_segments += 1
+        self._m_seals.inc()
+        if native:
+            self._m_seals_native.inc()
 
     def _on_seal_failure(self, job: SealJob, exc: OSError) -> None:
         store = self._store

@@ -38,6 +38,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from sitewhere_tpu import native
 from sitewhere_tpu.ids import NULL_ID
 
 # Column schema of one stored event row: the EventBatch columns that
@@ -407,17 +408,52 @@ def segment_pruned(c: Segment, active, probes, t0, t1) -> bool:
     return False
 
 
-def write_segment_file(path: str, cols: Dict[str, np.ndarray],
-                       seg: Segment, sync: bool = True,
-                       fsync_dir=None) -> None:
-    """Atomically write one sealed segment: columns + prune metadata.
+# The native writer's constant half (swwire.c ``seal_segment``): the
+# member names in file order, the int rows the zone maps and Blooms
+# read, the metadata version and the Bloom's size and hashes.  This
+# module stays the one definition of the format; the C side takes it
+# from here.
+_SEAL_SPEC = (
+    INT_COLUMNS + FLOAT_COLUMNS
+    + (META_CORE, META_BOUNDS, META_SHARD, META_REPLACES)
+    + tuple(bloom_member(name) for name in BLOOM_COLUMNS),
+    _INT_INDEX["ts_s"],
+    tuple(_INT_INDEX[name] for name in FILTER_COLUMNS),
+    tuple(_INT_INDEX[name] for name in BLOOM_COLUMNS),
+    META_VERSION, BLOOM_BITS, _H1, _H2,
+)
 
-    ``sync=False`` defers the fsyncs: the write stays atomic (tmp +
-    rename) but durability is settled later by the store's deferred-
-    durability pass.  The at-least-once premise only requires a segment
-    to be DURABLE before the journal offset covering its rows is
-    committed (the commit gate's explicit sync flush), not at seal
-    time."""
+
+def _native_seal():
+    """swwire's ``seal_segment`` if the native module is loaded, else
+    None.  Never builds or waits for the module, and never counts a
+    decode fallback: the decode tier and ``Instance.start``'s warm-up
+    load it, and until then a seal takes the Python writer."""
+    return getattr(native.loaded_swwire(), "seal_segment", None)
+
+
+def _tmp_path(path: str) -> str:
+    return f"{path}.tmp.{os.getpid()}"
+
+
+def _write_native(seal, tmp: str, ints: np.ndarray, flts: np.ndarray,
+                  shard: int, shard_count: int, replaces, sync: bool):
+    """One native call: zone maps, Blooms and the whole file, the GIL
+    released throughout.  Returns the ``(core, bounds, blooms)`` arrays
+    it filled and wrote."""
+    core = np.empty(4, np.int64)
+    bounds = np.empty((len(FILTER_COLUMNS), 2), np.int64)
+    blooms = np.empty((len(BLOOM_COLUMNS), (1 << BLOOM_BITS) // 8),
+                      np.uint8)
+    rep = np.asarray(replaces, np.int64) if replaces else None
+    seal(tmp, ints, flts, _SEAL_SPEC, int(shard), int(shard_count), rep,
+         core, bounds, blooms, bool(sync))
+    return core, bounds, blooms
+
+
+def _write_npz(tmp: str, cols: Dict[str, np.ndarray], seg: Segment,
+               sync: bool) -> None:
+    """The Python writer: ``np.savez`` of the columns + metadata."""
     meta = {
         META_CORE: np.asarray(
             [META_VERSION, seg.n, seg.min_ts, seg.max_ts], np.int64),
@@ -429,15 +465,73 @@ def write_segment_file(path: str, cols: Dict[str, np.ndarray],
         meta[META_REPLACES] = np.asarray(seg.replaces, np.int64)
     for bname, bloom in seg.blooms.items():
         meta[bloom_member(bname)] = bloom
-    tmp = f"{path}.tmp.{os.getpid()}"
     with open(tmp, "wb") as f:
         np.savez(f, **cols, **meta)
         if sync:
             f.flush()
             os.fsync(f.fileno())
+
+
+def _publish(tmp: str, path: str, sync: bool, fsync_dir) -> None:
     os.replace(tmp, path)
     if sync and fsync_dir is not None:
         fsync_dir()
+
+
+def write_segment_file(path: str, cols: Dict[str, np.ndarray],
+                       seg: Segment, sync: bool = True,
+                       fsync_dir=None) -> None:
+    """Atomically write one sealed segment: columns + prune metadata.
+
+    ``sync=False`` defers the fsyncs: the write stays atomic (tmp +
+    rename) but durability is settled later by the store's deferred-
+    durability pass.  The at-least-once premise only requires a segment
+    to be DURABLE before the journal offset covering its rows is
+    committed (the commit gate's explicit sync flush), not at seal
+    time.  The column dict's edition of :func:`seal_segment_file` (the
+    metadata is computed again from the same rows)."""
+    ints, flts = pack_cols(cols)
+    seal_segment_file(path, seg.seq, ints, flts, shard=seg.shard,
+                      shard_count=seg.shard_count, replaces=seg.replaces,
+                      sync=sync, fsync_dir=fsync_dir)
+
+
+def seal_segment_file(path: str, seq: int, ints: np.ndarray,
+                      flts: np.ndarray, *, shard: int = NULL_SHARD,
+                      shard_count: int = 0,
+                      replaces: Optional[Tuple[Tuple[int, int, int],
+                                               ...]] = None,
+                      sync: bool = True, fsync_dir=None,
+                      ) -> Tuple[Segment, bool]:
+    """Build and atomically write one sealed segment from a packed
+    ``([Ci, n] int32, [Cf, n] float32)`` pair (rows may be views at any
+    stride).  Returns ``(segment, native)``: the segment keeps its
+    columns resident until ``detach``; ``native`` says the native
+    writer made it, in one call with the GIL released, else the Python
+    writer did.  ``sync`` and ``fsync_dir`` as :func:`write_segment_file`
+    has them."""
+    tmp = _tmp_path(path)
+    cols = unpack_cols(ints, flts)
+    seal = _native_seal()
+    if seal is None:
+        seg = Segment(seq, cols, shard=shard, shard_count=shard_count)
+        seg.replaces = replaces
+        _write_npz(tmp, cols, seg, sync)
+    else:
+        core, bounds, blooms = _write_native(
+            seal, tmp, ints, flts, shard, shard_count, replaces, sync)
+        seg = Segment.lazy(
+            seq, None, None, n=int(core[1]), min_ts=int(core[2]),
+            max_ts=int(core[3]),
+            bounds={name: (int(bounds[i, 0]), int(bounds[i, 1]))
+                    for i, name in enumerate(FILTER_COLUMNS)},
+            blooms={name: blooms[i]
+                    for i, name in enumerate(BLOOM_COLUMNS)},
+            shard=shard, shard_count=shard_count, replaces=replaces)
+        seg.order_key = seq
+        seg._cols = cols
+    _publish(tmp, path, sync, fsync_dir)
+    return seg, seal is not None
 
 
 def open_segment(seq: int, path: str, cache: ColumnCache) -> Segment:
@@ -509,5 +603,6 @@ __all__ = [
     "META_REPLACES", "META_VERSION", "event_id", "split_event_id",
     "pack_cols", "unpack_cols", "bloom_probe", "bloom_member",
     "SegmentPruned", "ColumnCache", "Segment", "segment_pruned",
-    "write_segment_file", "open_segment", "resolve_tombstones",
+    "write_segment_file", "seal_segment_file", "open_segment",
+    "resolve_tombstones",
 ]

@@ -332,15 +332,15 @@ class TestRetentionVsSeal:
         gate = threading.Event()
         entered = threading.Event()
         stall = {"active": False}
-        real_write = sealer_mod.write_segment_file
+        real_write = sealer_mod.seal_segment_file
 
-        def stalled_write(path, cols, seg, **kw):
+        def stalled_write(path, seq, ints, flts, **kw):
             if stall["active"]:
                 entered.set()
                 assert gate.wait(timeout=10.0)
-            return real_write(path, cols, seg, **kw)
+            return real_write(path, seq, ints, flts, **kw)
 
-        monkeypatch.setattr(sealer_mod, "write_segment_file",
+        monkeypatch.setattr(sealer_mod, "seal_segment_file",
                             stalled_write)
         store = make_store(tmp_path, flush_rows=16, n_shards=1, workers=1)
         # one OLD committed segment (sealed inline before workers start)

@@ -38,6 +38,8 @@ NEW_TIMERS = (
     "pipeline.lock_wait_reinject_s",
     "pipeline.commit_gate_s",
     "store.inline_seal_s",
+    # every seal job, on whichever thread ran it
+    "store.seal_job_s",
     # the process layer: stalls of the loop's waits, full collections
     "runtime.stall_s",
     "runtime.stall_cpu_s",

@@ -70,12 +70,13 @@ if origin != want:
           "(got %r, wanted %r)" % (origin, want), file=sys.stderr)
     sys.exit(1)'
 
-echo "native_sanitize: running tests/test_native_fill.py under ASan/UBSan"
+echo "native_sanitize: running the native suites under ASan/UBSan"
 env LD_PRELOAD="$LIBASAN" \
     ASAN_OPTIONS="detect_leaks=0,abort_on_error=1,verify_asan_link_order=0" \
     UBSAN_OPTIONS="print_stacktrace=1,halt_on_error=1" \
     SW_NATIVE_LIB="$OUT" \
     JAX_PLATFORMS=cpu \
     python -m pytest tests/test_native_fill.py tests/test_native_wire.py \
-        tests/test_native_resolved.py -q -p no:cacheprovider "$@"
+        tests/test_native_resolved.py tests/test_native_seal.py -q \
+        -p no:cacheprovider "$@"
 echo "native_sanitize: OK (ASan/UBSan clean)"
